@@ -522,10 +522,13 @@ def run_coldstart(args, *, smoke: bool = False) -> dict:
     import tempfile
 
     from repro.core import FunctionSpec
-    from repro.launch.compile_cache import EXECUTABLE_INDEX
+    from repro.launch.compile_cache import EXECUTABLE_INDEX, disable_persistent_cache
 
     cycles = 3 if smoke else 5
     floor = 3.0 if smoke else 5.0
+    # cold must mean cold: no restore from JAX's persistent cache, whatever
+    # JAX_COMPILATION_CACHE_DIR says
+    disable_persistent_cache()
     EXECUTABLE_INDEX.clear()
 
     # --- scenario A: merge -> split -> re-merge churn --------------------
@@ -1539,11 +1542,10 @@ def main():
     args = ap.parse_args()
 
     if not args.coldstart:
-        # REPRO_COMPILE_CACHE=<dir>: persistent XLA cache across runs. The
-        # coldstart scenario opts out — its cold measurements must really
-        # be cold, even when CI restored a populated cache directory.
-        from repro.launch.compile_cache import maybe_enable_from_env
-        maybe_enable_from_env()
+        # persistent XLA cache across runs; the coldstart scenario turns it
+        # off itself (run_coldstart)
+        from repro.launch.compile_cache import enable_persistent_cache
+        enable_persistent_cache()
 
     if args.trace:
         # pin every tracer created from here on: scenarios drop their

@@ -22,8 +22,9 @@ Two hazards this repo has already shipped and hand-fixed once each:
 The tracer is a process-wide singleton (``TRACER``), disarmed by default
 (zero overhead: arming is what installs the patches). ``load_bench
 --serve --smoke`` arms it after warmup and asserts on the deltas;
-``kernels/ops.py`` reports eager kernel entries informationally (eager
-dispatch is legitimate on the unfused interpreter path).
+``kernels/ops.py`` reports every op entry with the path it took (kernel,
+interpret or ref), and eager entries separately (eager dispatch is
+legitimate on the unfused interpreter path).
 """
 from __future__ import annotations
 
@@ -39,6 +40,7 @@ class DispatchSnapshot:
     host_syncs: int
     decode_steps: int
     kernel_calls: int
+    compile_s: float = 0.0  # backend compile seconds
 
 
 class DispatchTracer:
@@ -55,9 +57,11 @@ class DispatchTracer:
         "_listener_installed": "_mu",
         "_unpatch": "_mu",
         "compiles": "_mu",
+        "compile_s": "_mu",
         "host_syncs": "_mu",
         "decode_steps": "_mu",
         "kernel_calls": "_mu",
+        "paths": "_mu",
     }
 
     _EVENT = "/jax/core/compile/backend_compile_duration"
@@ -73,9 +77,11 @@ class DispatchTracer:
         self._listener_installed = False
         self._unpatch = None  # installed patches' restore thunk, or None
         self.compiles = 0
+        self.compile_s = 0.0
         self.host_syncs = 0
         self.decode_steps = 0
         self.kernel_calls: dict[str, int] = {}
+        self.paths: dict[tuple[str, str], int] = {}
 
     @property
     def armed(self) -> bool:
@@ -92,6 +98,7 @@ class DispatchTracer:
             with self._mu:
                 if self._arm_count > 0:
                     self.compiles += 1
+                    self.compile_s += duration
 
     @guarded_by("_mu")
     def _install_listener(self) -> None:
@@ -170,13 +177,18 @@ class DispatchTracer:
             with self._mu:
                 self.decode_steps += 1
 
-    def note_kernel_call(self, name: str, probe=None) -> None:
-        """Informational: an op entry executed eagerly (concrete operand).
-
-        Legitimate on the unfused interpreter path; recorded so smoke
-        reports show the eager/traced split, never asserted on."""
+    def note_kernel_call(self, name: str, probe=None, path: str | None = None) -> None:
+        """An op entry and the ``path`` it dispatched to (``kernel`` |
+        ``interpret`` | ``ref``). ``paths`` counts every entry, traced or
+        eager, by (op, path) — what a smoke run reads to show the device
+        path really ran. ``kernel_calls`` counts eager entries only
+        (concrete operand): legitimate on the unfused interpreter path,
+        recorded so reports show the eager/traced split."""
         if not self._armed:
             return
+        if path is not None:
+            with self._mu:
+                self.paths[(name, path)] = self.paths.get((name, path), 0) + 1
         if probe is not None:
             try:
                 if not self._is_device_array(probe):
@@ -186,6 +198,11 @@ class DispatchTracer:
         with self._mu:
             self.kernel_calls[name] = self.kernel_calls.get(name, 0) + 1
 
+    def path_counts(self) -> dict[tuple[str, str], int]:
+        """A copy of the (op, path) entry counts."""
+        with self._mu:
+            return dict(self.paths)
+
     def snapshot(self) -> DispatchSnapshot:
         with self._mu:
             return DispatchSnapshot(
@@ -193,6 +210,7 @@ class DispatchTracer:
                 host_syncs=self.host_syncs,
                 decode_steps=self.decode_steps,
                 kernel_calls=sum(self.kernel_calls.values()),
+                compile_s=self.compile_s,
             )
 
     def delta(self, since: DispatchSnapshot) -> DispatchSnapshot:
@@ -202,6 +220,7 @@ class DispatchTracer:
             host_syncs=now.host_syncs - since.host_syncs,
             decode_steps=now.decode_steps - since.decode_steps,
             kernel_calls=now.kernel_calls - since.kernel_calls,
+            compile_s=now.compile_s - since.compile_s,
         )
 
 

@@ -78,15 +78,25 @@ class GroupRecord:
 
 
 def _allclose_tree(a, b, rtol: float, atol: float) -> bool:
+    """Health-check comparison of two output trees. Floating leaves are
+    held to the leaf's own scale, ``|x - y| <= atol + rtol * max|y|``: a
+    fused program rounds its bf16 matmuls differently, and that error
+    scales with the terms a dot product sums, not with each (possibly
+    cancelled, near-zero) output element. Other leaves must match exactly."""
     la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
     if len(la) != len(lb):
         return False
     for x, y in zip(la, lb):
-        xf = np.asarray(x, dtype=np.float64) if np.asarray(x).dtype.kind == "f" else np.asarray(x)
-        yf = np.asarray(y, dtype=np.float64) if np.asarray(y).dtype.kind == "f" else np.asarray(y)
-        if xf.shape != yf.shape:
+        xa, ya = np.asarray(x), np.asarray(y)
+        if xa.shape != ya.shape:
             return False
-        if not np.allclose(xf, yf, rtol=rtol, atol=atol):
+        if xa.dtype.kind != "f" or ya.dtype.kind != "f":
+            if not np.array_equal(xa, ya):
+                return False
+            continue
+        xf, yf = xa.astype(np.float64), ya.astype(np.float64)
+        scale = float(np.max(np.abs(yf))) if yf.size else 0.0
+        if not np.all(np.abs(xf - yf) <= atol + rtol * scale):
             return False
     return True
 

@@ -6,13 +6,15 @@ the last axis minor, so the kv index ``ik`` sweeps fully for each q block
 and is carried across the ``ik`` sweep [FlashAttention, arXiv:2205.14135,
 re-tiled for the MXU: bq = bk = 128 and head_dim-sized accumulators].
 
-GQA: the BlockSpec index maps route q head ``h`` to kv head ``h // G`` —
+Layout: the kernel reads head-major views, q (B, H, T, hd) and k/v
+(B, KV, S, hd), with the head axis squeezed out of every block, so each
+block's last two dims are (rows, hd) — the (8k, 128) tiles the TPU compiler
+requires. GQA: the index maps route q head ``h`` to kv head ``h // G``, so
 grouped heads reuse the same K/V block stream (no replication in HBM).
 
-Causality is handled two ways:
-  * blocks fully above the diagonal contribute nothing — masked to -inf and
-    skipped cheaply (their contribution to l is 0);
-  * the diagonal block applies the per-element triangular mask.
+Causality: blocks fully above the diagonal are skipped (no compute, and the
+index map re-points them at the last needed block so no DMA is issued); the
+diagonal block applies the per-element triangular mask.
 """
 from __future__ import annotations
 
@@ -23,9 +25,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import online_softmax as osm
+
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
-NEG_INF = -1e30
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *, causal: bool, scale: float, block_q: int, block_k: int, num_k_blocks: int):
@@ -34,39 +37,28 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *, causal: 
 
     @pl.when(ik == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        m_ref[...] = jnp.full_like(m_ref, osm.NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0, :]  # (bq, hd)
-    k = k_ref[0, :, 0, :]  # (bk, hd)
-    v = v_ref[0, :, 0, :]  # (bk, hd)
-
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # (bq, bk)
+    def _body():
+        s = osm.scores(q_ref[...], k_ref[...], scale)  # (bq, bk)
+        if causal:
+            rows = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+            cols = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+            s = jnp.where(rows >= cols, s, osm.NEG_INF)
+        m_ref[...], l_ref[...], acc_ref[...] = osm.step(
+            s, v_ref[...], m_ref[...], l_ref[...], acc_ref[...]
+        )
 
     if causal:
-        rows = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        cols = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        s = jnp.where(rows >= cols, s, NEG_INF)
-
-    m_prev = m_ref[...]
-    l_prev = l_ref[...]
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.exp(s - m_cur[:, None])  # (bq, bk)
-    l_ref[...] = l_prev * alpha + jnp.sum(p, axis=1)
-    m_ref[...] = m_cur
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+        pl.when(ik * block_k <= iq * block_q + block_q - 1)(_body)
+    else:
+        _body()
 
     @pl.when(ik == num_k_blocks - 1)
     def _finalize():
-        l = l_ref[...]
-        l = jnp.where(l == 0.0, 1.0, l)  # fully-masked rows -> 0 output
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = osm.finalize(l_ref[...], acc_ref[...], o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret"))
@@ -91,6 +83,11 @@ def flash_attention(
     grid = (b, h, t // block_q, s // block_k)
     scale = 1.0 / (hd**0.5)
 
+    def kv_map(ib, ih, iq, ik):
+        if causal:  # above-diagonal blocks re-read the last needed one (no DMA)
+            ik = jnp.minimum(ik, (iq * block_q + block_q - 1) // block_k)
+        return (ib, ih // g, ik, 0)
+
     kernel = functools.partial(
         _flash_kernel,
         causal=causal,
@@ -99,20 +96,21 @@ def flash_attention(
         block_k=block_k,
         num_k_blocks=s // block_k,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, hd), lambda ib, ih, iq, ik: (ib, iq, ih, 0)),
-            pl.BlockSpec((1, block_k, 1, hd), lambda ib, ih, iq, ik, g=g: (ib, ik, ih // g, 0)),
-            pl.BlockSpec((1, block_k, 1, hd), lambda ib, ih, iq, ik, g=g: (ib, ik, ih // g, 0)),
+            pl.BlockSpec((None, None, block_q, hd), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
+            pl.BlockSpec((None, None, block_k, hd), kv_map),
+            pl.BlockSpec((None, None, block_k, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, hd), lambda ib, ih, iq, ik: (ib, iq, ih, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=pl.BlockSpec((None, None, block_q, hd), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, t, hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),      # m: running row max
-            pltpu.VMEM((block_q,), jnp.float32),      # l: running row sum
+            pltpu.VMEM((block_q, 1), jnp.float32),    # m: running row max
+            pltpu.VMEM((block_q, 1), jnp.float32),    # l: running row sum
             pltpu.VMEM((block_q, hd), jnp.float32),   # acc: unnormalized output
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2))
+    return jnp.swapaxes(out, 1, 2)

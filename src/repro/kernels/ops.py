@@ -1,9 +1,14 @@
-"""jit'd dispatch wrappers: Pallas kernel on TPU, jnp oracle elsewhere.
+"""Dispatch wrappers: Pallas kernel on TPU, jnp oracle elsewhere.
 
-The models call these entry points; the CPU container (tests, dry-run
-lowering) takes the ref path, a real TPU deployment takes the kernel path.
-``REPRO_USE_PALLAS=1`` forces kernels (with ``interpret=True`` off-TPU — used
-by the kernel benchmarks).
+The models call these entry points. On a TPU every op runs its Pallas
+kernel, always: a shape the kernel's blocks do not divide raises from the
+kernel wrapper with the shapes, it never drops to the oracle. Off the TPU
+(tests, dry-run lowering) the ops take the oracle, and
+``REPRO_USE_PALLAS=1`` runs the kernels in interpret mode instead (kernel
+parity tests, the CPU rehearsal of ``chip_smoke.py``).
+
+Every entry is reported to ``analysis.dispatch.TRACER`` with the path it
+took, so a run can show that the device path really ran.
 """
 from __future__ import annotations
 
@@ -16,18 +21,15 @@ from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention as _decode_kernel
 from repro.kernels.flash_attention import flash_attention as _flash_kernel
 from repro.kernels.moe_gmm import moe_gmm as _gmm_kernel
-from repro.kernels.paged_attention import gather_pages
 from repro.kernels.paged_attention import paged_chunk_attention as _paged_chunk_kernel
 from repro.kernels.paged_attention import paged_decode_attention as _paged_kernel
-from repro.kernels.ssd_scan import ssd_scan as _ssd_kernel
 
 
 def _mode() -> str:
     """'kernel' | 'interpret' | 'ref'."""
-    forced = os.environ.get("REPRO_USE_PALLAS", "")
     if jax.default_backend() == "tpu":
-        return "ref" if forced == "0" else "kernel"
-    if forced == "1":
+        return "kernel"
+    if os.environ.get("REPRO_USE_PALLAS", "") == "1":
         return "interpret"
     return "ref"
 
@@ -40,72 +42,56 @@ def dispatch_mode() -> str:
     return _mode()
 
 
-def _aligned(*dims_and_blocks: tuple[int, int]) -> bool:
-    return all(d % b == 0 for d, b in dims_and_blocks)
+def _dispatch(name: str, probe) -> str:
+    mode = _mode()
+    TRACER.note_kernel_call(name, probe, path=mode)
+    return mode
 
 
 def attention(q, k, v, *, causal: bool = True):
-    TRACER.note_kernel_call("attention", q)
-    mode = _mode()
-    if mode != "ref" and _aligned((q.shape[1], 128), (k.shape[1], 128)):
-        return _flash_kernel(q, k, v, causal=causal, interpret=(mode == "interpret"))
-    return ref.mha_ref(q, k, v, causal=causal)
+    mode = _dispatch("attention", q)
+    if mode == "ref":
+        return ref.mha_ref(q, k, v, causal=causal)
+    return _flash_kernel(q, k, v, causal=causal, interpret=(mode == "interpret"))
 
 
 def decode_attention(q, k, v, cur_len):
-    TRACER.note_kernel_call("decode_attention", q)
-    mode = _mode()
-    if mode != "ref" and _aligned((k.shape[1], 512)):
-        return _decode_kernel(q, k, v, cur_len, interpret=(mode == "interpret"))
-    return ref.decode_attn_ref(q, k, v, cur_len)
+    mode = _dispatch("decode_attention", q)
+    if mode == "ref":
+        return ref.decode_attn_ref(q, k, v, cur_len)
+    return _decode_kernel(q, k, v, cur_len, interpret=(mode == "interpret"))
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_table, cur_len):
-    """q: (B, H, hd); pages (P, page, KV, hd); block_table (B, n) int32.
+    """q: (B, H, hd); pages (P, KV, page, hd); block_table (B, n) int32.
 
-    Kernel/interpret mode runs the block-table-indirect split-K kernel; the
-    ref path gathers pages contiguous (one XLA gather, fused into the
-    surrounding program) and reuses the dense decode oracle — bit-identical
-    to a dense cache of the same gathered width."""
-    TRACER.note_kernel_call("paged_decode_attention", q)
-    mode = _mode()
-    if mode != "ref" and _aligned((k_pages.shape[1], 128)):
-        return _paged_kernel(q, k_pages, v_pages, block_table, cur_len,
-                             interpret=(mode == "interpret"))
-    k = gather_pages(k_pages, block_table)
-    v = gather_pages(v_pages, block_table)
-    return ref.decode_attn_ref(q, k, v, cur_len)
+    Returns the block-table-indirect split-K kernel's result in
+    kernel/interpret mode, or None in ref mode — the caller
+    (``models/attention.py: paged_decode_attention``) then gathers the pages
+    contiguous and runs the dense decode path, bit-identical to a dense
+    cache of the same gathered width."""
+    mode = _dispatch("paged_decode_attention", q)
+    if mode == "ref":
+        return None
+    return _paged_kernel(q, k_pages, v_pages, block_table, cur_len,
+                         interpret=(mode == "interpret"))
 
 
 def paged_chunk_attention(q, k_pages, v_pages, block_table, start):
     """q: (B, C, H, hd); chunked-prefill attention over the page arena.
 
-    Returns the kernel result in kernel/interpret mode, or None when the
-    shapes don't fit the kernel tiling / ref mode is active — the caller
-    (``models/attention.py: paged_chunk_attention``) then runs the bit-exact
-    gather + q-chunked fallback."""
-    TRACER.note_kernel_call("paged_chunk_attention", q)
-    mode = _mode()
-    if mode != "ref" and _aligned((k_pages.shape[1], 128), (q.shape[1], 8)):
-        return _paged_chunk_kernel(q, k_pages, v_pages, block_table, start,
-                                   interpret=(mode == "interpret"))
-    return None
-
-
-def ssd(x, bm, cm, dt, a_log, d_skip, *, chunk: int = 256):
-    TRACER.note_kernel_call("ssd", x)
-    mode = _mode()
-    if mode != "ref" and _aligned((x.shape[1], chunk)):
-        return _ssd_kernel(x, bm, cm, dt, a_log, d_skip, chunk=chunk, interpret=(mode == "interpret"))
-    y, _ = ref.ssd_ref(x, bm, cm, dt, a_log, d_skip)
-    return y.astype(x.dtype)
+    Returns the kernel result in kernel/interpret mode, or None in ref mode
+    — the caller (``models/attention.py: paged_chunk_attention``) then runs
+    the bit-exact gather + q-chunked path."""
+    mode = _dispatch("paged_chunk_attention", q)
+    if mode == "ref":
+        return None
+    return _paged_chunk_kernel(q, k_pages, v_pages, block_table, start,
+                               interpret=(mode == "interpret"))
 
 
 def gmm(xe, w):
-    TRACER.note_kernel_call("gmm", xe)
-    mode = _mode()
-    e, c, d = xe.shape
-    f = w.shape[2]
-    if mode != "ref" and _aligned((c, 128), (d, 128), (f, 128)):
-        return _gmm_kernel(xe, w, interpret=(mode == "interpret"))
-    return ref.gmm_ref(xe, w)
+    mode = _dispatch("gmm", xe)
+    if mode == "ref":
+        return ref.gmm_ref(xe, w)
+    return _gmm_kernel(xe, w, interpret=(mode == "interpret"))

@@ -2,19 +2,24 @@
 split-K decode schedule.
 
 Same partial-softmax sweep as ``decode_attention.py`` (grid minor axis walks
-the KV sequence, (m, l, acc) carried in VMEM scratch), but K/V live in a
-shared page arena ``(num_pages, page, KV, hd)`` instead of a per-sequence
-contiguous buffer: the kv-block index maps read the sequence's *block
-table* — scalar-prefetched so the physical page id is known before the
-kernel body runs and the DMA fetches exactly that page. Sequences of any
-ragged length batch together; pages past ``cur_len`` are masked, and padded
-block-table entries point at the arena's reserved scratch page (reads are
-safe, contributions masked to zero).
+the sequence's pages, (m, l, acc) carried in VMEM scratch), but K/V live in
+a shared page arena instead of a per-sequence contiguous buffer: the page
+index maps read the sequence's *block table* — scalar-prefetched so the
+physical page id is known before the kernel body runs and the DMA fetches
+exactly that page. Sequences of any ragged length batch together; pages
+past the length are skipped (no compute, and the index map re-points them
+at the last live page so no DMA is issued), and padded block-table entries
+point at the arena's reserved scratch page.
 
-``gather_pages`` is the non-TPU/interpret fallback shape: it reconstructs
-the contiguous (B, S, KV, hd) view with one advanced-indexing gather, which
-XLA fuses into the surrounding decode program (see
-``models/attention.py: paged_decode_attention`` for the dispatch).
+Layout: the arena is head-major, one layer's pages are
+``(num_pages, KV, page, hd)``, so a block squeezes the page and KV-head
+axes and its last two dims are (page, hd) — the tiling the TPU compiler
+accepts. One grid cell serves the G query heads that share a KV head, so a
+page is fetched once per group and its scores are one MXU matmul.
+
+``gather_pages`` is the non-TPU fallback: it reconstructs the contiguous
+(B, S, KV, hd) view with one advanced-indexing gather, which XLA fuses into
+the surrounding program (see ``kernels/ops.py`` for the dispatch).
 """
 from __future__ import annotations
 
@@ -25,61 +30,49 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.kernels import online_softmax as osm
+
+DEFAULT_BLOCK_Q = 128
 
 
 def gather_pages(pages: jax.Array, block_table: jax.Array) -> jax.Array:
-    """pages: (P, page, KV, hd); block_table: (B, n) -> (B, n*page, KV, hd).
+    """pages: (P, KV, page, hd); block_table: (B, n) -> (B, n*page, KV, hd).
 
     The contiguous-gather fallback: one XLA gather rebuilds each sequence's
     logical cache from its pages (garbage past cur_len — callers mask)."""
     b, n = block_table.shape
-    _, page, kv, hd = pages.shape
-    return pages[block_table].reshape(b, n * page, kv, hd)
+    _, kv, page, hd = pages.shape
+    return jnp.swapaxes(pages[block_table], 2, 3).reshape(b, n * page, kv, hd)
 
 
 def _paged_kernel(
     bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     *, scale: float, page: int, num_page_blocks: int,
 ):
-    ib, _, ik = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    ib, ik = pl.program_id(0), pl.program_id(2)
+    cur = len_ref[ib]
 
     @pl.when(ik == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        m_ref[...] = jnp.full_like(m_ref, osm.NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0, :]  # (hd,)
-    k = k_ref[0, :, 0, :]  # (page, hd)
-    v = v_ref[0, :, 0, :]  # (page, hd)
-    cur = len_ref[ib]
-
-    s = jnp.einsum("kh,h->k", k.astype(jnp.float32), q.astype(jnp.float32)) * scale
-    # logical position of this page's rows = page index * page + row
-    cols = ik * page + jax.lax.broadcasted_iota(jnp.int32, (page,), 0)
-    s = jnp.where(cols < cur, s, NEG_INF)
-
-    m_prev = m_ref[0]
-    m_cur = jnp.maximum(m_prev, jnp.max(s))
-    alpha = jnp.exp(m_prev - m_cur)
-    # explicit zero for masked positions: when EVERY score so far is masked
-    # (cur_len 0 — a batcher's empty slot), m_cur is still NEG_INF and
-    # exp(s - m_cur) would be 1 per position, making the output a mean of
-    # scratch-page garbage; with the guard l stays 0 and _finalize emits
-    # exact zeros, matching the "masked contributes nothing" contract
-    p = jnp.where(s <= NEG_INF * 0.5, 0.0, jnp.exp(s - m_cur))
-    l_ref[0] = l_ref[0] * alpha + jnp.sum(p)
-    m_ref[0] = m_cur
-    acc_ref[...] = acc_ref[...] * alpha + jnp.einsum(
-        "k,kh->h", p, v.astype(jnp.float32)
-    )[None, :]
+    # an empty batcher slot (cur_len 0) computes nothing: l stays 0 and
+    # _finalize emits exact zeros, not a mean of scratch-page contents
+    @pl.when(ik * page < cur)
+    def _body():
+        s = osm.scores(q_ref[...], k_ref[...], scale)  # (G, page)
+        # logical position of this page's rows = page index * page + row
+        cols = ik * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(cols < cur, s, osm.NEG_INF)
+        m_ref[...], l_ref[...], acc_ref[...] = osm.step(
+            s, v_ref[...], m_ref[...], l_ref[...], acc_ref[...]
+        )
 
     @pl.when(ik == num_page_blocks - 1)
     def _finalize():
-        l = l_ref[0]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0, :] = (acc_ref[0] / l).astype(o_ref.dtype)
+        o_ref[...] = osm.finalize(l_ref[...], acc_ref[...], o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -92,89 +85,83 @@ def paged_decode_attention(
     *,
     interpret: bool = False,
 ) -> jax.Array:
-    """q: (B, H, hd); k_pages/v_pages: (P, page, KV, hd);
+    """q: (B, H, hd); k_pages/v_pages: (P, KV, page, hd);
     block_table: (B, n_pages) int32; cur_len: (B,) -> (B, H, hd)."""
     b, h, hd = q.shape
-    _, page, kv, _ = k_pages.shape
+    _, kv, page, _ = k_pages.shape
     n = block_table.shape[1]
     g = h // kv
-    grid = (b, h, n)
     scale = 1.0 / (hd**0.5)
+
+    def page_map(ib, j, ik, bt, ln):  # pages past cur_len re-read the last live one
+        return (bt[ib, jnp.minimum(ik, jnp.maximum(ln[ib] - 1, 0) // page)], j, 0, 0)
 
     kernel = functools.partial(_paged_kernel, scale=scale, page=page, num_page_blocks=n)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # block_table, cur_len
-        grid=grid,
+        grid=(b, kv, n),
         in_specs=[
-            pl.BlockSpec((1, 1, hd), lambda ib, ih, ik, bt, ln: (ib, ih, 0)),
-            # the indirection: physical page id comes from the prefetched table
-            pl.BlockSpec((1, page, 1, hd), lambda ib, ih, ik, bt, ln, g=g: (bt[ib, ik], 0, ih // g, 0)),
-            pl.BlockSpec((1, page, 1, hd), lambda ib, ih, ik, bt, ln, g=g: (bt[ib, ik], 0, ih // g, 0)),
+            pl.BlockSpec((None, None, g, hd), lambda ib, j, ik, bt, ln: (ib, j, 0, 0)),
+            pl.BlockSpec((None, None, page, hd), page_map),
+            pl.BlockSpec((None, None, page, hd), page_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, hd), lambda ib, ih, ik, bt, ln: (ib, ih, 0)),
+        out_specs=pl.BlockSpec((None, None, g, hd), lambda ib, j, ik, bt, ln: (ib, j, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1, hd), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, hd), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
         interpret=interpret,
-    )(block_table.astype(jnp.int32), cur_len.astype(jnp.int32), q, k_pages, v_pages)
+    )(block_table.astype(jnp.int32), cur_len.astype(jnp.int32),
+      q.reshape(b, kv, g, hd), k_pages, v_pages)
+    return out.reshape(b, h, hd)
 
 
 def _paged_chunk_kernel(
     bt_ref, start_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-    *, scale: float, page: int, num_page_blocks: int, chunk: int,
+    *, scale: float, page: int, num_page_blocks: int, block_q: int, groups: int,
 ):
-    """Chunked-prefill attention over the page arena: C query rows (one
-    prefill chunk starting at absolute position ``start``) sweep the
-    sequence's pages with the same online-softmax schedule as the decode
-    kernel, carrying per-row (m, l, acc) in VMEM scratch. Row i masks
-    columns past ``start + i`` (causal)."""
-    ib, _, ik = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    """Chunked-prefill attention over the page arena: a block of ``block_q``
+    query rows of one prefill chunk (the chunk starts at absolute position
+    ``start``), for each of the G heads sharing a KV head, sweeps the
+    sequence's pages with the decode kernel's online softmax, carrying
+    per-row (m, l, acc) in VMEM scratch. Row i masks columns past its own
+    absolute position (causal); pages wholly past the block's last row are
+    skipped."""
+    ib, iq, ik = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    row0 = start_ref[ib] + iq * block_q  # absolute position of the block's row 0
 
     @pl.when(ik == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        m_ref[...] = jnp.full_like(m_ref, osm.NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0, :]  # (C, hd)
-    k = k_ref[0, :, 0, :]  # (page, hd)
-    v = v_ref[0, :, 0, :]  # (page, hd)
-    start = start_ref[ib]
-
-    s = jnp.einsum(
-        "th,kh->tk", q.astype(jnp.float32), k.astype(jnp.float32)
-    ) * scale  # (C, page)
-    cols = ik * page + jax.lax.broadcasted_iota(jnp.int32, (chunk, page), 1)
-    rows = start + jax.lax.broadcasted_iota(jnp.int32, (chunk, page), 0)
-    s = jnp.where(cols <= rows, s, NEG_INF)
-
-    m_prev = m_ref[...]  # (C,)
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    alpha = jnp.exp(m_prev - m_cur)
-    # same explicit-zero guard as the decode kernel: a fully-masked row must
-    # contribute nothing, not a mean of scratch-page garbage
-    p = jnp.where(s <= NEG_INF * 0.5, 0.0, jnp.exp(s - m_cur[:, None]))
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1)
-    m_ref[...] = m_cur
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.einsum(
-        "tk,kh->th", p, v.astype(jnp.float32)
-    )
+    @pl.when(ik * page <= row0 + block_q - 1)
+    def _body():
+        k = k_ref[...]  # (page, hd)
+        v = v_ref[...]
+        rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, page), 0)
+        cols = ik * page + jax.lax.broadcasted_iota(jnp.int32, (block_q, page), 1)
+        causal = cols <= rows
+        for gi in range(groups):  # static: G (bq, hd) x (hd, page) MXU matmuls
+            s = jnp.where(causal, osm.scores(q_ref[gi], k, scale), osm.NEG_INF)
+            m_ref[gi], l_ref[gi], acc_ref[gi] = osm.step(
+                s, v, m_ref[gi], l_ref[gi], acc_ref[gi]
+            )
 
     @pl.when(ik == num_page_blocks - 1)
     def _finalize():
-        l = l_ref[...]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        for gi in range(groups):
+            o_ref[gi] = osm.finalize(l_ref[gi], acc_ref[gi], o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("block_q", "interpret"))
 def paged_chunk_attention(
     q: jax.Array,
     k_pages: jax.Array,
@@ -182,39 +169,53 @@ def paged_chunk_attention(
     block_table: jax.Array,
     start: jax.Array,
     *,
+    block_q: int = DEFAULT_BLOCK_Q,
     interpret: bool = False,
 ) -> jax.Array:
-    """q: (B, C, H, hd); k_pages/v_pages: (P, page, KV, hd);
+    """q: (B, C, H, hd); k_pages/v_pages: (P, KV, page, hd);
     block_table: (B, n) int32; start: (B,) absolute position of q[:, 0]
     -> (B, C, H, hd)."""
     b, c, h, hd = q.shape
-    _, page, kv, _ = k_pages.shape
+    _, kv, page, _ = k_pages.shape
     n = block_table.shape[1]
     g = h // kv
-    grid = (b, h, n)
+    block_q = min(block_q, c)
+    if c % block_q:
+        raise ValueError(f"chunk C={c} must divide block_q={block_q}")
     scale = 1.0 / (hd**0.5)
 
+    def page_map(ib, j, iq, ik, bt, st):  # pages past the block's last row: no DMA
+        return (bt[ib, jnp.minimum(ik, (st[ib] + (iq + 1) * block_q - 1) // page)], j, 0, 0)
+
+    def q_map(ib, j, iq, ik, bt, st):
+        return (ib, j, 0, iq, 0)
+
     kernel = functools.partial(
-        _paged_chunk_kernel, scale=scale, page=page, num_page_blocks=n, chunk=c
+        _paged_chunk_kernel, scale=scale, page=page, num_page_blocks=n,
+        block_q=block_q, groups=g,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # block_table, start
-        grid=grid,
+        grid=(b, kv, c // block_q, n),
         in_specs=[
-            pl.BlockSpec((1, c, 1, hd), lambda ib, ih, ik, bt, st: (ib, 0, ih, 0)),
-            pl.BlockSpec((1, page, 1, hd), lambda ib, ih, ik, bt, st, g=g: (bt[ib, ik], 0, ih // g, 0)),
-            pl.BlockSpec((1, page, 1, hd), lambda ib, ih, ik, bt, st, g=g: (bt[ib, ik], 0, ih // g, 0)),
+            pl.BlockSpec((None, None, g, block_q, hd), q_map),
+            pl.BlockSpec((None, None, page, hd), page_map),
+            pl.BlockSpec((None, None, page, hd), page_map),
         ],
-        out_specs=pl.BlockSpec((1, c, 1, hd), lambda ib, ih, ik, bt, st: (ib, 0, ih, 0)),
+        out_specs=pl.BlockSpec((None, None, g, block_q, hd), q_map),
         scratch_shapes=[
-            pltpu.VMEM((c,), jnp.float32),
-            pltpu.VMEM((c,), jnp.float32),
-            pltpu.VMEM((c, hd), jnp.float32),
+            pltpu.VMEM((g, block_q, 1), jnp.float32),
+            pltpu.VMEM((g, block_q, 1), jnp.float32),
+            pltpu.VMEM((g, block_q, hd), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    # (B, C, H, hd) -> head-major (B, KV, G, C, hd): each block is the G
+    # heads of one KV head over block_q rows
+    qh = q.reshape(b, c, kv, g, hd).transpose(0, 2, 3, 1, 4)
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
         interpret=interpret,
-    )(block_table.astype(jnp.int32), start.astype(jnp.int32), q, k_pages, v_pages)
+    )(block_table.astype(jnp.int32), start.astype(jnp.int32), qh, k_pages, v_pages)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, c, h, hd)

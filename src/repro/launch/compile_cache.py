@@ -12,9 +12,10 @@ Two layers keep a rebuilt execution unit from paying XLA again:
    the environment (jax version, backend, kernel dispatch mode).  A rebuilt
    unit whose key matches reuses the live executable — zero recompiles.
 2. JAX's persistent compilation cache (cross-process) —
-   ``enable_persistent_cache`` points jax at an on-disk cache directory so
-   even a fresh process (deploy, CI run, resurrect after restart) restores
-   serialized executables instead of re-running XLA.
+   ``enable_persistent_cache`` points jax at an on-disk cache directory
+   (``$JAX_COMPILATION_CACHE_DIR``, else a fixed directory in the checkout)
+   so even a fresh process (deploy, CI run, resurrect after restart)
+   restores serialized executables instead of re-running XLA.
 
 Safety invariants:
 
@@ -38,6 +39,7 @@ import os
 import threading
 import types
 import weakref
+from pathlib import Path
 from typing import Any, Mapping
 
 import jax
@@ -47,36 +49,46 @@ _MAX_ARRAY_BYTES = 1 << 20  # full-hash cap; larger arrays are sample-hashed
 _MAX_DEPTH = 8
 
 
-def enable_persistent_cache(directory: str) -> str | None:
-    """Point jax's persistent compilation cache at ``directory`` (created if
-    missing), with thresholds zeroed so even the tiny CPU test programs are
-    cached.  Returns the directory on success, None if the running jax
-    doesn't support the knobs (best-effort: the executable index still
-    works without it)."""
-    try:
-        os.makedirs(directory, exist_ok=True)
+#: Where the persistent cache lives when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: one fixed directory in the checkout (listed in ``.gitignore``). The
+#: path is part of JAX's cache key, so it must not move between runs.
+DEFAULT_CACHE_DIR = str(Path(__file__).resolve().parents[3] / ".jax_compile_cache")
+
+
+def cache_dir() -> str:
+    """``$JAX_COMPILATION_CACHE_DIR`` when set, else :data:`DEFAULT_CACHE_DIR`."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_CACHE_DIR
+
+
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache at :func:`cache_dir`, with
+    thresholds zeroed so every program is cached. A set
+    ``JAX_COMPILATION_CACHE_DIR`` is read by JAX itself, so no directory is
+    set in code then. Returns the directory."""
+    directory = cache_dir()
+    os.makedirs(directory, exist_ok=True)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", directory)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:
-        return None
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return directory
 
 
-def maybe_enable_from_env() -> str | None:
-    """Enable the persistent cache when ``REPRO_COMPILE_CACHE`` names a
-    directory (the CI workflow persists it across runs via actions/cache)."""
-    directory = os.environ.get("REPRO_COMPILE_CACHE", "")
-    if not directory:
-        return None
-    return enable_persistent_cache(directory)
+def disable_persistent_cache() -> None:
+    """Turn JAX's persistent cache off for the rest of the process, whatever
+    the environment says — for measurements that must compile cold."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
 
 
 def environment_key() -> tuple:
     """Everything outside the spec that changes what a program lowers to.
 
     ``dispatch_mode`` matters because ``kernels/ops.py`` picks Pallas vs the
-    jnp oracle per call site: flipping ``REPRO_USE_PALLAS`` mid-process must
+    jnp oracle per backend: flipping ``REPRO_USE_PALLAS`` mid-process must
     miss the index rather than reuse a stale lowering."""
     from repro.kernels import ops
 

@@ -123,8 +123,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_path: str | None) 
     from repro.launch.hlo_analysis import analyze
 
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax < 0.5 wraps the dict in a list
-        ca = ca[0] if ca else {}
     raw_flops = float(ca.get("flops", 0.0))
     raw_bytes = float(ca.get("bytes accessed", 0.0))
     summary = analyze(compiled.as_text())  # loop-aware (trip-count-scaled)

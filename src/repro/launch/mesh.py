@@ -26,10 +26,9 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for the production mesh, have {len(devices)} — "
             "run under dryrun.py (it sets --xla_force_host_platform_device_count=512)"
         )
-    kwargs = {}
-    if hasattr(jax.sharding, "AxisType"):  # jax >= 0.5; Auto is the default before
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, devices=devices, **kwargs)
+    return jax.make_mesh(
+        shape, axes, devices=devices, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_smoke_mesh(devices=None):

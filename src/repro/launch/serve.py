@@ -31,13 +31,13 @@ def main() -> None:
 
     from repro.configs import get_arch, reduced_config
     from repro.core import FusionPolicy, OrchestratedBackend, TinyJaxBackend
-    from repro.launch.compile_cache import maybe_enable_from_env
+    from repro.launch.compile_cache import enable_persistent_cache
     from repro.models.model import build_model
     from repro.serving.engine import ServingEngine
 
-    # REPRO_COMPILE_CACHE=<dir>: persistent XLA cache — relaunches restore
-    # executables instead of rebuilding them (the cold-start story).
-    maybe_enable_from_env()
+    # persistent XLA cache — relaunches restore executables instead of
+    # rebuilding them (the cold-start story)
+    enable_persistent_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

@@ -37,11 +37,11 @@ def main() -> None:
     from repro.configs import get_arch, reduced_config
     from repro.configs.base import ShapeConfig
     from repro.data import SyntheticTokenPipeline
-    from repro.launch.compile_cache import maybe_enable_from_env
+    from repro.launch.compile_cache import enable_persistent_cache
     from repro.models.model import build_model
 
-    # REPRO_COMPILE_CACHE=<dir>: persistent XLA cache across train relaunches
-    maybe_enable_from_env()
+    # persistent XLA cache across train relaunches
+    enable_persistent_cache()
     from repro.optim import AdamWConfig, cosine_schedule
     from repro.training import TrainLoop
     from repro.training.train_step import init_train_state, make_train_step

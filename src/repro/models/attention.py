@@ -22,10 +22,13 @@ DEFAULT_Q_CHUNK = 512
 def attn_defs(cfg: ModelConfig):
     d, h, kv, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     defs = {
-        "wq": ParamDef((d, h, hd), ("embed_fsdp", "heads", "head_dim")),
-        "wk": ParamDef((d, kv, hd), ("embed_fsdp", "kv_heads", "head_dim")),
-        "wv": ParamDef((d, kv, hd), ("embed_fsdp", "kv_heads", "head_dim")),
-        "wo": ParamDef((h, hd, d), ("heads", "head_dim", "embed_fsdp")),
+        # fan-in is d for the projections in and h*hd for the one out: a
+        # (d, h, hd) leaf's default axis (-2) would be h, giving q/k entries
+        # of std ~sqrt(d/h) and a saturated, chaotic softmax at init
+        "wq": ParamDef((d, h, hd), ("embed_fsdp", "heads", "head_dim"), scale_axis=-3),
+        "wk": ParamDef((d, kv, hd), ("embed_fsdp", "kv_heads", "head_dim"), scale_axis=-3),
+        "wv": ParamDef((d, kv, hd), ("embed_fsdp", "kv_heads", "head_dim"), scale_axis=-3),
+        "wo": ParamDef((h, hd, d), ("heads", "head_dim", "embed_fsdp"), scale_axis=(-3, -2)),
     }
     if cfg.qk_norm:
         defs["q_norm"] = ParamDef((hd,), ("head_dim",), init="ones")
@@ -148,7 +151,7 @@ def paged_decode_attention(
 ) -> jax.Array:
     """One-token attention over a paged KV arena.
 
-    q: (B,1,H,hd); pages: (P, page, KV, hd); block_table: (B, n) int32 rows
+    q: (B,1,H,hd); pages: (P, KV, page, hd); block_table: (B, n) int32 rows
     of physical page ids (padded entries point at the arena's scratch page);
     cur_len: (B,) valid lengths. On TPU the block-table-indirect split-K
     kernel reads pages directly; elsewhere ONE advanced-indexing gather
@@ -159,8 +162,9 @@ def paged_decode_attention(
     from repro.kernels import ops as kops
     from repro.kernels.paged_attention import gather_pages
 
-    if kops._mode() == "kernel" and k_pages.shape[1] % 128 == 0:
-        return kops.paged_decode_attention(q[:, 0], k_pages, v_pages, block_table, cur_len)[:, None]
+    out = kops.paged_decode_attention(q[:, 0], k_pages, v_pages, block_table, cur_len)
+    if out is not None:
+        return out[:, None]
     k_cache = gather_pages(k_pages, block_table)
     v_cache = gather_pages(v_pages, block_table)
     return decode_attention(q, k_cache, v_cache, cur_len)
@@ -176,7 +180,7 @@ def paged_chunk_attention(
     """Causal attention for one chunked-prefill block over a paged arena.
 
     q: (1, C, H, hd) — C chunk rows whose absolute positions begin at
-    ``start`` (shape (1,) int32); pages: (P, page, KV, hd); block_table:
+    ``start`` (shape (1,) int32); pages: (P, KV, page, hd); block_table:
     (1, n). Each chunk row attends to every position <= its own absolute
     position, exactly like the matching rows of a dense causal prefill. On
     TPU the block-table-indirect chunk kernel reads pages directly;
@@ -232,13 +236,13 @@ def update_paged_kv(
     sequence's write position: physical page ``bt[b, cur//page]``, row
     ``cur % page``. Masked slots carry an all-scratch block-table row with
     ``cur_len == 0``, so their write lands in the reserved scratch page."""
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     b = block_table.shape[0]
     logical = cur_len // page
     phys = block_table[jnp.arange(b), logical]  # (B,) physical page ids
     slot = cur_len % page
-    k_pages = k_pages.at[phys, slot].set(k_new[:, 0].astype(k_pages.dtype))
-    v_pages = v_pages.at[phys, slot].set(v_new[:, 0].astype(v_pages.dtype))
+    k_pages = k_pages.at[phys, :, slot].set(k_new[:, 0].astype(k_pages.dtype))
+    v_pages = v_pages.at[phys, :, slot].set(v_new[:, 0].astype(v_pages.dtype))
     return k_pages, v_pages
 
 
@@ -257,13 +261,13 @@ def update_paged_kv_chunk(
     ``i >= valid`` are padding (the chunk is padded to a power of two for
     compile-cache reuse): their writes are routed to the reserved scratch
     page, same contract as a masked decode slot."""
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     c = k_new.shape[1]
     idx = jnp.arange(c)
     pos = start[0] + idx
     logical = jnp.clip(pos // page, 0, block_table.shape[1] - 1)
     phys = jnp.where(idx < valid[0], block_table[0, logical], 0)  # (C,)
     slot = pos % page
-    k_pages = k_pages.at[phys, slot].set(k_new[0].astype(k_pages.dtype))
-    v_pages = v_pages.at[phys, slot].set(v_new[0].astype(v_pages.dtype))
+    k_pages = k_pages.at[phys, :, slot].set(k_new[0].astype(k_pages.dtype))
+    v_pages = v_pages.at[phys, :, slot].set(v_new[0].astype(v_pages.dtype))
     return k_pages, v_pages

@@ -25,10 +25,13 @@ from repro.sharding.specs import LogicalRules, shard_as
 def cross_attn_defs(cfg: ModelConfig):
     d, h, kv, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     return {
-        "wq": ParamDef((d, h, hd), ("embed_fsdp", "heads", "head_dim")),
-        "wk": ParamDef((d, kv, hd), ("embed_fsdp", "kv_heads", "head_dim")),
-        "wv": ParamDef((d, kv, hd), ("embed_fsdp", "kv_heads", "head_dim")),
-        "wo": ParamDef((h, hd, d), ("heads", "head_dim", "embed_fsdp")),
+        # fan-in is d for the projections in and h*hd for the one out: a
+        # (d, h, hd) leaf's default axis (-2) would be h, giving q/k entries
+        # of std ~sqrt(d/h) and a saturated, chaotic softmax at init
+        "wq": ParamDef((d, h, hd), ("embed_fsdp", "heads", "head_dim"), scale_axis=-3),
+        "wk": ParamDef((d, kv, hd), ("embed_fsdp", "kv_heads", "head_dim"), scale_axis=-3),
+        "wv": ParamDef((d, kv, hd), ("embed_fsdp", "kv_heads", "head_dim"), scale_axis=-3),
+        "wo": ParamDef((h, hd, d), ("heads", "head_dim", "embed_fsdp"), scale_axis=(-3, -2)),
     }
 
 

@@ -14,6 +14,7 @@ model on a 1-CPU container.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable
 
@@ -29,7 +30,8 @@ class ParamDef:
     shape: tuple[int, ...]
     logical: tuple[str | None, ...]
     init: str = "normal"          # normal | zeros | ones | embed
-    scale_axis: int | None = None  # fan-in axis for 'normal' (default: -2)
+    # fan-in axis (or axes, multiplied) for 'normal'; default -2
+    scale_axis: int | tuple[int, ...] | None = None
     dtype: Any = jnp.bfloat16
 
     def __post_init__(self):
@@ -47,7 +49,17 @@ def _fan_in(d: ParamDef) -> int:
     ax = d.scale_axis
     if ax is None:
         ax = -2 if len(d.shape) >= 2 else 0
-    return max(1, d.shape[ax])
+    axes = ax if isinstance(ax, tuple) else (ax,)
+    return max(1, math.prod(d.shape[a] for a in axes))
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _draw_leaf(d: ParamDef, key: jax.Array) -> jax.Array:
+    """One random leaf as one compiled program: the float32 draw, scale and
+    cast fuse, so only the ``d.dtype`` output is ever materialized (an
+    eager draw would hold a float32 copy of the whole leaf first)."""
+    std = 1.0 if d.init == "embed" else 1.0 / math.sqrt(_fan_in(d))
+    return (jax.random.normal(key, d.shape, jnp.float32) * std).astype(d.dtype)
 
 
 def _init_leaf(d: ParamDef, key: jax.Array) -> jax.Array:
@@ -55,10 +67,7 @@ def _init_leaf(d: ParamDef, key: jax.Array) -> jax.Array:
         return jnp.zeros(d.shape, d.dtype)
     if d.init == "ones":
         return jnp.ones(d.shape, d.dtype)
-    if d.init == "embed":
-        return (jax.random.normal(key, d.shape, jnp.float32)).astype(d.dtype)
-    std = 1.0 / math.sqrt(_fan_in(d))
-    return (jax.random.normal(key, d.shape, jnp.float32) * std).astype(d.dtype)
+    return _draw_leaf(d, key)
 
 
 def init_params(defs, rng: jax.Array):
@@ -102,6 +111,14 @@ def map_defs(fn: Callable[[ParamDef], ParamDef], defs):
     return jax.tree_util.tree_map(fn, defs, is_leaf=is_def)
 
 
+def _shift_axis(ax):
+    if ax is None:
+        return None
+    if isinstance(ax, tuple):
+        return tuple(_shift_axis(a) for a in ax)
+    return ax if ax < 0 else ax + 1
+
+
 def stack_defs(defs, n: int, logical: str = "layers"):
     """Prepend a stacking axis (for scan-over-layers stacked params)."""
     return map_defs(
@@ -109,7 +126,7 @@ def stack_defs(defs, n: int, logical: str = "layers"):
             d,
             shape=(n, *d.shape),
             logical=(logical, *d.logical),
-            scale_axis=None if d.scale_axis is None else (d.scale_axis if d.scale_axis < 0 else d.scale_axis + 1),
+            scale_axis=_shift_axis(d.scale_axis),
         ),
         defs,
     )

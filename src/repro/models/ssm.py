@@ -83,33 +83,11 @@ def _gated_out(params, y: jax.Array, z: jax.Array, cfg: ModelConfig, eps: float 
     return jnp.einsum("bte,ed->btd", yf.astype(y.dtype), params["out"])
 
 
-def _final_state_only(x, bm, dt, a_log):
-    """Closed-form final SSD state (B,H,P,N) without the output sweep."""
-    h = x.shape[2]
-    grp = bm.shape[2]
-    a = -jnp.exp(a_log.astype(jnp.float32))
-    dta = dt.astype(jnp.float32) * a
-    cum = jnp.cumsum(dta, axis=1)  # (B,T,H)
-    w_j = jnp.exp(cum[:, -1:, :] - cum) * dt.astype(jnp.float32)
-    bh = jnp.repeat(bm, h // grp, axis=2).astype(jnp.float32)
-    state = jnp.einsum("bthp,bthn->bhpn", x.astype(jnp.float32) * w_j[..., None], bh)
-    return None, state
-
-
 def ssd_chunked(x, bm, cm, dt, a_log, d_skip, chunk: int, init_state=None):
     """SSD dual form. x: (B,T,H,P); bm/cm: (B,T,G,N); dt: (B,T,H) fp32.
 
     Returns (y (B,T,H,P), final_state (B,H,P,N) fp32).
     """
-    if init_state is None:
-        from repro.kernels import ops as kops
-
-        if kops._mode() == "kernel" and x.shape[1] % chunk == 0:
-            # Pallas path (TPU): kernel returns y; recompute final state via
-            # the cheap rank-Q closed form only when a cache is collected.
-            y_k = kops.ssd(x, bm, cm, dt, a_log, d_skip, chunk=chunk)
-            _, state_k = _final_state_only(x, bm, dt, a_log)
-            return y_k, state_k
     b, t, h, p = x.shape
     grp = bm.shape[2]
     n = bm.shape[3]

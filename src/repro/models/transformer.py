@@ -170,7 +170,7 @@ def apply_block_decode_paged(
     """Single-token block step against one layer's page arena slice.
 
     Same math as :func:`apply_block_decode` but the KV cache is
-    ``(num_pages, page, KV, hd)`` shared across requests, addressed through
+    ``(num_pages, KV, page, hd)`` shared across requests, addressed through
     the batch's block table: scatter the new token's K/V into its page,
     then attend through the table (kernel indirection on TPU, contiguous
     gather elsewhere). Attention kinds only — SSM state is recurrent, not
@@ -362,7 +362,7 @@ def apply_stack_decode_paged(
 ):
     """One decode step through the stack against a paged arena.
 
-    ``arena``: ``{'k','v'}`` of shape (L, num_pages, page, KV, hd) — the
+    ``arena``: ``{'k','v'}`` of shape (L, num_pages, KV, page, hd) — the
     stage's slice of the shared pool. Like :func:`apply_stack_decode`'s
     carry mode, the arena rides in the scan CARRY with per-layer in-place
     dynamic updates, so the whole pool stays ONE buffer through the stack

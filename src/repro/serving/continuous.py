@@ -80,7 +80,7 @@ class _Request:
         "inputs", "max_new_tokens", "eos_id", "slo", "future",
         "t_submit", "t_alloc", "t_admit", "tokens", "step_s", "seq_id",
         "cur_len", "remaining", "next_token", "last_emit", "job",
-        "span", "psid",
+        "span", "psid", "first_logits",
     )
 
     def __init__(self, inputs, max_new_tokens, eos_id, slo, future, t_submit):
@@ -102,6 +102,7 @@ class _Request:
         self.job = None  # PagedPrefillJob while the chunked prefill runs
         self.span = None  # obs.SpanContext root (None when tracing off)
         self.psid = None  # pre-allocated prefill-stall span id (chunk parent)
+        self.first_logits = None  # (1, V) device array from the prefill
 
 
 class ContinuousBatcher:
@@ -172,7 +173,8 @@ class ContinuousBatcher:
                slo: SLOClass | None = None, eos_id: int | None = None) -> Future:
         """Admit one generation request. Returns a Future resolving to
         ``{"tokens": (1, n) int32, "step_s": per-token seconds, "pages":
-        peak pages held, "queued_s": lane wait}``."""
+        peak pages held, "queued_s": lane wait, "first_logits": (1, V)
+        prefill logits}``."""
         slo = slo or BEST_EFFORT
         b = jax.tree.leaves(inputs)[0].shape[0]
         if b != 1:
@@ -331,6 +333,7 @@ class ContinuousBatcher:
             req.span.emit("prefill-stall", "prefill-stall", req.t_alloc,
                           req.t_admit, span_id=req.psid)
         req.last_emit = req.t_admit  # first token emitted at admission
+        req.first_logits = logits
         req.remaining = req.max_new_tokens
         first = int(np.asarray(_greedy_token(jnp.asarray(logits)))[0, 0])
         req.tokens.append(first)
@@ -442,6 +445,7 @@ class ContinuousBatcher:
             "pages": pages,
             "amortized_pages": amortized,
             "queued_s": req.t_admit - req.t_submit,
+            "first_logits": req.first_logits,
         })
 
     def _step(self) -> None:

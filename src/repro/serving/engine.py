@@ -92,25 +92,33 @@ def _pick_groups(n_layers: int, requested: int) -> int:
 
 
 class ServingEngine:
+    """Deploys ``model`` as a function chain on ``platform``.
+
+    Weights are held once, by the deployed functions: each stage's spec
+    holds the only copy of its layers and the engine keeps no full tree.
+    With ``params=None`` the blocks chain draws every stage's weights
+    directly from ``seed`` (a full-width model never exists as one stacked
+    tree); a caller that passes ``params`` keeps its own tree."""
+
     def __init__(self, model: Model, platform: ProvusePlatform, *, max_len: int = 256,
-                 params=None, trust_domain: str | None = None,
+                 params=None, seed: int = 0, trust_domain: str | None = None,
                  kv_pages: int = 0, kv_page_size: int = 16):
         self.model = model
         self.cfg = model.cfg
         self.platform = platform
         self.max_len = max_len
-        self.params = params if params is not None else model.init(jax.random.PRNGKey(0))
         self.prefix = self.cfg.name
         self.trust = trust_domain or self.cfg.name
         self.entry = f"{self.prefix}/embed"
         self.arena: KVArena | None = None
+        key = jax.random.PRNGKey(seed)
         fam = self.cfg.family
         if fam in ("dense", "moe", "vlm", "ssm"):
-            self._deploy_blocks_chain()
+            self._deploy_blocks_chain(params, key)
         elif fam == "audio":
-            self._deploy_encdec_chain()
+            self._deploy_encdec_chain(params if params is not None else model.init(key))
         elif fam == "hybrid":
-            self._deploy_monolithic_chain()
+            self._deploy_monolithic_chain(params if params is not None else model.init(key))
         else:
             raise ValueError(fam)
         if kv_pages:
@@ -118,7 +126,7 @@ class ServingEngine:
 
     # ------------------------------------------------------------ chains
 
-    def _deploy_blocks_chain(self) -> None:
+    def _deploy_blocks_chain(self, params, key) -> None:
         cfg = self.cfg
         L = cfg.num_layers
         g = _pick_groups(L, cfg.num_function_groups)
@@ -126,6 +134,18 @@ class ServingEngine:
         kind = "moe" if cfg.family == "moe" else ("ssm" if cfg.family == "ssm" else "dense")
         names = [f"{self.prefix}/g{i}" for i in range(g)]
         head_name = f"{self.prefix}/head"
+        if params is None:  # draw stage by stage: no full stacked tree
+            keys = jax.random.split(key, g + 1)
+            defs = self.model.param_defs
+            shared = init_params({"embed": defs["embed"], "ln_f": defs["ln_f"]}, keys[0])
+            group_params = [
+                init_params(tfm.stack_block_defs(cfg, kind, per), keys[1 + i]) for i in range(g)
+            ]
+        else:
+            shared = {"embed": params["embed"], "ln_f": params["ln_f"]}
+            group_params = [
+                _slice_tree(params["blocks"], i * per, (i + 1) * per) for i in range(g)
+            ]
 
         def embed_fn(ctx, params, inputs, cur_len, caches):
             if "tokens" in inputs:
@@ -135,7 +155,7 @@ class ServingEngine:
             return ctx.call(names[0], x, cur_len, caches)
 
         self.platform.deploy(
-            FunctionSpec(self.entry, embed_fn, {"table": self.params["embed"]["table"]}, self.trust)
+            FunctionSpec(self.entry, embed_fn, {"table": shared["embed"]["table"]}, self.trust)
         )
 
         def make_group_fn(i: int):
@@ -179,11 +199,8 @@ class ServingEngine:
 
             return group_fn
 
-        blocks = self.params["blocks"]
         for i, name in enumerate(names):
-            self.platform.deploy(
-                FunctionSpec(name, make_group_fn(i), _slice_tree(blocks, i * per, (i + 1) * per), self.trust)
-            )
+            self.platform.deploy(FunctionSpec(name, make_group_fn(i), group_params[i], self.trust))
 
         def head_fn(ctx, params, x, cur_len, caches):
             if isinstance(caches, dict) and "chunk_valid" in caches:
@@ -196,13 +213,11 @@ class ServingEngine:
             logits = unembed(params["embed"], h)[:, 0]
             return logits, caches
 
-        self.platform.deploy(
-            FunctionSpec(head_name, head_fn, {"ln_f": self.params["ln_f"], "embed": self.params["embed"]}, self.trust)
-        )
+        self.platform.deploy(FunctionSpec(head_name, head_fn, shared, self.trust))
         self.group_names = names
         self.kind = kind
 
-    def _deploy_encdec_chain(self) -> None:
+    def _deploy_encdec_chain(self, params) -> None:
         cfg = self.cfg
         dec_name = f"{self.prefix}/decoder"
 
@@ -233,18 +248,18 @@ class ServingEngine:
             logits = unembed(params["embed"], h)[:, 0]
             return logits, caches
 
-        enc_params = {"encoder": self.params["encdec"]["encoder"]}
+        enc_params = {"encoder": params["encdec"]["encoder"]}
         dec_params = {
-            "encdec": {"decoder": self.params["encdec"]["decoder"]},
-            "embed": self.params["embed"],
-            "ln_f": self.params["ln_f"],
+            "encdec": {"decoder": params["encdec"]["decoder"]},
+            "embed": params["embed"],
+            "ln_f": params["ln_f"],
         }
         # encode() expects params["encoder"]; decoder fns expect the nested form
         self.platform.deploy(FunctionSpec(self.entry, enc_fn, enc_params, self.trust))
         self.platform.deploy(FunctionSpec(dec_name, dec_fn, dec_params, self.trust))
         self.dec_name = dec_name
 
-    def _deploy_monolithic_chain(self) -> None:
+    def _deploy_monolithic_chain(self, params) -> None:
         cfg = self.cfg
         core_name = f"{self.prefix}/core"
         head_name = f"{self.prefix}/head"
@@ -277,10 +292,10 @@ class ServingEngine:
             logits = unembed(params["embed"], h)[:, 0]
             return logits, caches
 
-        self.platform.deploy(FunctionSpec(self.entry, embed_fn, {"table": self.params["embed"]["table"]}, self.trust))
-        self.platform.deploy(FunctionSpec(core_name, core_fn, self.params["hybrid"], self.trust))
+        self.platform.deploy(FunctionSpec(self.entry, embed_fn, {"table": params["embed"]["table"]}, self.trust))
+        self.platform.deploy(FunctionSpec(core_name, core_fn, params["hybrid"], self.trust))
         self.platform.deploy(
-            FunctionSpec(head_name, head_fn, {"ln_f": self.params["ln_f"], "embed": self.params["embed"]}, self.trust)
+            FunctionSpec(head_name, head_fn, {"ln_f": params["ln_f"], "embed": params["embed"]}, self.trust)
         )
 
     # ------------------------------------------------------- provisioning
@@ -333,7 +348,7 @@ class ServingEngine:
         return self.cfg.family in ("dense", "moe", "vlm")
 
     def enable_paging(self, num_pages: int, page_size: int = 16) -> KVArena:
-        """Preallocate the shared KV arena: one (layers, pages, page, KV, hd)
+        """Preallocate the shared KV arena: one (layers, pages, KV, page, hd)
         pool per chain stage, one allocator/block table across stages."""
         if not self.paging_supported:
             raise ValueError(f"paged KV unsupported for family {self.cfg.family!r}")

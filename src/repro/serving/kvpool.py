@@ -7,7 +7,9 @@ stacks/splits those pytrees through the batching boundary. The arena
 replaces that with ONE preallocated page pool per chain stage:
 
 * every stage owns ``k``/``v`` arrays of shape
-  ``(stage_layers, num_pages, page_size, kv_heads, head_dim)``;
+  ``(stage_layers, num_pages, kv_heads, page_size, head_dim)`` — head-major
+  pages, so the attention kernels' page blocks end in (page, hd) tiles the
+  TPU compiler accepts, with no transpose per step;
 * a sequence holds ``ceil(cur_len / page_size)`` pages, tracked in a host-
   side block table (sequence -> physical page ids, in logical order);
 * pages are allocated at prefill (copy-on-prefill scatters the dense
@@ -123,8 +125,8 @@ class KVArena:
         self.dtype = jnp.dtype(dtype)
         self.data: dict[str, dict[str, jax.Array]] = {
             name: {
-                "k": jnp.zeros((n_layers, num_pages, page_size, kv_heads, head_dim), self.dtype),
-                "v": jnp.zeros((n_layers, num_pages, page_size, kv_heads, head_dim), self.dtype),
+                "k": jnp.zeros((n_layers, num_pages, kv_heads, page_size, head_dim), self.dtype),
+                "v": jnp.zeros((n_layers, num_pages, kv_heads, page_size, head_dim), self.dtype),
             }
             for name, n_layers in self.stages.items()
         }
@@ -546,6 +548,7 @@ class KVArena:
                     chunks = src[:, lo:span].reshape(
                         src.shape[0], n - skip, self.page_size, self.kv_heads, self.head_dim
                     )
+                    chunks = jnp.swapaxes(chunks, 2, 3)  # head-major pages
                     dst[kv] = dst[kv].at[:, ids].set(chunks.astype(self.dtype))
 
     def swap_data(self, stage: str, new: dict) -> None:
@@ -572,7 +575,7 @@ class KVArena:
         row = jnp.asarray(row_np)
         out = {}
         for kv in ("k", "v"):
-            pages_v = self.data[stage][kv][:, row]  # (L, width, page, kv, hd)
+            pages_v = jnp.swapaxes(self.data[stage][kv][:, row], 2, 3)  # (L, width, page, kv, hd)
             l = pages_v.shape[0]
             out[kv] = pages_v.reshape(l, width * self.page_size, self.kv_heads, self.head_dim)
         return out

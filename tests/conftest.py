@@ -6,13 +6,3 @@ import sys
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-
-# REPRO_COMPILE_CACHE=<dir> (set by CI with an actions/cache'd directory):
-# persist XLA executables across test runs so repeat compiles restore
-# instead of rebuild. A no-op when the variable is unset.
-try:
-    from repro.launch.compile_cache import maybe_enable_from_env
-
-    maybe_enable_from_env()
-except Exception:  # pragma: no cover - cache is an optimization, never a gate
-    pass

@@ -86,6 +86,51 @@ def test_environment_key_covers_dispatch_mode():
     assert environment_key() == environment_key()
 
 
+@pytest.fixture
+def _restore_cache_config():
+    keys = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
+            "jax_persistent_cache_min_entry_size_bytes",
+            "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    yield
+    from jax.experimental.compilation_cache import compilation_cache
+
+    for k, v in saved.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
+
+
+def test_compile_cache_default_is_fixed_in_checkout(monkeypatch, _restore_cache_config):
+    """Unset JAX_COMPILATION_CACHE_DIR: the cache goes to the checkout's own
+    ``.jax_compile_cache`` — the same path on every call, so a later process
+    finds what an earlier one wrote."""
+    import pathlib
+
+    from repro.launch import compile_cache as cc
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    checkout = pathlib.Path(__file__).resolve().parents[1]
+    assert cc.cache_dir() == str(checkout / ".jax_compile_cache") == cc.cache_dir()
+    assert cc.enable_persistent_cache() == cc.DEFAULT_CACHE_DIR
+    assert jax.config.jax_compilation_cache_dir == cc.DEFAULT_CACHE_DIR
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+
+
+def test_compile_cache_honours_env_dir(monkeypatch, tmp_path, _restore_cache_config):
+    """A set JAX_COMPILATION_CACHE_DIR wins, and the code sets no other
+    directory (JAX reads the variable itself)."""
+    from repro.launch import compile_cache as cc
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
+    before = jax.config.jax_compilation_cache_dir
+    assert cc.cache_dir() == str(tmp_path / "cc")
+    assert cc.enable_persistent_cache() == str(tmp_path / "cc")
+    assert (tmp_path / "cc").is_dir()
+    assert jax.config.jax_compilation_cache_dir == before
+    cc.disable_persistent_cache()
+    assert not jax.config.jax_enable_compilation_cache
+
+
 def test_executable_index_lru_and_counters():
     idx = ExecutableIndex(max_entries=2)
     e = dataclasses.make_dataclass("E", [("compile_s", float)])(0.5)

@@ -21,10 +21,8 @@ SCRIPT = textwrap.dedent(
     sys.path.insert(0, "src")
     from repro.launch.hlo_analysis import analyze
 
-    kw = {}
-    if hasattr(jax.sharding, "AxisType"):  # jax >= 0.5; Auto is the default before
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * 2
-    mesh = jax.make_mesh((4, 2), ("data", "model"), devices=jax.devices(), **kw)
+    mesh = jax.make_mesh((4, 2), ("data", "model"), devices=jax.devices(),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     L, B, D = 12, 64, 128
 
     def f(x, ws):
@@ -41,8 +39,6 @@ SCRIPT = textwrap.dedent(
         compiled = jax.jit(f).lower(xs, ws).compile()
     s = analyze(compiled.as_text())
     raw = compiled.cost_analysis()
-    if isinstance(raw, (list, tuple)):  # jax < 0.5 wraps it in a list
-        raw = raw[0]
     print(json.dumps({
         "flops": s.flops,
         "bytes": s.bytes,

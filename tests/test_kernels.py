@@ -59,8 +59,8 @@ def test_paged_decode_attention_vs_contiguous(b, pages, page, h, kv, hd, dtype):
 
     ks = jax.random.split(RNG, 5)
     q = jax.random.normal(ks[0], (b, h, hd), jnp.float32).astype(dtype)
-    kp = jax.random.normal(ks[1], (pages, page, kv, hd), jnp.float32).astype(dtype)
-    vp = jax.random.normal(ks[2], (pages, page, kv, hd), jnp.float32).astype(dtype)
+    kp = jax.random.normal(ks[1], (pages, kv, page, hd), jnp.float32).astype(dtype)
+    vp = jax.random.normal(ks[2], (pages, kv, page, hd), jnp.float32).astype(dtype)
     width = 3
     # ragged sequences through a shuffled table; padded rows hit page 0
     bt = jax.random.randint(ks[3], (b, width), 1, pages, jnp.int32)
@@ -78,8 +78,8 @@ def test_paged_decode_attention_scratch_rows_masked():
     ks = jax.random.split(RNG, 3)
     b, pages, page, h, kv, hd = 2, 4, 128, 2, 2, 32
     q = jax.random.normal(ks[0], (b, h, hd), jnp.float32)
-    kp = jax.random.normal(ks[1], (pages, page, kv, hd), jnp.float32)
-    vp = jax.random.normal(ks[2], (pages, page, kv, hd), jnp.float32)
+    kp = jax.random.normal(ks[1], (pages, kv, page, hd), jnp.float32)
+    vp = jax.random.normal(ks[2], (pages, kv, page, hd), jnp.float32)
     bt = jnp.array([[1, 2], [0, 0]], jnp.int32)
     cur = jnp.array([page + 7, 0], jnp.int32)
     out = paged_decode_attention(q, kp, vp, bt, cur, interpret=True)
@@ -88,6 +88,30 @@ def test_paged_decode_attention_scratch_rows_masked():
         q[:1], gather_pages(kp, bt[:1]), gather_pages(vp, bt[:1]), cur[:1]
     )
     np.testing.assert_allclose(np.asarray(out[:1]), np.asarray(expect), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("c,start,page,h,kv,hd,block_q", [
+    (16, 0, 128, 4, 2, 64, 128),     # first chunk of a prompt
+    (32, 200, 128, 4, 2, 64, 16),    # later chunk, two q blocks, crosses a page
+    (8, 37, 16, 2, 1, 32, 128),      # MQA, small pages, unaligned start
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_chunk_attention_vs_contiguous(c, start, page, h, kv, hd, block_q, dtype):
+    """Chunked-prefill kernel == gather-to-contiguous + causal oracle with
+    the chunk's absolute offset."""
+    from repro.kernels.paged_attention import gather_pages, paged_chunk_attention
+
+    ks = jax.random.split(RNG, 4)
+    pages, width = 9, 4
+    q = jax.random.normal(ks[0], (1, c, h, hd), jnp.float32).astype(dtype)
+    kp = jax.random.normal(ks[1], (pages, kv, page, hd), jnp.float32).astype(dtype)
+    vp = jax.random.normal(ks[2], (pages, kv, page, hd), jnp.float32).astype(dtype)
+    bt = jax.random.permutation(ks[3], jnp.arange(1, pages, dtype=jnp.int32))[None, :width]
+    out = paged_chunk_attention(q, kp, vp, bt, jnp.array([start], jnp.int32),
+                                block_q=block_q, interpret=True)
+    expect = ref.mha_ref(q, gather_pages(kp, bt), gather_pages(vp, bt), causal=True,
+                         q_offset=start)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(expect, np.float32), **tol(dtype))
 
 
 def test_decode_attention_length_edge_cases():

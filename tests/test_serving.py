@@ -41,10 +41,11 @@ def test_chain_generation_matches_direct_model():
     model = build_model(cfg)
     platform = TinyJaxBackend(FusionPolicy(min_observations=2, merge_cost_s=0.0))
     try:
-        engine = ServingEngine(model, platform, max_len=48)
+        params = model.init(jax.random.PRNGKey(0))
+        engine = ServingEngine(model, platform, max_len=48, params=params)
         tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 12), 0, cfg.vocab_size, jnp.int32)
         got, _ = engine.generate({"tokens": tokens}, steps=10)
-        expect = direct_generate(model, engine.params, tokens, 10, 48)
+        expect = direct_generate(model, params, tokens, 10, 48)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(expect))
         # fusion actually happened during generation
         assert any(m.healthy for m in platform.merger.merge_log)
