@@ -20,6 +20,7 @@ import dataclasses
 import threading
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.errors import HealthCheckError
@@ -35,6 +36,8 @@ class MergeEvent:
     build_s: float
     healthy: bool
     reason: str = ""
+    # Where the health check found the outputs apart (member, leaf, size).
+    detail: str = ""
     # Members whose canary was replayed through the live path during the
     # health check — each replay is one extra (control-plane) invocation on
     # the billing meter, so tests can account for merge traffic exactly.
@@ -57,6 +60,7 @@ class SplitEvent:
     reason: str = ""
     checked_members: tuple[str, ...] = ()
     epoch: int = 0
+    detail: str = ""
     build_s: float = 0.0
     warm: bool | None = None  # every rebuilt unit hit the executable index
 
@@ -77,28 +81,35 @@ class GroupRecord:
     baseline_rates: dict[str, float] = dataclasses.field(default_factory=dict)
 
 
-def _allclose_tree(a, b, rtol: float, atol: float) -> bool:
-    """Health-check comparison of two output trees. Floating leaves are
-    held to the leaf's own scale, ``|x - y| <= atol + rtol * max|y|``: a
-    fused program rounds its bf16 matmuls differently, and that error
-    scales with the terms a dot product sums, not with each (possibly
-    cancelled, near-zero) output element. Other leaves must match exactly."""
-    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
-    if len(la) != len(lb):
-        return False
-    for x, y in zip(la, lb):
+def _tree_mismatch(a, b, rtol: float, atol: float) -> str | None:
+    """Health-check comparison of two output trees: None when they agree,
+    else where and by how much they differ. Floating leaves are held to the
+    leaf's own scale, ``|x - y| <= atol + rtol * max|y|``: a fused program
+    rounds its bf16 matmuls differently, and that error scales with the
+    terms a dot product sums, not with each (possibly cancelled, near-zero)
+    output element. Other leaves must match exactly."""
+    pa, _ = jax.tree_util.tree_flatten_with_path(a)
+    lb = jax.tree.leaves(b)
+    if len(pa) != len(lb):
+        return f"{len(pa)} leaves vs {len(lb)}"
+    for (path, x), y in zip(pa, lb):
+        where = jax.tree_util.keystr(path) or "output"
         xa, ya = np.asarray(x), np.asarray(y)
         if xa.shape != ya.shape:
-            return False
-        if xa.dtype.kind != "f" or ya.dtype.kind != "f":
+            return f"{where}: shape {xa.shape} vs {ya.shape}"
+        # jnp.issubdtype, not dtype.kind: numpy files bfloat16 under kind "V"
+        if not (jnp.issubdtype(xa.dtype, jnp.floating) and jnp.issubdtype(ya.dtype, jnp.floating)):
             if not np.array_equal(xa, ya):
-                return False
+                return f"{where}: {xa.dtype} values differ"
             continue
         xf, yf = xa.astype(np.float64), ya.astype(np.float64)
         scale = float(np.max(np.abs(yf))) if yf.size else 0.0
-        if not np.all(np.abs(xf - yf) <= atol + rtol * scale):
-            return False
-    return True
+        diff = np.abs(xf - yf)
+        bound = atol + rtol * scale
+        if not np.all(diff <= bound):
+            return (f"{where} {ya.dtype}{list(ya.shape)}: max |d| {float(np.nanmax(diff))} "
+                    f"> {bound} on scale {scale}")
+    return None
 
 
 class Merger:
@@ -286,6 +297,7 @@ class Merger:
 
             # --- health check on captured canary traffic (warms the compile) ---
             healthy = True
+            detail = ""
             checked: list[str] = []
             for name in sorted(group):
                 canary = platform.handler.canary(name)
@@ -294,8 +306,9 @@ class Merger:
                 ref = platform._invoke_with_retry(name, canary)  # old (still-routed) path
                 got = merged.execute(name, canary)
                 checked.append(name)
-                if not _allclose_tree(ref, got, self.health_rtol, self.health_atol):
-                    healthy = False
+                bad = _tree_mismatch(got, ref, self.health_rtol, self.health_atol)
+                if bad is not None:
+                    healthy, detail = False, f"{name}: {bad}"
                     break
             if not checked:
                 healthy = False  # no canary -> cannot verify; do not swap
@@ -309,7 +322,7 @@ class Merger:
                         self._quarantined.add((caller, callee))
                         self._failed_groups.add(frozenset(group))
                 event = MergeEvent(self._clock.now(), tuple(sorted(group)), 0,
-                                   self._clock.now() - t0, False, reason, tuple(checked))
+                                   self._clock.now() - t0, False, reason, detail, tuple(checked))
                 self.merge_log.append(event)
                 self._trace_outcome("merge", event)
                 return
@@ -491,6 +504,7 @@ class Merger:
             # params) mid-check.
             fused.begin_request()
             healthy = True
+            detail = ""
             checked: list[str] = []
             try:
                 for cell in cells:
@@ -512,8 +526,9 @@ class Merger:
                         ref = fused.execute(m, canary)
                         got = units[cell].execute(m, canary)
                         checked.append(m)
-                        if not _allclose_tree(ref, got, self.health_rtol, self.health_atol):
-                            healthy = False
+                        bad = _tree_mismatch(got, ref, self.health_rtol, self.health_atol)
+                        if bad is not None:
+                            healthy, detail = False, f"{m}: {bad}"
                             break
                     if not healthy:
                         break
@@ -529,7 +544,7 @@ class Merger:
                     self._clock.now(), tuple(sorted(members)),
                     tuple(tuple(sorted(c)) for c in cells), False,
                     "health check failed" if not healthy else "no self-contained entry to verify",
-                    tuple(checked), build_s=self._clock.now() - t0,
+                    tuple(checked), detail=detail, build_s=self._clock.now() - t0,
                 )
                 self.split_log.append(event)
                 self._trace_outcome("split", event)

@@ -823,6 +823,7 @@ class ProvusePlatform:
                     "healthy": e.healthy,
                     "epoch": e.epoch,
                     "reason": e.reason,
+                    "detail": e.detail,
                     "warm": e.warm,
                 }
                 for e in self.merger.merge_log
@@ -834,6 +835,7 @@ class ProvusePlatform:
                     "healthy": e.healthy,
                     "epoch": e.epoch,
                     "reason": e.reason,
+                    "detail": e.detail,
                     "build_s": round(e.build_s, 4),
                     "warm": e.warm,
                 }

@@ -9,7 +9,8 @@ the CPU (kernels in interpret mode) as a rehearsal.
 
 What a run checks (:func:`failures`):
 
-* the platform merged at least one edge of the chain while serving;
+* the platform merged at least one edge of the chain while serving, and
+  its health check refused no merge;
 * the paged decode and paged chunk ops ran their Pallas kernels (counted
   by ``analysis.dispatch.TRACER``), never the gather fallback;
 * every request was served its tokens;
@@ -153,7 +154,8 @@ def run(cfg: ModelConfig, *, seed: int = 0, requests: int = 8, new_tokens: int =
         compiled = TRACER.delta(snap0)
         stats = platform.stats()
         merges = [m["members"] for m in stats["merges"] if m["healthy"]]
-        refused = [(m["members"], m["reason"]) for m in stats["merges"] if not m["healthy"]]
+        refused = [(m["members"], f"{m['reason']}: {m['detail']}" if m["detail"] else m["reason"])
+                   for m in stats["merges"] if not m["healthy"]]
         mem = jax.devices()[0].memory_stats() or {}
         return {
             "dispatch_mode": ops.dispatch_mode(),
@@ -185,6 +187,8 @@ def failures(report: dict) -> list[str]:
     out = []
     if not report["merges"]:
         out.append("no chain merge happened")
+    for members, reason in report["merges_refused"]:
+        out.append(f"merge of {' + '.join(members)} refused: {reason}")
     paths = report["kernel_paths"]
     kernel = "interpret" if report["dispatch_mode"] == "interpret" else "kernel"
     for op in PAGED_OPS:

@@ -9,6 +9,7 @@ import pytest
 from repro.core import FunctionSpec, FusionPolicy, OrchestratedBackend, TinyJaxBackend
 from repro.core.function import FunctionInstance
 from repro.core.handler import EdgeStats
+from repro.core.merger import _tree_mismatch
 
 BACKENDS = [TinyJaxBackend, OrchestratedBackend]
 
@@ -69,6 +70,7 @@ def test_health_check_failure_never_swaps_routing(backend_cls):
         events = p.merger.merge_log
         assert events and not events[-1].healthy
         assert events[-1].reason == "health check failed"
+        assert events[-1].detail.startswith("A: output float32[2, 8]: max |d| 100.0")
         assert events[-1].checked_members  # it DID replay canaries before aborting
         assert {n: id(p.registry.resolve(n)) for n in ("A", "B")} == before
         n_events = len(events)
@@ -81,6 +83,32 @@ def test_health_check_failure_never_swaps_routing(backend_cls):
             assert ("A", "B") not in live_members
     finally:
         p.shutdown()
+
+
+_LOGITS = np.array([[4.0, -0.01, 0.5]], np.float32)
+
+
+@pytest.mark.parametrize("got,needle", [
+    ({"logits": _LOGITS, "len": np.int32(3)}, None),
+    # 0.05 off a logit near zero is rounding on a leaf of scale 4: accepted
+    ({"logits": _LOGITS + np.float32(0.05), "len": np.int32(3)}, None),
+    # bf16 leaves are floating too (numpy files bfloat16 under kind "V")
+    ({"logits": jnp.asarray(_LOGITS + np.float32(0.05), jnp.bfloat16), "len": np.int32(3)}, None),
+    ({"logits": jnp.asarray(_LOGITS + np.float32(1.0), jnp.bfloat16), "len": np.int32(3)},
+     "['logits'] float32[1, 3]: max |d| 1.0 > 0.09"),
+    ({"logits": _LOGITS + np.float32(1.0), "len": np.int32(3)},
+     "['logits'] float32[1, 3]: max |d| 1.0"),
+    ({"logits": _LOGITS, "len": np.int32(4)}, "['len']: int32 values differ"),
+    ({"logits": _LOGITS[:, :2], "len": np.int32(3)}, "['logits']: shape (1, 2) vs (1, 3)"),
+    ({"logits": _LOGITS}, "1 leaves vs 2"),
+])
+def test_tree_mismatch_names_the_leaf_and_the_gap(got, needle):
+    ref = {"logits": _LOGITS, "len": np.int32(3)}
+    bad = _tree_mismatch(got, ref, rtol=2e-2, atol=1e-2)
+    if needle is None:
+        assert bad is None
+    else:
+        assert bad is not None and bad.startswith(needle), bad
 
 
 def test_failed_group_not_rebuilt_via_other_edges():
