@@ -2,12 +2,59 @@
 
 Each oracle is the mathematically transparent O(T^2)/dense formulation —
 slow and memory-hungry by design. Kernel tests sweep shapes/dtypes and
-assert_allclose against these.
+assert_allclose against these. ``chunked_mha_ref`` is the same attention
+query-chunked, the form ``kernels/ops.py`` runs off the TPU.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+DEFAULT_Q_CHUNK = 512
+
+
+def chunked_mha_ref(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    *,
+    causal: bool,
+    q_chunk: int = DEFAULT_Q_CHUNK,
+    q_offset: jax.Array | int = 0,
+) -> jax.Array:
+    """Query-chunked attention. q: (B,T,H,hd); k,v: (B,S,KV,hd) -> (B,T,H,hd).
+
+    ``lax.scan`` over query blocks, so the lowered program never
+    materializes a (T, S) score tensor — the jnp analogue of the
+    flash-attention memory profile, which keeps dry-run lowering at full
+    size honest. ``q_offset``: absolute position of q[0] (a chunked-prefill
+    block may start past 0)."""
+    b, t, h, hd = q.shape
+    num_kv = k.shape[2]
+    qg = q.reshape(b, t, num_kv, h // num_kv, hd)
+    s_len = k.shape[1]
+    chunk = min(q_chunk, t)
+    if t % chunk != 0:  # fall back to one block for odd lengths (tests)
+        chunk = t
+    n_chunks = t // chunk
+    qg = qg.reshape(b, n_chunks, chunk, num_kv, h // num_kv, hd)
+    k_idx = jnp.arange(s_len)
+    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
+
+    def body(carry, inp):
+        q_blk, blk_i = inp  # (B, chunk, KV, G, hd)
+        scores = jnp.einsum("btkgh,bskh->bkgts", q_blk, k, preferred_element_type=jnp.float32) * scale
+        if causal:
+            q_idx = blk_i * chunk + jnp.arange(chunk) + q_offset
+            mask = k_idx[None, :] <= q_idx[:, None]
+            scores = jnp.where(mask[None, None, None], scores, -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum("bkgts,bskh->btkgh", probs.astype(v.dtype), v)
+        return carry, out
+
+    _, outs = jax.lax.scan(body, None, (jnp.moveaxis(qg, 1, 0), jnp.arange(n_chunks)))
+    # outs: (n_chunks, B, chunk, KV, G, hd)
+    return jnp.moveaxis(outs, 0, 1).reshape(b, t, h, hd)
 
 
 def mha_ref(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True, q_offset: int = 0) -> jax.Array:

@@ -1,11 +1,12 @@
-"""GQA/MQA attention: train/prefill (chunked causal), decode (KV cache),
-and cross-attention for enc-dec.
+"""GQA/MQA attention: train/prefill (causal), decode (KV cache), paged
+decode and chunked prefill over the KV arena, and cross-attention for
+enc-dec.
 
-The full-sequence path is *query-chunked* (``lax.scan`` over query blocks) so
-the lowered program never materializes a (T, S) score tensor — the jnp
-analogue of the flash-attention memory profile. On TPU the Pallas kernels in
-``repro.kernels`` replace this path (see kernels/ops.py dispatch); the
-lowering structure (FLOPs/bytes) is equivalent for roofline purposes.
+Projections and cache updates live here; the attention itself is one call
+into ``kernels/ops.py``, which picks the Pallas kernel on a TPU and the jnp
+reference elsewhere. The reference's full-sequence path is query-chunked, so
+the lowered program never materializes a (T, S) score tensor and keeps the
+flash-attention memory profile for roofline purposes.
 """
 from __future__ import annotations
 
@@ -15,8 +16,6 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models.layers import apply_rope, rms_norm_1d
 from repro.models.params import ParamDef
-
-DEFAULT_Q_CHUNK = 512
 
 
 def attn_defs(cfg: ModelConfig):
@@ -50,63 +49,12 @@ def qkv_project(params, x: jax.Array, cfg: ModelConfig, positions: jax.Array | N
     return q, k, v
 
 
-def _gqa_scores(q: jax.Array, k: jax.Array) -> jax.Array:
-    """q: (B,Tq,KV,G,hd), k: (B,S,KV,hd) -> (B,KV,G,Tq,S) fp32."""
-    scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], jnp.float32))
-    s = jnp.einsum("btkgh,bskh->bkgts", q, k, preferred_element_type=jnp.float32)
-    return s * scale
-
-
-def _group_q(q: jax.Array, num_kv: int) -> jax.Array:
-    b, t, h, hd = q.shape
-    return q.reshape(b, t, num_kv, h // num_kv, hd)
-
-
-def full_attention(
-    q: jax.Array,
-    k: jax.Array,
-    v: jax.Array,
-    *,
-    causal: bool,
-    q_chunk: int = DEFAULT_Q_CHUNK,
-    q_offset: jax.Array | int = 0,
-) -> jax.Array:
-    """Query-chunked attention. q: (B,T,H,hd); k,v: (B,S,KV,hd) -> (B,T,H,hd).
-
-    ``q_offset``: absolute position of q[0] (for prefill-continuation /
-    chunked-prefill the query block may start past 0).
-    """
+def full_attention(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool) -> jax.Array:
+    """q: (B,T,H,hd); k,v: (B,S,KV,hd) -> (B,T,H,hd). The flash kernel on a
+    TPU, the query-chunked jnp reference elsewhere (``kernels/ops.py``)."""
     from repro.kernels import ops as kops
 
-    if kops._mode() == "kernel" and isinstance(q_offset, int) and q_offset == 0:
-        if q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0:
-            return kops.attention(q, k, v, causal=causal)  # Pallas on TPU
-    b, t, h, hd = q.shape
-    num_kv = k.shape[2]
-    qg = _group_q(q, num_kv)
-    s_len = k.shape[1]
-    chunk = min(q_chunk, t)
-    if t % chunk != 0:  # fall back to one block for odd lengths (tests)
-        chunk = t
-    n_chunks = t // chunk
-    qg = qg.reshape(b, n_chunks, chunk, num_kv, h // num_kv, hd)
-    k_idx = jnp.arange(s_len)
-
-    def body(carry, inp):
-        q_blk, blk_i = inp  # (B, chunk, KV, G, hd)
-        scores = _gqa_scores(q_blk, k)  # (B,KV,G,chunk,S) fp32
-        if causal:
-            q_idx = blk_i * chunk + jnp.arange(chunk) + q_offset
-            mask = k_idx[None, :] <= q_idx[:, None]
-            scores = jnp.where(mask[None, None, None], scores, -jnp.inf)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bkgts,bskh->btkgh", probs.astype(v.dtype), v)
-        return carry, out
-
-    _, outs = jax.lax.scan(body, None, (jnp.moveaxis(qg, 1, 0), jnp.arange(n_chunks)))
-    # outs: (n_chunks, B, chunk, KV, G, hd)
-    out = jnp.moveaxis(outs, 0, 1).reshape(b, t, h, hd)
-    return out
+    return kops.attention(q, k, v, causal=causal)
 
 
 def decode_attention(
@@ -128,18 +76,7 @@ def decode_attention(
         # dtype; the upconvert fuses into the attention kernel on TPU
         k_cache = k_cache.astype(q.dtype)
         v_cache = v_cache.astype(q.dtype)
-    if kops._mode() == "kernel" and k_cache.shape[1] % 512 == 0:
-        return kops.decode_attention(q[:, 0], k_cache, v_cache, cur_len)[:, None]
-    b, _, h, hd = q.shape
-    num_kv = k_cache.shape[2]
-    qg = _group_q(q, num_kv)  # (B,1,KV,G,hd)
-    scores = _gqa_scores(qg, k_cache)  # (B,KV,G,1,S) fp32
-    s_idx = jnp.arange(k_cache.shape[1])
-    mask = s_idx[None, :] < cur_len[:, None]  # (B,S)
-    scores = jnp.where(mask[:, None, None, None, :], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bkgts,bskh->btkgh", probs.astype(v_cache.dtype), v_cache)
-    return out.reshape(b, 1, h, hd)
+    return kops.decode_attention(q[:, 0], k_cache, v_cache, cur_len)[:, None]
 
 
 def paged_decode_attention(
@@ -154,20 +91,12 @@ def paged_decode_attention(
     q: (B,1,H,hd); pages: (P, KV, page, hd); block_table: (B, n) int32 rows
     of physical page ids (padded entries point at the arena's scratch page);
     cur_len: (B,) valid lengths. On TPU the block-table-indirect split-K
-    kernel reads pages directly; elsewhere ONE advanced-indexing gather
-    rebuilds the contiguous (B, n*page, KV, hd) view and the dense
-    ``decode_attention`` runs on it — when ``n*page`` equals the dense
-    path's ``max_len`` the two are the same program on the same values
-    (masked positions contribute exactly zero), so paging is bit-exact."""
+    kernel reads pages directly; elsewhere the pages are gathered contiguous
+    and the dense decode reference runs on them, bit-exact against a dense
+    cache of the same width (``kernels/ops.py``)."""
     from repro.kernels import ops as kops
-    from repro.kernels.paged_attention import gather_pages
 
-    out = kops.paged_decode_attention(q[:, 0], k_pages, v_pages, block_table, cur_len)
-    if out is not None:
-        return out[:, None]
-    k_cache = gather_pages(k_pages, block_table)
-    v_cache = gather_pages(v_pages, block_table)
-    return decode_attention(q, k_cache, v_cache, cur_len)
+    return kops.paged_decode_attention(q[:, 0], k_pages, v_pages, block_table, cur_len)[:, None]
 
 
 def paged_chunk_attention(
@@ -185,23 +114,10 @@ def paged_chunk_attention(
     position, exactly like the matching rows of a dense causal prefill. On
     TPU the block-table-indirect chunk kernel reads pages directly;
     elsewhere one gather rebuilds the contiguous view and the q-chunked
-    ``full_attention`` runs with ``q_offset=start`` — masked positions
-    contribute exactly zero, so chunked prefill is bit-exact vs dense."""
+    reference runs with ``q_offset=start``, bit-exact against dense."""
     from repro.kernels import ops as kops
 
-    out = kops.paged_chunk_attention(q, k_pages, v_pages, block_table, start)
-    if out is not None:
-        return out
-    k_cache = gather_pages_cast(k_pages, block_table, q.dtype)
-    v_cache = gather_pages_cast(v_pages, block_table, q.dtype)
-    return full_attention(q, k_cache, v_cache, causal=True, q_offset=start[0])
-
-
-def gather_pages_cast(pages: jax.Array, block_table: jax.Array, dtype) -> jax.Array:
-    from repro.kernels.paged_attention import gather_pages
-
-    out = gather_pages(pages, block_table)
-    return out.astype(dtype) if out.dtype != dtype else out
+    return kops.paged_chunk_attention(q, k_pages, v_pages, block_table, start)
 
 
 def attn_output(params, attn: jax.Array) -> jax.Array:

@@ -240,16 +240,10 @@ def apply_moe(params, x: jax.Array, cfg: ModelConfig, rules: LogicalRules | None
     # --- expert compute (dense GEMMs; E is the EP axis) ---
     from repro.kernels import ops as kops
 
-    if kops._mode() == "kernel" and g == 1 and cap % 128 == 0 and d % 128 == 0 and cfg.moe_d_ff % 128 == 0:
-        gate = kops.gmm(xe[0], params["wi_gate"])[None]
-        up = kops.gmm(xe[0], params["wi_up"])[None]
-        h = jax.nn.silu(gate.astype(jnp.float32)).astype(xe.dtype) * up
-        ye = kops.gmm(h[0], params["wo"])[None]  # wo: (E, f, d)
-    else:
-        gate = jnp.einsum("gecd,edf->gecf", xe, params["wi_gate"])
-        up = jnp.einsum("gecd,edf->gecf", xe, params["wi_up"])
-        h = jax.nn.silu(gate.astype(jnp.float32)).astype(xe.dtype) * up
-        ye = jnp.einsum("gecf,efd->gecd", h, params["wo"])
+    gate = kops.gmm(xe, params["wi_gate"])
+    up = kops.gmm(xe, params["wi_up"])
+    h = jax.nn.silu(gate.astype(jnp.float32)).astype(xe.dtype) * up
+    ye = kops.gmm(h, params["wo"])  # wo: (E, f, d)
     ye = shard_as(ye, ("batch", "experts", None, None), rules)
 
     # --- combine: one explicit all-gather of ye over 'model' (E-dim), then a

@@ -94,14 +94,13 @@ def _pick_groups(n_layers: int, requested: int) -> int:
 class ServingEngine:
     """Deploys ``model`` as a function chain on ``platform``.
 
-    Weights are held once, by the deployed functions: each stage's spec
-    holds the only copy of its layers and the engine keeps no full tree.
-    With ``params=None`` the blocks chain draws every stage's weights
-    directly from ``seed`` (a full-width model never exists as one stacked
-    tree); a caller that passes ``params`` keeps its own tree."""
+    Weights are drawn from ``seed`` and held once, by the deployed
+    functions: each stage's spec holds the only copy of its layers and the
+    engine keeps no full tree. The blocks chain draws every stage's weights
+    on its own, so a full-width model never exists as one stacked tree."""
 
     def __init__(self, model: Model, platform: ProvusePlatform, *, max_len: int = 256,
-                 params=None, seed: int = 0, trust_domain: str | None = None,
+                 seed: int = 0, trust_domain: str | None = None,
                  kv_pages: int = 0, kv_page_size: int = 16):
         self.model = model
         self.cfg = model.cfg
@@ -114,11 +113,11 @@ class ServingEngine:
         key = jax.random.PRNGKey(seed)
         fam = self.cfg.family
         if fam in ("dense", "moe", "vlm", "ssm"):
-            self._deploy_blocks_chain(params, key)
+            self._deploy_blocks_chain(key)
         elif fam == "audio":
-            self._deploy_encdec_chain(params if params is not None else model.init(key))
+            self._deploy_encdec_chain(model.init(key))
         elif fam == "hybrid":
-            self._deploy_monolithic_chain(params if params is not None else model.init(key))
+            self._deploy_monolithic_chain(model.init(key))
         else:
             raise ValueError(fam)
         if kv_pages:
@@ -126,7 +125,7 @@ class ServingEngine:
 
     # ------------------------------------------------------------ chains
 
-    def _deploy_blocks_chain(self, params, key) -> None:
+    def _deploy_blocks_chain(self, key) -> None:
         cfg = self.cfg
         L = cfg.num_layers
         g = _pick_groups(L, cfg.num_function_groups)
@@ -134,18 +133,12 @@ class ServingEngine:
         kind = "moe" if cfg.family == "moe" else ("ssm" if cfg.family == "ssm" else "dense")
         names = [f"{self.prefix}/g{i}" for i in range(g)]
         head_name = f"{self.prefix}/head"
-        if params is None:  # draw stage by stage: no full stacked tree
-            keys = jax.random.split(key, g + 1)
-            defs = self.model.param_defs
-            shared = init_params({"embed": defs["embed"], "ln_f": defs["ln_f"]}, keys[0])
-            group_params = [
-                init_params(tfm.stack_block_defs(cfg, kind, per), keys[1 + i]) for i in range(g)
-            ]
-        else:
-            shared = {"embed": params["embed"], "ln_f": params["ln_f"]}
-            group_params = [
-                _slice_tree(params["blocks"], i * per, (i + 1) * per) for i in range(g)
-            ]
+        keys = jax.random.split(key, g + 1)
+        defs = self.model.param_defs
+        shared = init_params({"embed": defs["embed"], "ln_f": defs["ln_f"]}, keys[0])
+        group_params = [
+            init_params(tfm.stack_block_defs(cfg, kind, per), keys[1 + i]) for i in range(g)
+        ]
 
         def embed_fn(ctx, params, inputs, cur_len, caches):
             if "tokens" in inputs:

@@ -126,6 +126,55 @@ def test_decode_attention_length_edge_cases():
         np.testing.assert_allclose(np.asarray(out), np.asarray(expect), rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("prompt,needle", [
+    (200, r"seq lengths \(200,200\) must divide blocks \(128,128\)"),  # dense prefill
+    (256, None),                                                      # aligned: the kernel
+])
+def test_kernel_mode_dense_prefill_raises_instead_of_reference(monkeypatch, prompt, needle):
+    """On a TPU every attention op runs its kernel: a dense prefill whose
+    length the flash blocks do not divide raises with the shapes from the
+    kernel wrapper, it never returns the jnp reference's answer."""
+    from repro.analysis.dispatch import TRACER
+    from repro.configs import get_arch, reduced_config
+    from repro.kernels import ops
+    from repro.models.model import build_model
+
+    model = build_model(reduced_config(get_arch("starcoder2-3b")))
+    params = jax.eval_shape(model.init, RNG)
+    batch = {"tokens": jax.ShapeDtypeStruct((1, prompt), jnp.int32)}
+    monkeypatch.setattr(ops, "_mode", lambda: "kernel")
+    TRACER.arm()
+    try:
+        before = TRACER.path_counts()
+        if needle is None:
+            # aligned shapes trace into the flash kernel (lowering it needs a TPU)
+            logits, _ = jax.eval_shape(model.prefill_fn, params, batch)
+            assert logits.shape == (1, model.cfg.vocab_size)
+        else:
+            with pytest.raises(ValueError, match=needle):
+                jax.eval_shape(model.prefill_fn, params, batch)
+        after = TRACER.path_counts()
+    finally:
+        TRACER.disarm()
+    # the op entered its kernel path, never the reference
+    assert after.get(("attention", "kernel"), 0) > before.get(("attention", "kernel"), 0)
+    assert after.get(("attention", "ref"), 0) == before.get(("attention", "ref"), 0)
+
+
+def test_kernel_mode_dense_decode_raises_instead_of_reference(monkeypatch):
+    """Same rule for the dense decode kernel: a cache width its 512-row kv
+    blocks do not divide raises with the shapes."""
+    from repro.kernels import ops
+    from repro.models import attention as attn_mod
+
+    monkeypatch.setattr(ops, "_mode", lambda: "kernel")
+    q = jax.ShapeDtypeStruct((2, 1, 4, 128), jnp.bfloat16)
+    cache = jax.ShapeDtypeStruct((2, 768, 2, 128), jnp.bfloat16)
+    cur = jax.ShapeDtypeStruct((2,), jnp.int32)
+    with pytest.raises(ValueError, match="S=768 must divide block_k=512"):
+        jax.eval_shape(attn_mod.decode_attention, q, cache, cache, cur)
+
+
 @pytest.mark.parametrize("b,t,h,grp,p,n,chunk", [
     (2, 128, 4, 1, 32, 16, 32),
     (1, 256, 2, 2, 64, 32, 64),

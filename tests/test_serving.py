@@ -36,13 +36,23 @@ def direct_generate(model, params, tokens, steps, max_len):
     return jnp.concatenate(out, axis=1)
 
 
+def deployed_params(engine):
+    """The model's full tree, rebuilt from the only copy there is: the
+    weights the deployed stages hold."""
+    spec = engine.platform.spec_of
+    head = spec(engine.chain_names()[-1]).params
+    groups = [spec(n).params for n in engine.group_names]
+    return {"embed": head["embed"], "ln_f": head["ln_f"],
+            "blocks": jax.tree.map(lambda *xs: jnp.concatenate(xs), *groups)}
+
+
 def test_chain_generation_matches_direct_model():
     cfg = reduced_config(get_arch("llama3.2-1b"))
     model = build_model(cfg)
     platform = TinyJaxBackend(FusionPolicy(min_observations=2, merge_cost_s=0.0))
     try:
-        params = model.init(jax.random.PRNGKey(0))
-        engine = ServingEngine(model, platform, max_len=48, params=params)
+        engine = ServingEngine(model, platform, max_len=48)
+        params = deployed_params(engine)
         tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 12), 0, cfg.vocab_size, jnp.int32)
         got, _ = engine.generate({"tokens": tokens}, steps=10)
         expect = direct_generate(model, params, tokens, 10, 48)

@@ -32,7 +32,7 @@ def test_chip_smoke_phases_rehearse_on_cpu(monkeypatch):
 def _report(**over):
     base = {
         "dispatch_mode": "kernel", "new_tokens": 4, "tokens_served": [4, 4],
-        "merges": [("a", "b")], "merges_refused": [],
+        "merges": [("a", "b")], "merges_refused": [], "instances_left": 1,
         "kernel_paths": {f"{op}:kernel": 3 for op in smoke.PAGED_OPS},
         "checks": [{"request": 0, "argmax_served": 5, "argmax_ref": 5,
                     "rel": 0.01, "max_abs_diff": 0.1}],
@@ -45,6 +45,7 @@ def _report(**over):
 @pytest.mark.parametrize("over,needle", [
     ({}, None),
     ({"merges": []}, "no chain merge"),
+    ({"instances_left": 2}, "not fused to one"),
     ({"merges_refused": [(("a", "b", "c"), "health check failed: c: ...")]},
      "merge of a + b + c refused"),
     ({"kernel_paths": {"paged_decode_attention:kernel": 1,
