@@ -22,6 +22,7 @@ import hashlib
 import json
 import os
 import shutil
+import tempfile
 import threading
 import time
 
@@ -228,8 +229,10 @@ class SnapshotStore:
 
     Layout: ``<dir>/<digest>/leaf_00000.npy .. leaf_NNNNN.npy + meta.json``
     where the digest is :func:`snapshot_digest` of the param tree. Writes go
-    to ``<digest>.tmp`` and ``os.rename`` into place (same crash-atomicity as
-    checkpoints); ``put`` of an already-stored tree is a metadata touch
+    to a ``<digest>.<unique>.tmp`` directory of the writer's own and
+    ``os.rename`` into place (same crash-atomicity as checkpoints; when two
+    writers race on one digest the first rename wins and the other's copy
+    is dropped); ``put`` of an already-stored tree is a metadata touch
     (content-address dedup — a fleet of same-weights functions stores one
     copy). ``restore`` opens each leaf with ``np.load(mmap_mode='r')`` so
     bytes are paged in lazily, and by default re-hashes what it read against
@@ -279,10 +282,9 @@ class SnapshotStore:
             with self._lock:
                 self.dedup_hits += 1
             return digest
-        tmp = final + ".tmp"
-        if os.path.exists(tmp):
-            shutil.rmtree(tmp)
-        os.makedirs(tmp)
+        # a staging directory of this writer's own: a concurrent put of the
+        # same content (two parks racing) must not delete files under it
+        tmp = tempfile.mkdtemp(prefix=f"{digest}.", suffix=".tmp", dir=self.directory)
         flat = _flatten_with_paths(tree)
         keys = sorted(flat)
         treedef = jax.tree_util.tree_flatten(tree)[1]
@@ -303,7 +305,12 @@ class SnapshotStore:
             np.save(os.path.join(tmp, f"leaf_{i:05d}.npy"), stored)
         with open(os.path.join(tmp, "meta.json"), "w") as f:
             json.dump(meta, f)
-        os.rename(tmp, final)  # atomic publish
+        try:
+            os.rename(tmp, final)  # atomic publish
+        except OSError:
+            if not os.path.isdir(final):
+                raise
+            shutil.rmtree(tmp)  # another writer published the same content first
         with self._lock:
             self.puts += 1
             self.put_s += time.perf_counter() - t0

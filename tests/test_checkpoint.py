@@ -1,6 +1,7 @@
 """Checkpoint manager: atomicity, bf16 round-trip, retention, async save —
 plus the snapshot store backing scale-to-zero provisioning."""
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -164,6 +165,38 @@ def test_snapshot_put_dedups_identical_content(tmp_path):
     assert store.stats()["puts"] == 1
     assert store.stats()["dedup_hits"] == 1
     assert store.stats()["entries"] == 1
+
+
+def test_snapshot_concurrent_puts_of_one_tree_publish_a_whole_snapshot(tmp_path):
+    """Two parks of the same weights (the reconciler's idle tick racing an
+    explicit scale_to_zero) put one content address at the same time. Each
+    writer stages its own directory, so neither deletes the other's files
+    and the published snapshot is always whole."""
+    tree = {"w": np.arange(64 * 64, dtype=np.float32).reshape(64, 64),
+            "b": np.ones(64, np.float32)}
+    for rnd in range(40):
+        store = SnapshotStore(str(tmp_path / f"r{rnd}"))
+        barrier = threading.Barrier(6)
+        digests, errors = [], []
+
+        def put():
+            try:
+                barrier.wait(timeout=10)
+                digests.append(store.put(tree))
+            except Exception as e:  # noqa: BLE001 - reported by the assert below
+                errors.append(e)
+
+        threads = [threading.Thread(target=put) for _ in range(6)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+        assert not any(t.is_alive() for t in threads)
+        assert errors == [] and len(digests) == 6 and len(set(digests)) == 1
+        restored = store.restore(digests[0], tree)
+        for k in tree:
+            np.testing.assert_array_equal(np.asarray(restored[k]), tree[k])
+        assert [d for d in os.listdir(store.directory) if d.endswith(".tmp")] == []
 
 
 def test_snapshot_distinct_content_distinct_digests(tmp_path):
