@@ -25,6 +25,10 @@ The tracer is a process-wide singleton (``TRACER``), disarmed by default
 ``kernels/ops.py`` reports every op entry with the path it took (kernel,
 interpret or ref), and eager entries separately (eager dispatch is
 legitimate on the unfused interpreter path).
+
+While armed it also sums the seconds spent in merge and split health checks
+(``merge_check_s``, the durations of the merger's ``merge.check`` phases):
+with compile time, the set-up cost a settled deployment has paid.
 """
 from __future__ import annotations
 
@@ -41,6 +45,7 @@ class DispatchSnapshot:
     decode_steps: int
     kernel_calls: int
     compile_s: float = 0.0  # backend compile seconds
+    merge_check_s: float = 0.0  # health-check seconds (merge.check phases)
 
 
 class DispatchTracer:
@@ -58,6 +63,7 @@ class DispatchTracer:
         "_unpatch": "_mu",
         "compiles": "_mu",
         "compile_s": "_mu",
+        "merge_check_s": "_mu",
         "host_syncs": "_mu",
         "decode_steps": "_mu",
         "kernel_calls": "_mu",
@@ -78,6 +84,7 @@ class DispatchTracer:
         self._unpatch = None  # installed patches' restore thunk, or None
         self.compiles = 0
         self.compile_s = 0.0
+        self.merge_check_s = 0.0
         self.host_syncs = 0
         self.decode_steps = 0
         self.kernel_calls: dict[str, int] = {}
@@ -177,6 +184,12 @@ class DispatchTracer:
             with self._mu:
                 self.decode_steps += 1
 
+    def note_merge_check(self, seconds: float) -> None:
+        if self._armed:
+            with self._mu:
+                if self._arm_count > 0:
+                    self.merge_check_s += seconds
+
     def note_kernel_call(self, name: str, probe=None, path: str | None = None) -> None:
         """An op entry and the ``path`` it dispatched to (``kernel`` |
         ``interpret`` | ``ref``). ``paths`` counts every entry, traced or
@@ -211,6 +224,7 @@ class DispatchTracer:
                 decode_steps=self.decode_steps,
                 kernel_calls=sum(self.kernel_calls.values()),
                 compile_s=self.compile_s,
+                merge_check_s=self.merge_check_s,
             )
 
     def delta(self, since: DispatchSnapshot) -> DispatchSnapshot:
@@ -221,6 +235,7 @@ class DispatchTracer:
             decode_steps=now.decode_steps - since.decode_steps,
             kernel_calls=now.kernel_calls - since.kernel_calls,
             compile_s=now.compile_s - since.compile_s,
+            merge_check_s=now.merge_check_s - since.merge_check_s,
         )
 
 

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analysis.dispatch import TRACER
 from repro.core.errors import HealthCheckError
 from repro.core.function import FunctionInstance
 from repro.scheduler.clock import SYSTEM_CLOCK
@@ -116,6 +117,13 @@ def _tree_mismatch(a, b, rtol: float, atol: float) -> str | None:
             return (f"{where} {ya.dtype}{list(ya.shape)}: max |d| {float(np.nanmax(diff))} "
                     f"> {bound} on scale {scale}")
     return None
+
+
+def _canary_bytes(platform, members) -> int:
+    """Bytes of the canaries a health check of ``members`` replays."""
+    return sum(int(getattr(leaf, "nbytes", 0))
+               for m in members
+               for leaf in jax.tree.leaves(platform.handler.canary(m)))
 
 
 class Merger:
@@ -305,17 +313,30 @@ class Merger:
             healthy = True
             detail = ""
             checked: list[str] = []
-            for name in sorted(group):
-                canary = platform.handler.canary(name)
-                if canary is None:
-                    continue
-                ref = platform._invoke_with_retry(name, canary)  # old (still-routed) path
-                got = merged.execute(name, canary)
-                checked.append(name)
-                bad = _tree_mismatch(got, ref, self.health_rtol, self.health_atol)
-                if bad is not None:
-                    healthy, detail = False, f"{name}: {bad}"
-                    break
+            phase = platform.tracer.phase
+            t_check = self._clock.now()
+            with phase("merge.check", control=True, kind="merge",
+                       members="+".join(sorted(group)),
+                       canary_bytes=_canary_bytes(platform, group)):
+                for name in sorted(group):
+                    canary = platform.handler.canary(name)
+                    if canary is None:
+                        continue
+                    # each replay is waited for in its own phase, so the
+                    # phases split the check's time: old path, fused unit
+                    # (its compile included), host-side comparison
+                    with phase("merge.replay_ref", control=True, member=name):
+                        ref = jax.block_until_ready(
+                            platform._invoke_with_retry(name, canary))  # old (still-routed) path
+                    with phase("merge.replay_fused", control=True, member=name):
+                        got = jax.block_until_ready(merged.execute(name, canary))
+                    checked.append(name)
+                    with phase("merge.compare", control=True, member=name):
+                        bad = _tree_mismatch(got, ref, self.health_rtol, self.health_atol)
+                    if bad is not None:
+                        healthy, detail = False, f"{name}: {bad}"
+                        break
+            TRACER.note_merge_check(self._clock.now() - t_check)
             if not checked:
                 healthy = False  # no canary -> cannot verify; do not swap
 
@@ -512,34 +533,47 @@ class Merger:
             healthy = True
             detail = ""
             checked: list[str] = []
+            phase = platform.tracer.phase
+            t_check = self._clock.now()
             try:
-                for cell in cells:
-                    for m in sorted(cell):
-                        canary = platform.handler.canary(m)
-                        if canary is None:
-                            continue
-                        if units[cell].get_compiled(m, canary) is None:
-                            # Boundary entry: replaying it would dispatch the
-                            # outbound call through live routing — i.e. queue
-                            # behind the saturated fused pod this split exists
-                            # to relieve, blocking the reconciler for the
-                            # backlog's duration and polluting edge stats and
-                            # billing with control-plane traffic. Co-members'
-                            # self-contained entries cover the rebuilt units;
-                            # compiling it here still pre-warms the post-split
-                            # eager fallback's entry cache.
-                            continue
-                        ref = fused.execute(m, canary)
-                        got = units[cell].execute(m, canary)
-                        checked.append(m)
-                        bad = _tree_mismatch(got, ref, self.health_rtol, self.health_atol)
-                        if bad is not None:
-                            healthy, detail = False, f"{m}: {bad}"
+                with phase("merge.check", control=True, kind="split",
+                           members="+".join(sorted(members)),
+                           canary_bytes=_canary_bytes(platform, members)):
+                    for cell in cells:
+                        for m in sorted(cell):
+                            canary = platform.handler.canary(m)
+                            if canary is None:
+                                continue
+                            if units[cell].get_compiled(m, canary) is None:
+                                # Boundary entry: replaying it would dispatch
+                                # the outbound call through live routing —
+                                # i.e. queue behind the saturated fused pod
+                                # this split exists to relieve, blocking the
+                                # reconciler for the backlog's duration and
+                                # polluting edge stats and billing with
+                                # control-plane traffic. Co-members'
+                                # self-contained entries cover the rebuilt
+                                # units; compiling it here still pre-warms
+                                # the post-split eager fallback's entry cache.
+                                continue
+                            # the fused unit is the reference here, the
+                            # rebuilt unit the one checked
+                            with phase("merge.replay_ref", control=True, member=m):
+                                ref = jax.block_until_ready(fused.execute(m, canary))
+                            with phase("merge.replay_split", control=True, member=m):
+                                got = jax.block_until_ready(units[cell].execute(m, canary))
+                            checked.append(m)
+                            with phase("merge.compare", control=True, member=m):
+                                bad = _tree_mismatch(got, ref, self.health_rtol,
+                                                     self.health_atol)
+                            if bad is not None:
+                                healthy, detail = False, f"{m}: {bad}"
+                                break
+                        if not healthy:
                             break
-                    if not healthy:
-                        break
             finally:
                 fused.end_request()
+                TRACER.note_merge_check(self._clock.now() - t_check)
             if not healthy or not checked:
                 for unit in units.values():
                     platform.detach_instance(unit)

@@ -628,17 +628,23 @@ class ProvusePlatform:
     # ------------------------------------------------------------- running
 
     def _run_request(self, instance: FunctionInstance, entry: str, args: tuple):
-        instance.begin_request()
-        self.handler.enter(entry, instance)
+        # host phases (profiler annotations, inert unless a trace runs):
+        # bracket, dispatch (returns once the program is queued), billing
+        phase = self.tracer.phase
+        with phase("platform.enter"):
+            instance.begin_request()
+            self.handler.enter(entry, instance)
         try:
-            out = instance.execute(entry, args)
+            with phase("platform.execute"):
+                out = instance.execute(entry, args)
         except BaseException:
             # failed attempts are not billed — the retry path would otherwise
             # double-bill the same request (swap races, redeploys)
             self.handler.abort(entry)
             raise
         else:
-            self.handler.exit(entry)
+            with phase("platform.exit"):
+                self.handler.exit(entry)
             return out
         finally:
             instance.end_request()
@@ -661,28 +667,29 @@ class ProvusePlatform:
         """Serial dispatch with swap-race recovery. Also the Merger's canary
         replay path — no latency observation here, so control-plane traffic
         never pollutes the external latency percentiles."""
-        self._ensure_live(name)
-        try:
+        with self.tracer.phase("platform.dispatch"):
+            self._ensure_live(name)
             try:
-                return self._dispatch_sync(name, args)
-            except UnknownFunctionError:
-                # raced a scale-to-zero park: the route vanished between
-                # _ensure_live and resolve — resurrect and retry (a truly
-                # unknown name stays unknown and re-raises)
-                self._ensure_live(name)
-                return self._dispatch_sync(name, args)
-            except InvocationError:
-                # A request can race a merge swap: it resolved the old
-                # instance, the Merger retired it mid-flight. Re-resolving
-                # picks up the new routing; only if THAT fails is the
-                # container actually gone and a fresh one provisioned.
                 try:
                     return self._dispatch_sync(name, args)
-                except InvocationError:
-                    self._redeploy(name)
+                except UnknownFunctionError:
+                    # raced a scale-to-zero park: the route vanished between
+                    # _ensure_live and resolve — resurrect and retry (a truly
+                    # unknown name stays unknown and re-raises)
+                    self._ensure_live(name)
                     return self._dispatch_sync(name, args)
-        finally:
-            self._drain_candidates()
+                except InvocationError:
+                    # A request can race a merge swap: it resolved the old
+                    # instance, the Merger retired it mid-flight. Re-resolving
+                    # picks up the new routing; only if THAT fails is the
+                    # container actually gone and a fresh one provisioned.
+                    try:
+                        return self._dispatch_sync(name, args)
+                    except InvocationError:
+                        self._redeploy(name)
+                        return self._dispatch_sync(name, args)
+            finally:
+                self._drain_candidates()
 
     def invoke(self, name: str, *args):
         """External (client) invocation — serial path. Mints the request's
@@ -876,7 +883,8 @@ class TinyJaxBackend(ProvusePlatform):
         self._async_pool = ThreadPoolExecutor(max_workers=async_workers, thread_name_prefix="tinyjax-async")
 
     def _dispatch_sync(self, name: str, args: tuple):
-        instance = self.registry.resolve(name)
+        with self.tracer.phase("platform.enter"):
+            instance = self.registry.resolve(name)
         return self._run_request(instance, name, args)
 
     def _dispatch_batch_impl(self, name: str, args_list: list[tuple]) -> list:

@@ -95,6 +95,7 @@ def decode_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="decode_attention",  # the kernel's HLO op name, whatever jit wraps it
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
         interpret=interpret,

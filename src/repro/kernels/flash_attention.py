@@ -98,6 +98,7 @@ def flash_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="flash_attention",  # the kernel's HLO op name, whatever jit wraps it
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, None, block_q, hd), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),

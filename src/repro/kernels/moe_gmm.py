@@ -58,6 +58,7 @@ def moe_gmm(
     kernel = functools.partial(_gmm_kernel, num_d_blocks=d // block_d)
     return pl.pallas_call(
         kernel,
+        name="moe_gmm",  # the kernel's HLO op name, whatever jit wraps it
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_c, block_d), lambda ie, ic, if_, id_: (ie, ic, id_)),

@@ -114,6 +114,7 @@ def paged_decode_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="paged_decode_attention",  # the kernel's HLO op name, whatever jit wraps it
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
         interpret=interpret,
@@ -214,6 +215,7 @@ def paged_chunk_attention(
     qh = q.reshape(b, c, kv, g, hd).transpose(0, 2, 3, 1, 4)
     out = pl.pallas_call(
         kernel,
+        name="paged_chunk_attention",  # the kernel's HLO op name, whatever jit wraps it
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
         interpret=interpret,

@@ -84,6 +84,7 @@ def ssd_scan(
     kernel = functools.partial(_ssd_kernel, chunk=chunk)
     return pl.pallas_call(
         kernel,
+        name="ssd_scan",  # the kernel's HLO op name, whatever jit wraps it
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, chunk, 1, p), lambda ib, ih, ic: (ib, ic, ih, 0)),

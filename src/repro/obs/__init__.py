@@ -11,17 +11,21 @@ from repro.obs.export import (
     serve_prometheus,
 )
 from repro.obs.trace import (
+    CLOCK_ANCHOR,
     CONTROL_TRACE_ID,
     PHASES,
     FlightRecorder,
     SpanContext,
     SpanRecord,
     Tracer,
+    anchor_offset_ns,
     live_tracers,
+    null_phase,
     retain_tracers,
 )
 
 __all__ = [
+    "CLOCK_ANCHOR",
     "CONTROL_TRACE_ID",
     "PHASES",
     "EdgeCostModel",
@@ -29,6 +33,7 @@ __all__ = [
     "SpanContext",
     "SpanRecord",
     "Tracer",
+    "anchor_offset_ns",
     "attribute",
     "attribute_trace",
     "build_trees",
@@ -37,6 +42,7 @@ __all__ = [
     "export_all_chrome",
     "export_chrome",
     "live_tracers",
+    "null_phase",
     "prometheus_text",
     "retain_tracers",
     "serve_prometheus",

@@ -23,12 +23,28 @@ bounded ring buffer behind that buffer's own (uncontended) lock; overflow
 drops the oldest record and bumps a drop counter.  The recorder never
 blocks the request path on a reader — ``snapshot()`` copies buffers one at
 a time.
+
+Host phases
+-----------
+Below the request, :meth:`Tracer.phase` marks the layers a decode step
+passes through (batcher, engine, platform) as ``jax.profiler``
+annotations: spans on the device trace's own clock, so a device idle gap
+can be set beside the host phase it fell in. They are inert (one
+``TraceAnnotation`` that records nothing) unless a profiler trace runs, and
+they never enter the flight recorder, whose rings they would flood. Rare
+phases (a merge's health check) also land on the control timeline. The
+batcher's loop emits an ``obs.clock`` annotation about once a second
+carrying the tracer clock's reading (argument ``t``, seconds), from which
+a trace reader shifts the recorder's spans onto the profiler's clock.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 from contextlib import contextmanager
+
+from jax.profiler import TraceAnnotation
 
 from repro.scheduler.clock import SYSTEM_CLOCK
 
@@ -53,6 +69,18 @@ PHASES = frozenset(
 CONTROL_TRACE_ID = 0
 
 _ROOT_SPAN_ID = 1
+
+#: What :meth:`Tracer.phase` returns with tracing off: one shared context
+#: that does nothing, so a disabled tracer constructs nothing per phase.
+_NO_PHASE = contextlib.nullcontext()
+
+#: The clock-anchor annotation's name (see the module docstring).
+CLOCK_ANCHOR = "obs.clock"
+
+
+def null_phase(name: str, **args):
+    """:meth:`Tracer.phase` for code that runs without a tracer."""
+    return _NO_PHASE
 
 
 @dataclasses.dataclass(frozen=True, slots=True)
@@ -287,6 +315,22 @@ def retain_tracers(on: bool = True) -> None:
             _RETAINED.clear()
 
 
+def anchor_offset_ns(xplane_path: str) -> float | None:
+    """The profiler's clock minus the tracer's, in ns, from the ``obs.clock``
+    anchors of a ``.xplane.pb`` (their median); None when it holds none. A
+    recorder span at tracer time ``t`` lies at ``t * 1e9 + offset`` there."""
+    from jax.profiler import ProfileData
+
+    offsets = sorted(
+        ev.start_ns - t * 1e9
+        for plane in ProfileData.from_file(xplane_path).planes
+        for line in plane.lines
+        for ev in line.events
+        if ev.name == CLOCK_ANCHOR and (t := dict(ev.stats).get("t")) is not None
+    )
+    return offsets[len(offsets) // 2] if offsets else None
+
+
 def live_tracers() -> list:
     """Live tracers in registration order (export pid order)."""
     with _REGISTRY_LOCK:
@@ -378,6 +422,28 @@ class Tracer:
         if cur is None:
             return self.activate(None)
         return self.activate(cur[0], cur[1])
+
+    # ------------------------------------------------------ host phases
+
+    def phase(self, name: str, *, control: bool = False, **args):
+        """Context manager marking a host phase ``name`` as a profiler
+        annotation with ``args`` as its arguments. ``control=True`` is for
+        rare phases: the phase also becomes a ``control_span`` over its
+        duration. With tracing disabled it is a shared null context."""
+        if not self.enabled:
+            return _NO_PHASE
+        if control:
+            return self._control_phase(name, args)
+        return TraceAnnotation(name, **args)
+
+    @contextmanager
+    def _control_phase(self, name: str, args: dict):
+        t0 = self.clock.now()
+        try:
+            with TraceAnnotation(name, **args):
+                yield
+        finally:
+            self.control_span(name, t0, self.clock.now(), args=args or None)
 
     # -------------------------------------------------- control timeline
 

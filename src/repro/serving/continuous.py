@@ -36,6 +36,12 @@ Every request's RAM bill is its pages: on exit the batcher records an
 :class:`~repro.core.billing.ArenaLease` — peak pages held x page bytes x
 residency seconds — the per-request GB-s the paper's RAM-reduction story
 is about.
+
+Host phases: the loop marks ``serve.admit``, ``serve.prefill_chunk`` and
+``serve.decode_step`` (with ``serve.step_inputs``, ``serve.token_fetch`` and
+``serve.emit`` inside) through the platform tracer's ``phase``: profiler
+annotations on the loop thread, inert unless a profiler trace runs, and
+about once a second an ``obs.clock`` anchor (see ``obs.trace``).
 """
 from __future__ import annotations
 
@@ -47,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.billing import ArenaLease
+from repro.obs.trace import CLOCK_ANCHOR, null_phase
 from repro.scheduler.adaptive import ServiceTimeEstimate
 from repro.scheduler.batching import largest_pow2_le
 from repro.scheduler.scheduler import OverloadShedError
@@ -164,6 +171,8 @@ class ContinuousBatcher:
         # queue-wait / prefill-stall (+ chunk children) / batch-compute
         # phases tile [t_submit, t_done] exactly
         self._tracer = getattr(engine.platform, "tracer", None)
+        self._phase = self._tracer.phase if self._tracer is not None else null_phase
+        self._anchor_t = float("-inf")  # clock reading of the last obs.clock
         self._thread = threading.Thread(target=self._loop, daemon=True, name="continuous-batcher")
         self._thread.start()
 
@@ -254,71 +263,86 @@ class ContinuousBatcher:
         if req.span is not None:
             req.span.finish(args={"error": error})
 
+    def _drop(self, req: _Request, exc: Exception) -> None:
+        """Fail one request whose admission, prefill or seating raised: free
+        its slot and pages, deliver the error, close its span. The loop
+        serves on."""
+        for i, held in enumerate(self._slots):
+            if held is req:
+                self._release_slot(i)
+        if req.seq_id is not None:
+            self.engine.arena.free(req.seq_id)
+        if not req.future.done():
+            _deliver(req.future, exc=exc)
+        self._fail_span(req, type(exc).__name__)
+
     def _admit(self) -> None:
         """Fill free slots from the lanes, strictest class first. Runs on
         the loop thread. The chunked path (default for token prompts)
         starts ONE prefill job and returns — the loop interleaves its
         chunks with decode steps via :meth:`_prefill_tick`, and the next
         admission waits for the job to seat. ``serialize_prefill`` (or a
-        non-token prompt) takes the old full-prefill-at-admit path."""
+        non-token prompt) takes the old full-prefill-at-admit path. A
+        request whose admission raises fails alone."""
         while True:
             if self._job is not None:
                 return  # a chunked prefill is in flight: it owns admission
-            free = [i for i, s in enumerate(self._slots) if s is None]
-            if not free:
+            if all(s is not None for s in self._slots):
                 return
             with self._cv:
                 got = self._lanes.pop()
             if got is None:
                 return
             req, slo = got
-            arena = self.engine.arena
-            t_in = jax.tree.leaves(req.inputs)[0].shape[1]
-            # the LAST decode step writes position t_in + max_new - 2; the
-            # whole lifetime must fit the table and the pool, or the request
-            # is permanently unservable: fail fast — requeueing would starve
-            # the lane forever, and admitting would blow up mid-flight and
-            # take every co-resident stream down with it
-            final_len = t_in + max(0, req.max_new_tokens - 1)
-            need = arena.pages_for(final_len)
-            if need > min(arena.num_pages - 1, self.engine.block_width):
-                _deliver(req.future, exc=ArenaFull(
-                    f"prompt {t_in} + {req.max_new_tokens} generated tokens needs "
-                    f"{need} pages; pool holds {arena.num_pages - 1}, "
-                    f"table {self.engine.block_width}"
-                ))
-                self._fail_span(req, "ArenaFull")
-                continue
-            self._seq += 1
-            req.seq_id = ("cb", self._seq)
-            # residency starts when the pages do: both admission paths
-            # allocate BEFORE running any chain, and the lease bills that too
-            req.t_alloc = self.clock.now()
-            if not self.serialize_prefill and "tokens" in req.inputs:
-                try:
-                    req.job = self.engine.begin_prefill_paged(req.seq_id, req.inputs)
-                except ArenaFull:
-                    with self._cv:
-                        self._lanes.requeue(req, slo)  # transient: residents
-                    return                             # will free pages
-                except BaseException as exc:  # noqa: BLE001 — deliver, don't kill the loop
-                    _deliver(req.future, exc=exc)
-                    self._fail_span(req, type(exc).__name__)
-                    continue
-                self._job = req
-                return
             try:
-                logits, t_in = self.engine.prefill_paged(req.seq_id, req.inputs)
-            except ArenaFull:
-                with self._cv:
-                    self._lanes.requeue(req, slo)  # transient: residents will
-                return                             # free pages; retry first
-            except BaseException as exc:  # noqa: BLE001 — deliver, don't kill the loop
-                _deliver(req.future, exc=exc)
-                self._fail_span(req, type(exc).__name__)
+                more = self._admit_one(req, slo)
+            except Exception as exc:  # noqa: BLE001 — deliver, don't kill the loop
+                self._drop(req, exc)
                 continue
-            req.cur_len = t_in
-            self._seat(req, logits)
+            if not more:
+                return
+
+    def _admit_one(self, req: _Request, slo) -> bool:
+        """Admit one popped request; False when admission must stop for now
+        (a prefill job started, or the arena is transiently full)."""
+        arena = self.engine.arena
+        t_in = jax.tree.leaves(req.inputs)[0].shape[1]
+        # the LAST decode step writes position t_in + max_new - 2; the
+        # whole lifetime must fit the table and the pool, or the request
+        # is permanently unservable: fail fast — requeueing would starve
+        # the lane forever, and admitting would blow up mid-flight and
+        # take every co-resident stream down with it
+        final_len = t_in + max(0, req.max_new_tokens - 1)
+        need = arena.pages_for(final_len)
+        if need > min(arena.num_pages - 1, self.engine.block_width):
+            _deliver(req.future, exc=ArenaFull(
+                f"prompt {t_in} + {req.max_new_tokens} generated tokens needs "
+                f"{need} pages; pool holds {arena.num_pages - 1}, "
+                f"table {self.engine.block_width}"
+            ))
+            self._fail_span(req, "ArenaFull")
+            return True
+        self._seq += 1
+        req.seq_id = ("cb", self._seq)
+        # residency starts when the pages do: both admission paths
+        # allocate BEFORE running any chain, and the lease bills that too
+        req.t_alloc = self.clock.now()
+        chunked = not self.serialize_prefill and "tokens" in req.inputs
+        try:
+            if chunked:
+                req.job = self.engine.begin_prefill_paged(req.seq_id, req.inputs)
+            else:
+                logits, t_in = self.engine.prefill_paged(req.seq_id, req.inputs)
+        except ArenaFull:
+            with self._cv:
+                self._lanes.requeue(req, slo)  # transient: residents will
+            return False                       # free pages; retry first
+        if chunked:
+            self._job = req
+            return False
+        req.cur_len = t_in
+        self._seat(req, logits)
+        return True
 
     def _seat(self, req: _Request, logits) -> None:
         """Prefill finished (either path): emit the first token and take a
@@ -378,17 +402,19 @@ class ContinuousBatcher:
         req = self._job
         if req is None:
             return False
+        with self._phase("serve.prefill_chunk"):
+            try:
+                self._advance_prefill(req)
+            except Exception as exc:  # noqa: BLE001 — deliver, don't kill the loop
+                self._job = None
+                self._drop(req, exc)
+        return True
+
+    def _advance_prefill(self, req: _Request) -> None:
         budget = self._chunk_budget(req)
         pos0 = req.job.pos
         t0 = self.clock.now()
-        try:
-            logits = self.engine.prefill_chunk_paged(req.job, budget)
-        except BaseException as exc:  # noqa: BLE001 — deliver, don't kill the loop
-            self._job = None
-            self.engine.arena.free(req.seq_id)
-            _deliver(req.future, exc=exc)
-            self._fail_span(req, type(exc).__name__)
-            return True
+        logits = self.engine.prefill_chunk_paged(req.job, budget)
         done = req.job.pos - pos0
         t1 = self.clock.now()
         if done > 0:  # a whole-prompt cache hit computes zero prompt tokens
@@ -402,12 +428,11 @@ class ContinuousBatcher:
                           parent_id=req.psid, args={"tokens": done})
         self.prefill_chunks += 1
         if logits is None:
-            return True  # more chunks to go
+            return  # more chunks to go
         self._job = None
         req.cur_len = req.job.t_in
         req.job = None
         self._seat(req, logits)
-        return True
 
     def _release_slot(self, i: int) -> None:
         """Clear a slot back to masked: all-scratch row, zero length/token."""
@@ -450,6 +475,20 @@ class ContinuousBatcher:
 
     def _step(self) -> None:
         """One decode step for the whole fixed-capacity batch."""
+        phase = self._phase
+        with phase("serve.decode_step"):
+            with phase("serve.step_inputs"):
+                active = self._step_inputs()
+                tokens = jnp.asarray(self._tok)
+            logits = self.engine.paged_decode_step(tokens, self._cur, self._bt)
+            with phase("serve.token_fetch"):
+                nxt = np.asarray(_greedy_token(jnp.asarray(logits)))
+            with phase("serve.emit"):
+                self._emit(active, nxt)
+
+    def _step_inputs(self) -> list[int]:
+        """Give every resident its write page and fill the step's token,
+        length and block-table rows; returns the slots that step."""
         width = self.engine.block_width
         active = []
         for i, req in enumerate(self._slots):
@@ -480,8 +519,10 @@ class ContinuousBatcher:
             self._tok[i, 0] = req.next_token
             self._cur[i] = req.cur_len
             active.append(i)
-        logits = self.engine.paged_decode_step(jnp.asarray(self._tok), self._cur, self._bt)
-        nxt = np.asarray(_greedy_token(jnp.asarray(logits)))
+        return active
+
+    def _emit(self, active: list[int], nxt: np.ndarray) -> None:
+        """Hand each stepped slot its token; finish the requests that end."""
         now = self.clock.now()
         self.steps += 1
         self._occupancy_sum += len(active)
@@ -501,9 +542,21 @@ class ContinuousBatcher:
                 self._release_slot(i)
                 self._finish(req)
 
+    def _clock_anchor(self) -> None:
+        """About once a second, an ``obs.clock`` annotation carrying the
+        clock's reading: where it lies on the profiler's clock maps the
+        recorder's spans there."""
+        now = self.clock.now()
+        if now - self._anchor_t >= 1.0:
+            self._anchor_t = now
+            with self._phase(CLOCK_ANCHOR, t=now):
+                pass
+
     def _loop(self) -> None:
         while True:
-            self._admit()
+            self._clock_anchor()
+            with self._phase("serve.admit"):
+                self._admit()
             # one prefill chunk rides between decode steps: residents keep
             # emitting while a joiner's prompt streams in
             prefilled = self._prefill_tick()

@@ -22,6 +22,10 @@ path (and scatter-fills the preallocated max_len cache); (B, 1) takes the
 decode path. One deployed function serves both request types, mirroring a
 FaaS function with two routes.
 
+Host phases of a paged call (``engine.decode_step`` / ``engine.prefill_chunk``
+around ``engine.caches`` and ``engine.store_back``) are the platform
+tracer's ``phase`` annotations: inert unless a profiler trace runs.
+
 Paged serving: with ``enable_paging`` the decode route can also serve from
 a shared :class:`~repro.serving.kvpool.KVArena` — ``caches`` then carries a
 block table plus each stage's page-pool slice instead of per-client dense
@@ -427,20 +431,20 @@ class ServingEngine:
             )
             self.arena.commit_prefill(job.seq_id)
             return logits
-        c = max(1, min(int(max_tokens), t_in - job.pos))
-        padded = 1 << (c - 1).bit_length()
-        buf = np.zeros((1, padded), np.int32)
-        buf[0, :c] = job.tokens[job.pos : job.pos + c]
-        row = self.arena.block_row(job.seq_id, self.block_width)
-        caches = self.paged_caches(row[None, :])
-        caches["chunk_valid"] = jnp.asarray([c], jnp.int32)
-        self.platform.handler.note_demand(self.entry)
-        logits, caches = self.platform._invoke_with_retry(
-            self.entry,
-            ({"tokens": jnp.asarray(buf)}, jnp.asarray([job.pos], jnp.int32), caches),
-        )
-        for name in self.arena.data:
-            self.arena.swap_data(name, caches[name])
+        phase = self.platform.tracer.phase
+        with phase("engine.prefill_chunk"):
+            c = max(1, min(int(max_tokens), t_in - job.pos))
+            with phase("engine.caches"):
+                padded = 1 << (c - 1).bit_length()
+                buf = np.zeros((1, padded), np.int32)
+                buf[0, :c] = job.tokens[job.pos : job.pos + c]
+                row = self.arena.block_row(job.seq_id, self.block_width)
+                caches = self.paged_caches(row[None, :])
+                caches["chunk_valid"] = jnp.asarray([c], jnp.int32)
+                args = ({"tokens": jnp.asarray(buf)}, jnp.asarray([job.pos], jnp.int32), caches)
+            self.platform.handler.note_demand(self.entry)
+            logits, caches = self.platform._invoke_with_retry(self.entry, args)
+            self._store_back(caches)
         job.pos += c
         if job.pos >= t_in:
             self.arena.commit_prefill(job.seq_id)
@@ -474,18 +478,24 @@ class ServingEngine:
         still have canaries from the (dense) prefill invocations; demand is
         noted so the fusion policy sees serve traffic as client load."""
         TRACER.note_decode_step()
-        self.platform.handler.note_demand(self.entry)
-        caches = self.paged_caches(block_table)
-        if not write_kv:
-            caches["__frozen__"] = ()
-        logits, caches = self.platform._invoke_with_retry(
-            self.entry,
-            ({"tokens": tokens}, jnp.asarray(cur_len, jnp.int32), caches),
-        )
-        if write_kv:
+        phase = self.platform.tracer.phase
+        with phase("engine.decode_step"):
+            self.platform.handler.note_demand(self.entry)
+            with phase("engine.caches"):
+                caches = self.paged_caches(block_table)
+                if not write_kv:
+                    caches["__frozen__"] = ()
+                args = ({"tokens": tokens}, jnp.asarray(cur_len, jnp.int32), caches)
+            logits, caches = self.platform._invoke_with_retry(self.entry, args)
+            if write_kv:
+                self._store_back(caches)
+        return logits
+
+    def _store_back(self, caches: dict) -> None:
+        """Keep the step's updated page pools as the arena's state."""
+        with self.platform.tracer.phase("engine.store_back"):
             for name in self.arena.data:
                 self.arena.swap_data(name, caches[name])
-        return logits
 
     def _block_table_for(self, seq_ids) -> np.ndarray:
         rows = [self.arena.block_row(s, self.block_width) for s in seq_ids]

@@ -10,7 +10,9 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +88,39 @@ def test_attention_kernel_compiles_for_v5e(one_chip, name):
     fn, args = _kernel_case(name, one_chip)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()  # the Pallas kernel is in
+
+
+def _reader_patterns(metric):
+    """The op-name patterns a roofline reader of the benchmark matches."""
+    path = os.path.join(os.path.dirname(__file__), "..", "bench", "metrics", f"{metric}.py")
+    spec = importlib.util.spec_from_file_location(f"reader_{metric}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.KERNEL
+
+
+@pytest.mark.parametrize("name,metric", [
+    ("paged_decode_attention", "paged_decode_roofline"),
+    ("paged_chunk_attention", "paged_chunk_roofline"),
+])
+def test_paged_kernel_op_carries_its_name_for_the_reader(one_chip, name, metric):
+    """Called twice, unjitted, inside a program of another name, the kernel
+    compiles to two custom calls named after it, and the roofline reader's
+    patterns match those two instructions and nothing else."""
+    jitted, args = _kernel_case(name, one_chip)
+    fn = jitted.__wrapped__  # no jit scope of the kernel's name around it
+
+    def serve_step(q, *rest):
+        return fn(q, *rest), fn(q * 2, *rest)
+
+    text = jax.jit(serve_step).lower(*args).compile().as_text()
+    ops = dict(re.findall(r"^\s*(?:ROOT )?%(\S+) = (.*)$", text, re.M))
+    patterns = _reader_patterns(metric)
+    matched = [op for op in ops if any(p in op for p in patterns)]
+    assert len(matched) == 2, matched
+    for op in matched:
+        assert op.startswith(f"{name}.")
+        assert 'custom_call_target="tpu_custom_call"' in ops[op]
 
 
 def test_paged_decode_group_step_compiles_for_v5e(one_chip, monkeypatch):
