@@ -1,21 +1,29 @@
-"""Paged flash-decoding attention — block-table indirection over the
-split-K decode schedule.
+"""Paged attention over the shared KV page arena: flash-decoding of one
+new token per sequence, and chunked prefill.
 
-Same partial-softmax sweep as ``decode_attention.py`` (grid minor axis walks
-the sequence's pages, (m, l, acc) carried in VMEM scratch), but K/V live in
-a shared page arena instead of a per-sequence contiguous buffer: the page
-index maps read the sequence's *block table* — scalar-prefetched so the
-physical page id is known before the kernel body runs and the DMA fetches
-exactly that page. Sequences of any ragged length batch together; pages
-past the length are skipped (no compute, and the index map re-points them
-at the last live page so no DMA is issued), and padded block-table entries
-point at the arena's reserved scratch page.
+K/V live in a shared page arena instead of a per-sequence contiguous
+buffer; each sequence's *block table* names its pages. The arena is
+head-major, one layer's pages are ``(num_pages, KV, page, hd)``, so one
+page of every KV head is one contiguous ``(KV, page, hd)`` block.
 
-Layout: the arena is head-major, one layer's pages are
-``(num_pages, KV, page, hd)``, so a block squeezes the page and KV-head
-axes and its last two dims are (page, hd) — the tiling the TPU compiler
-accepts. One grid cell serves the G query heads that share a KV head, so a
-page is fetched once per group and its scores are one MXU matmul.
+``paged_decode_attention`` runs one grid step per batch slot, with all KV
+heads in it. K and V stay in HBM (``pl.ANY``); the block table and lengths
+are scalar-prefetched. Inside the step a ``fori_loop`` walks the slot's
+live pages in blocks of ``ppb`` pages — its trip count is
+``ceil(cur_len / (ppb * page))``, so pages past the length cost neither a
+DMA nor an iteration, and an empty slot (cur_len 0) starts none and writes
+exact zeros. Each block's pages are copied, one ``(KV, page, hd)`` DMA per
+page for K and one for V, into a double-buffered VMEM scratch
+``(2, ppb, KV, page, hd)``: block j+1 is in flight while block j computes.
+Per KV head the scores are one ``(G, hd) x (ppb*page, hd)^T`` MXU matmul
+folded into the (m, l, acc) online-softmax state; columns at or past the
+length are masked in the last block only. ``ppb`` follows from the shapes:
+the largest divisor of the table width with at most ``MAX_BLOCK_KEYS`` keys
+per block whose double-buffered K+V scratch fits ``SCRATCH_BUDGET_BYTES``.
+
+``paged_chunk_attention`` (chunked prefill) keeps a grid over pages: its
+page index maps read the block table, and pages wholly past the chunk's
+last row are skipped.
 
 ``gather_pages`` is the non-TPU fallback: it reconstructs the contiguous
 (B, S, KV, hd) view with one advanced-indexing gather, which XLA fuses into
@@ -33,6 +41,9 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import online_softmax as osm
 
 DEFAULT_BLOCK_Q = 128
+LANES = 128  # the TPU's vector lane width
+MAX_BLOCK_KEYS = 1024  # keys per decode DMA block: one score matmul's width
+SCRATCH_BUDGET_BYTES = 4 << 20  # the decode kernel's double-buffered K+V blocks
 
 
 def gather_pages(pages: jax.Array, block_table: jax.Array) -> jax.Array:
@@ -45,37 +56,88 @@ def gather_pages(pages: jax.Array, block_table: jax.Array) -> jax.Array:
     return jnp.swapaxes(pages[block_table], 2, 3).reshape(b, n * page, kv, hd)
 
 
+def _pages_per_block(n: int, kv: int, page: int, hd: int, itemsize: int) -> int:
+    """The largest divisor of the table width ``n`` whose block holds at most
+    ``MAX_BLOCK_KEYS`` keys and whose double-buffered K and V scratch
+    (``4 * ppb * KV * page * hd * itemsize``) fits ``SCRATCH_BUDGET_BYTES``;
+    at least 1."""
+    fits = [
+        d for d in range(1, n + 1)
+        if n % d == 0 and d * page <= MAX_BLOCK_KEYS
+        and 4 * d * kv * page * hd * itemsize <= SCRATCH_BUDGET_BYTES
+    ]
+    return max(fits, default=1)
+
+
 def _paged_kernel(
-    bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-    *, scale: float, page: int, num_page_blocks: int,
+    bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, m_ref, l_ref, acc_ref,
+    *, scale: float, page: int, ppb: int,
 ):
-    ib, ik = pl.program_id(0), pl.program_id(2)
+    ib = pl.program_id(0)
     cur = len_ref[ib]
+    kv = q_ref.shape[0]
+    bk = ppb * page
+    live_pages = (cur + page - 1) // page
+    num_blocks = (cur + bk - 1) // bk
 
-    @pl.when(ik == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, osm.NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, osm.NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # an empty batcher slot (cur_len 0) computes nothing: l stays 0 and
-    # _finalize emits exact zeros, not a mean of scratch-page contents
-    @pl.when(ik * page < cur)
-    def _body():
-        s = osm.scores(q_ref[...], k_ref[...], scale)  # (G, page)
-        # logical position of this page's rows = page index * page + row
-        cols = ik * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(cols < cur, s, osm.NEG_INF)
-        m_ref[...], l_ref[...], acc_ref[...] = osm.step(
-            s, v_ref[...], m_ref[...], l_ref[...], acc_ref[...]
-        )
+    def copy_block(blk, slot, start: bool):
+        """Start (or wait for) the K and V DMAs of block ``blk``'s live pages
+        into buffer ``slot``; a page past the length is never copied."""
+        for i in range(ppb):
+            idx = blk * ppb + i
 
-    @pl.when(ik == num_page_blocks - 1)
-    def _finalize():
-        o_ref[...] = osm.finalize(l_ref[...], acc_ref[...], o_ref.dtype)
+            @pl.when(idx < live_pages)
+            def _():
+                pid = bt_ref[ib, idx]
+                for hbm, buf, sem in ((k_hbm, k_buf, sems.at[0, slot]),
+                                      (v_hbm, v_buf, sems.at[1, slot])):
+                    copy = pltpu.make_async_copy(hbm.at[pid], buf.at[slot, i], sem)
+                    if start:
+                        copy.start()
+                    else:
+                        copy.wait()
+
+    def fold(blk, slot, masked: bool):
+        for j in range(kv):  # static: one (G, hd) x (bk, hd)^T matmul per KV head
+            k = k_buf[slot, :, j].reshape(bk, -1)
+            v = v_buf[slot, :, j].reshape(bk, -1)
+            s = osm.scores(q_ref[j], k, scale)  # (G, bk)
+            if masked:  # the last block: columns at or past cur_len, and their
+                # never-copied rows of V (stale VMEM), contribute exact zeros
+                rest = cur - blk * bk
+                s = jnp.where(jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) < rest,
+                              s, osm.NEG_INF)
+                v = jnp.where(jax.lax.broadcasted_iota(jnp.int32, v.shape, 0) < rest,
+                              v, jnp.zeros_like(v))
+            m_ref[j], l_ref[j], acc_ref[j] = osm.step(s, v, m_ref[j], l_ref[j], acc_ref[j])
+
+    @pl.when(num_blocks > 0)
+    def _prime():
+        copy_block(0, 0, start=True)
+
+    def body(blk, carry):
+        slot = blk % 2
+
+        @pl.when(blk + 1 < num_blocks)
+        def _prefetch():
+            copy_block(blk + 1, 1 - slot, start=True)
+
+        copy_block(blk, slot, start=False)
+        last = blk == num_blocks - 1
+        pl.when(last)(lambda: fold(blk, slot, True))
+        pl.when(jnp.logical_not(last))(lambda: fold(blk, slot, False))
+        return carry
+
+    jax.lax.fori_loop(0, num_blocks, body, 0)
+    for j in range(kv):  # an empty slot keeps l == 0: exact zeros
+        o_ref[j] = osm.finalize(l_ref[j], acc_ref[j], o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "pages_per_block"))
 def paged_decode_attention(
     q: jax.Array,
     k_pages: jax.Array,
@@ -84,43 +146,58 @@ def paged_decode_attention(
     cur_len: jax.Array,
     *,
     interpret: bool = False,
+    pages_per_block: int | None = None,
 ) -> jax.Array:
     """q: (B, H, hd); k_pages/v_pages: (P, KV, page, hd);
-    block_table: (B, n_pages) int32; cur_len: (B,) -> (B, H, hd)."""
+    block_table: (B, n_pages) int32; cur_len: (B,) -> (B, H, hd).
+
+    ``pages_per_block`` overrides the block size derived from the shapes
+    (tests force multi-block sweeps at tiny shapes with it); it must divide
+    ``n_pages``."""
     b, h, hd = q.shape
     _, kv, page, _ = k_pages.shape
     n = block_table.shape[1]
     g = h // kv
     scale = 1.0 / (hd**0.5)
+    qh = q.reshape(b, kv, g, hd)
+    # a page DMA may only slice an HBM array whose minor dim fills whole
+    # 128-lane tiles: a narrower head goes in zero-padded (pad lanes score 0
+    # and carry 0), at the cost of one pass over the arena per call
+    lanes = -(-hd // LANES) * LANES
+    if lanes != hd:
+        pad = [(0, 0)] * 3 + [(0, lanes - hd)]
+        qh, k_pages, v_pages = (jnp.pad(x, pad) for x in (qh, k_pages, v_pages))
+    ppb = pages_per_block or _pages_per_block(n, kv, page, lanes, k_pages.dtype.itemsize)
+    if n % ppb:
+        raise ValueError(f"pages_per_block={ppb} must divide the table width {n}")
 
-    def page_map(ib, j, ik, bt, ln):  # pages past cur_len re-read the last live one
-        return (bt[ib, jnp.minimum(ik, jnp.maximum(ln[ib] - 1, 0) // page)], j, 0, 0)
-
-    kernel = functools.partial(_paged_kernel, scale=scale, page=page, num_page_blocks=n)
+    kernel = functools.partial(_paged_kernel, scale=scale, page=page, ppb=ppb)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # block_table, cur_len
-        grid=(b, kv, n),
+        grid=(b,),
         in_specs=[
-            pl.BlockSpec((None, None, g, hd), lambda ib, j, ik, bt, ln: (ib, j, 0, 0)),
-            pl.BlockSpec((None, None, page, hd), page_map),
-            pl.BlockSpec((None, None, page, hd), page_map),
+            pl.BlockSpec((None, kv, g, lanes), lambda ib, bt, ln: (ib, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((None, None, g, hd), lambda ib, j, ik, bt, ln: (ib, j, 0, 0)),
+        out_specs=pl.BlockSpec((None, kv, g, lanes), lambda ib, bt, ln: (ib, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, hd), jnp.float32),
+            pltpu.VMEM((2, ppb, kv, page, lanes), k_pages.dtype),
+            pltpu.VMEM((2, ppb, kv, page, lanes), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),  # (K, V) x buffer slot
+            pltpu.VMEM((kv, g, 1), jnp.float32),
+            pltpu.VMEM((kv, g, 1), jnp.float32),
+            pltpu.VMEM((kv, g, lanes), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         name="paged_decode_attention",  # the kernel's HLO op name, whatever jit wraps it
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, kv, g, lanes), q.dtype),
         interpret=interpret,
-    )(block_table.astype(jnp.int32), cur_len.astype(jnp.int32),
-      q.reshape(b, kv, g, hd), k_pages, v_pages)
-    return out.reshape(b, h, hd)
+    )(block_table.astype(jnp.int32), cur_len.astype(jnp.int32), qh, k_pages, v_pages)
+    return out[..., :hd].reshape(b, h, hd)
 
 
 def _paged_chunk_kernel(

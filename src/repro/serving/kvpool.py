@@ -48,10 +48,12 @@ requests — not to ``clients x max_len``;
 it held, amortized by refcount for shared ones.
 
 The allocator is host-side (plain ints under ``_lock``); the page *data*
-are device arrays updated functionally — decode programs gather pages
+are device arrays updated functionally — decode programs read pages
 through the block table and scatter the new token's K/V back (see
-``models/attention.py: paged_decode_attention`` and the Pallas kernel in
-``kernels/paged_attention.py``). Every host-side read-modify-write swap of
+``models/attention.py: paged_decode_attention``; on the TPU the Pallas
+kernel in ``kernels/paged_attention.py`` runs one grid step per batch slot
+and DMAs only the slot's live pages, several pages per block). Every
+host-side read-modify-write swap of
 ``self.data`` (prefill scatter, CoW copy, step store-back) happens under
 ``_data_lock``, so two concurrent writers can never rebase on the same
 stale array and silently drop each other's pages.

@@ -48,46 +48,60 @@ def test_decode_attention_vs_ref(b, s, h, kv, hd, dtype):
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(expect, np.float32), **tol(dtype))
 
 
-@pytest.mark.parametrize("b,pages,page,h,kv,hd", [
-    (3, 9, 128, 4, 2, 64),
-    (2, 5, 256, 8, 1, 32),   # MQA
+@pytest.mark.parametrize("b,pages,page,h,kv,hd,width,ppb,lengths", [
+    pytest.param(3, 9, 128, 4, 2, 64, 3, None, None, id="3-9-128-4-2-64"),
+    pytest.param(2, 5, 256, 8, 1, 32, 3, None, None, id="2-5-256-8-1-32"),  # MQA
+    # four 32-key blocks, the last partial; a one-page and a one-block sequence
+    pytest.param(3, 17, 16, 4, 2, 64, 8, 2, (117, 45, 3), id="multi-block-partial-last"),
+    # a length on a page boundary (3 pages) and one on a block boundary (2 blocks)
+    pytest.param(2, 17, 16, 4, 2, 64, 8, 2, (48, 64), id="page-and-block-boundary"),
+    pytest.param(1, 9, 128, 6, 2, 128, 4, None, (300,), id="one-slot-frozen-step"),
+    pytest.param(2, 9, 16, 4, 4, 32, 4, 2, (50, 17), id="mha"),  # G = 1
+    # 16-token pages, a 96-page table: 48 pages (768 keys) per block
+    pytest.param(2, 97, 16, 4, 2, 64, 96, None, (1400, 17), id="page16-wide-table"),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_decode_attention_vs_contiguous(b, pages, page, h, kv, hd, dtype):
-    """Block-table-indirect kernel == gather-to-contiguous + dense oracle."""
+def test_paged_decode_attention_vs_contiguous(b, pages, page, h, kv, hd, width, ppb, lengths, dtype):
+    """Block-table-indirect kernel == gather-to-contiguous + dense oracle,
+    over one or several DMA blocks of ``ppb`` pages (derived when None)."""
     from repro.kernels.paged_attention import gather_pages, paged_decode_attention
 
     ks = jax.random.split(RNG, 5)
     q = jax.random.normal(ks[0], (b, h, hd), jnp.float32).astype(dtype)
     kp = jax.random.normal(ks[1], (pages, kv, page, hd), jnp.float32).astype(dtype)
     vp = jax.random.normal(ks[2], (pages, kv, page, hd), jnp.float32).astype(dtype)
-    width = 3
     # ragged sequences through a shuffled table; padded rows hit page 0
     bt = jax.random.randint(ks[3], (b, width), 1, pages, jnp.int32)
-    cur = jax.random.randint(ks[4], (b,), 1, width * page, jnp.int32)
-    out = paged_decode_attention(q, kp, vp, bt, cur, interpret=True)
+    if lengths is None:
+        cur = jax.random.randint(ks[4], (b,), 1, width * page, jnp.int32)
+    else:
+        cur = jnp.array(lengths, jnp.int32)
+    out = paged_decode_attention(q, kp, vp, bt, cur, interpret=True, pages_per_block=ppb)
     expect = ref.decode_attn_ref(q, gather_pages(kp, bt), gather_pages(vp, bt), cur)
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(expect, np.float32), **tol(dtype))
 
 
 def test_paged_decode_attention_scratch_rows_masked():
-    """A masked slot (all-scratch row, cur_len 0) must produce EXACT zeros
-    — not a mean of scratch-page garbage — and not disturb live rows."""
+    """Masked slots (all-scratch rows, cur_len 0) must produce EXACT zeros
+    — not a mean of scratch-page garbage — and not disturb live rows: an
+    8-slot batch with 2 live slots, as the batcher sends its capacity."""
     from repro.kernels.paged_attention import gather_pages, paged_decode_attention
 
     ks = jax.random.split(RNG, 3)
-    b, pages, page, h, kv, hd = 2, 4, 128, 2, 2, 32
+    b, pages, page, h, kv, hd = 8, 5, 128, 2, 2, 32
     q = jax.random.normal(ks[0], (b, h, hd), jnp.float32)
     kp = jax.random.normal(ks[1], (pages, kv, page, hd), jnp.float32)
     vp = jax.random.normal(ks[2], (pages, kv, page, hd), jnp.float32)
-    bt = jnp.array([[1, 2], [0, 0]], jnp.int32)
-    cur = jnp.array([page + 7, 0], jnp.int32)
+    live = np.array([2, 5])
+    bt = jnp.zeros((b, 2), jnp.int32).at[live].set(jnp.array([[1, 2], [4, 3]], jnp.int32))
+    cur = jnp.zeros((b,), jnp.int32).at[live].set(jnp.array([page + 7, 2 * page], jnp.int32))
     out = paged_decode_attention(q, kp, vp, bt, cur, interpret=True)
-    np.testing.assert_array_equal(np.asarray(out[1]), 0.0)
+    empty = np.setdiff1d(np.arange(b), live)
+    np.testing.assert_array_equal(np.asarray(out)[empty], 0.0)
     expect = ref.decode_attn_ref(
-        q[:1], gather_pages(kp, bt[:1]), gather_pages(vp, bt[:1]), cur[:1]
+        q[live], gather_pages(kp, bt[live]), gather_pages(vp, bt[live]), cur[live]
     )
-    np.testing.assert_allclose(np.asarray(out[:1]), np.asarray(expect), rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(out)[live], np.asarray(expect), rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize("c,start,page,h,kv,hd,block_q", [

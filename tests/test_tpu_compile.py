@@ -73,6 +73,20 @@ def _kernel_case(name, one):
             paged_decode_attention,
             (s((B, H, HD)), pages, pages, table, s((B,), jnp.int32)),
         ),
+        # the chat cell's decode call: the batcher's 64 slots over a
+        # 32-page table into a 128-page arena
+        "paged_decode_attention.cell": (
+            paged_decode_attention,
+            (s((64, H, HD)), s((128, KV, PAGE, HD)), s((128, KV, PAGE, HD)),
+             s((64, 32), jnp.int32), s((64,), jnp.int32)),
+        ),
+        # StableLM-2-1.6B widths (32 MHA heads of 64): the widest page, so
+        # the tightest DMA block for the kernel's VMEM budget
+        "paged_decode_attention.mha64": (
+            paged_decode_attention,
+            (s((64, 32, 64)), s((128, 32, PAGE, 64)), s((128, 32, PAGE, 64)),
+             s((64, 32), jnp.int32), s((64,), jnp.int32)),
+        ),
         "paged_chunk_attention": (
             paged_chunk_attention,
             (s((1, CHUNK, H, HD)), pages, pages, s((1, MAX_LEN // PAGE), jnp.int32),
@@ -83,6 +97,7 @@ def _kernel_case(name, one):
 
 @pytest.mark.parametrize("name", [
     "flash_attention", "decode_attention", "paged_decode_attention", "paged_chunk_attention",
+    "paged_decode_attention.cell", "paged_decode_attention.mha64",
 ])
 def test_attention_kernel_compiles_for_v5e(one_chip, name):
     fn, args = _kernel_case(name, one_chip)
