@@ -6,8 +6,12 @@
 The cell (an entry of ``workloads`` in ``BENCHMARK.json``) names a model
 configuration (``bench/configs/<config>.json``) and a traffic mix
 (``bench/traffic/<traffic>.json``); per-layer metrics are readers in
-``bench/metrics/<name>.py``. A new cell, mix or metric is a new file; this
-script does not change.
+``bench/metrics/<name>.py``. The configuration file names, by their paths
+from the checkout's root, its plain reference (``"reference"``: a module
+with ``served_gaps``, as ``bench/reference.py``) and the module that counts
+its work (``"counts"``: the functions ``bench/flops.py`` has, which the
+readers reach as ``ctx["counts"]``). A new cell, mix, metric or model
+family is a new file; this script does not change.
 
 One run, in one process:
 
@@ -24,7 +28,7 @@ One run, in one process:
    counts, also when it finishes in the bounded drain after it;
 3. ``memory_peak_bytes`` is read, the program's state is freed, and a
    seeded sample of the finished requests, the longest among them, is
-   checked against the float32 reference (``bench/reference.py``).
+   checked against the configuration's float32 reference.
 
 ``--trace 0`` prints the cell's end-to-end metrics, ``--trace 1`` traces a
 few seconds of the window with the profiler and prints the per-layer
@@ -35,11 +39,11 @@ exits 2.
 
 ``--sweep r1,r2,...`` (not a cell) serves the mix at each offered rate in
 turn on one engine and prints one line of readings per rate, to find the
-highest rate the cell sustains. ``--control 1`` puts the fp8 control (see
-``bench/reference.py``) in the program's place on the same sample: its gaps
-are the numbers compared, so a sound control run reads ``correct`` false,
-and the program's own gaps are printed beside them. The benchmark's own
-runs never do.
+highest rate the cell sustains. ``--control 1`` puts the fp8 control of the
+configuration's reference (see ``bench/reference.py``) in the program's
+place on the same sample: its gaps are the numbers compared, so a sound
+control run reads ``correct`` false, and the program's own gaps are printed
+beside them. The benchmark's own runs never do.
 """
 from __future__ import annotations
 
@@ -48,6 +52,7 @@ import time
 T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -89,7 +94,9 @@ def read_json(path: str) -> dict:
 
 
 def load_cell(name: str) -> tuple[dict, dict, dict, dict]:
-    """(benchmark, cell, configuration, mix) for a cell name."""
+    """(benchmark, cell, configuration, mix) for a cell name; the
+    configuration's reference and counts modules are loaded here, so a
+    configuration that names either wrongly is refused before the run."""
     bench = read_json(os.path.join(ROOT, "BENCHMARK.json"))
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -98,6 +105,7 @@ def load_cell(name: str) -> tuple[dict, dict, dict, dict]:
     cfg_entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     config = read_json(os.path.join(ROOT, cfg_entry["file"]))
     mix = read_json(os.path.join(HERE, "traffic", f"{cell['traffic']}.json"))
+    model_modules(config)
     return bench, cell, config, mix
 
 
@@ -124,12 +132,30 @@ def peaks_for(kind: str) -> dict:
     return table[kind]
 
 
-def load_reader(name: str):
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  os.path.join(HERE, "metrics", f"{name}.py"))
+@functools.cache
+def load_module(path: str):
+    """The Python file at ``path`` (from the checkout's root), loaded once
+    per process."""
+    name = "bench_" + "".join(c if c.isalnum() else "_" for c in path)
+    spec = importlib.util.spec_from_file_location(name, os.path.join(ROOT, path))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_reader(name: str):
+    return load_module(f"bench/metrics/{name}.py")
+
+
+def model_modules(config: dict) -> tuple:
+    """(reference, counts): the modules the configuration file names under
+    those keys. A configuration that leaves either out is refused: no
+    reference or counts are assumed for it."""
+    for key in ("reference", "counts"):
+        if key not in config:
+            raise ValueError(f"configuration {config.get('name')!r} names no {key!r} module "
+                             f"(a path from the checkout's root)")
+    return load_module(config["reference"]), load_module(config["counts"])
 
 
 # --------------------------------------------------------------- the system
@@ -392,10 +418,11 @@ def pick_sample(served: list[Served], seed: int) -> list[Served]:
     return sample
 
 
-def check(model: dict, seed: int, sample: list[Served], limits: dict, control: bool) -> dict:
+def check(reference, model: dict, seed: int, sample: list[Served], limits: dict,
+          control: bool) -> dict:
+    """The gaps of the sample's served tokens below the best logits of the
+    configuration's ``reference`` module, each compared with its limit."""
     import numpy as np
-
-    import reference
 
     seqs = [(s.req.prompt, np.asarray(s.tokens, np.int32)) for s in sample]
     t = time.perf_counter()
@@ -507,6 +534,7 @@ def run_cell(bench: dict, cell: dict, config: dict, mix: dict, *, seed: int, sec
     from repro.launch.compile_cache import enable_persistent_cache
 
     enable_persistent_cache()
+    reference, counts = model_modules(config)
     model = dict(config["model"])
     page = config["serving"]["kv_page_size"]
     system = System(config, seed)
@@ -577,7 +605,7 @@ def run_cell(bench: dict, cell: dict, config: dict, mix: dict, *, seed: int, sec
         reduced = reduce_trace(log_dir, calls)
         shutil.rmtree(log_dir, ignore_errors=True)
         ctx = {"spans": spans, "counters": counters, "trace": reduced, "model": model,
-               "peaks": peaks, "window": win}
+               "counts": counts, "peaks": peaks, "window": win}
         metrics = {}
         for m in bench["per_layer"]:
             value = load_reader(m["name"]).read(ctx)
@@ -602,7 +630,7 @@ def run_cell(bench: dict, cell: dict, config: dict, mix: dict, *, seed: int, sec
     del served
     readings = {}
     if sample:
-        verdict = check(model, seed, sample, config["limits"], control)
+        verdict = check(reference, model, seed, sample, config["limits"], control)
         log(f"reference: {verdict['requests']} requests, {verdict['tokens']} served tokens, "
             f"{verdict['reference_s']} s")
         if control:
@@ -680,11 +708,12 @@ def main(argv=None) -> int:
     ap.add_argument("--sweep", default="", help="comma-separated offered rates (req/s)")
     args = ap.parse_args(argv)
 
-    bench, cell, config, mix = load_cell(args.workload)
+    # set before anything imports JAX, which reads them once (the
+    # configuration's reference imports it); no size cap: evicting warm-up
+    # programs would make set-up compile more with every run
     os.environ["JAX_COMPILATION_CACHE_DIR"] = CACHE_DIR
-    # no size cap: evicting warm-up programs would make set-up compile more
-    # with every run
     os.environ["JAX_COMPILATION_CACHE_MAX_SIZE"] = "-1"
+    bench, cell, config, mix = load_cell(args.workload)
     import jax
 
     info = device_info(jax)

@@ -1,19 +1,21 @@
-"""The operation and byte counts against hand counts at published sizes:
-StarCoder2-3B (GQA, a two-matrix GELU MLP) as the benchmark runs it, and
-StableLM-2-1.6B (MHA, a gated SiLU MLP) for the other shape."""
+"""The dense counts against hand counts at published sizes: StarCoder2-3B
+(GQA, a two-matrix GELU MLP) as the benchmark runs it, and StableLM-2-1.6B
+(MHA, a gated SiLU MLP) for the other shape; the roofline's least time."""
 import json
 import os
 
 import pytest
 
+import device
 import flops
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 #: StableLM-2-1.6B's sizes (huggingface.co/stabilityai/stablelm-2-1_6b)
-STABLELM = {"num_layers": 24, "d_model": 2048, "num_heads": 32, "num_kv_heads": 32,
-            "d_head": 64, "d_ff": 5632, "vocab_size": 100352, "act": "silu"}
+STABLELM = {"family": "dense", "norm": "layernorm", "num_layers": 24, "d_model": 2048,
+            "num_heads": 32, "num_kv_heads": 32, "d_head": 64, "d_ff": 5632,
+            "vocab_size": 100352, "act": "silu"}
 
 
 def model(name):
@@ -63,15 +65,17 @@ def test_prefill_chunk_flops_by_hand():
 
 def test_paged_decode_kernel_reads_each_live_key_and_value_once():
     m = model("starcoder2-3b")
-    f, b = flops.paged_decode_kernel(m, [100, 28])
-    assert f == 4 * 24 * 128 * 128
-    # K and V of 128 keys over 2 KV heads of 128, bf16, plus q in and out
-    assert b == 2 * 128 * 2 * 128 * 2 + 2 * 2 * 24 * 128 * 2
+    pairs = flops.paged_decode_kernel(m, [100, 28])
+    # K and V of 128 keys over 2 KV heads of 128, bf16, plus q in and out,
+    # in each of the 30 layers alike
+    assert pairs == [(4 * 24 * 128 * 128, 2 * 128 * 2 * 128 * 2 + 2 * 2 * 24 * 128 * 2)] * 30
 
 
 def test_paged_chunk_kernel_reads_the_prefix_and_chunk():
     m = model("stablelm-1.6b")
-    f, b = flops.paged_chunk_kernel(m, 256, 128)
+    pairs = flops.paged_chunk_kernel(m, 256, 128)
+    assert len(pairs) == 24 and len(set(pairs)) == 1
+    f, b = pairs[0]
     assert f == 4 * 32 * 64 * flops.causal_keys(256, 128)
     assert b == 2 * 384 * 32 * 64 * 2 + 2 * 128 * 32 * 64 * 2
 
@@ -79,4 +83,4 @@ def test_paged_chunk_kernel_reads_the_prefix_and_chunk():
 @pytest.mark.parametrize("f,b,bound", [(197e12, 1.0, 1.0), (1.0, 819e9 * 2, 2.0)])
 def test_roofline_takes_the_larger_bound(f, b, bound):
     peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-    assert flops.roofline_seconds(f, b, peaks) == pytest.approx(bound)
+    assert device.roofline_seconds(f, b, peaks) == pytest.approx(bound)

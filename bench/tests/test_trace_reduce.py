@@ -1,10 +1,15 @@
 """The reduction from trace to busy time, idle gaps and kernel time, on
-hand-made intervals and on a trace recorded here."""
+hand-made intervals and on a trace recorded here; the kernel rooflines and
+MFU from the configuration's counts, summed per layer call."""
+import os
+
 import jax
 import jax.numpy as jnp
+import pytest
 
 import device
 import flops
+import run
 import trace_reduce as tr
 
 Op = tr.Op
@@ -41,13 +46,16 @@ def test_gaps_are_named_by_the_host_event_that_overlaps_most():
     assert named == [["bench.call", 20e-9], ["no host event", 100e-9]]
 
 
-def _ctx(calls, marks, ops, kind_ops):
-    m = {"num_layers": 2, "d_model": 8, "num_heads": 2, "num_kv_heads": 1, "d_head": 4,
-         "d_ff": 16, "vocab_size": 32, "act": "gelu"}
+TOY = {"family": "dense", "norm": "layernorm", "num_layers": 2, "d_model": 8, "num_heads": 2,
+       "num_kv_heads": 1, "d_head": 4, "d_ff": 16, "vocab_size": 32, "act": "gelu"}
+
+
+def _ctx(calls, marks, ops, kind_ops, m=TOY, peaks=None):
     steps = {}
     for op, i in zip(ops, tr.assign(ops, [s for s, _ in marks])):
         steps.setdefault(i, []).append(op)
-    return {"model": m, "peaks": {"bf16_flops_per_s": 1e9, "hbm_bytes_per_s": 1e9},
+    return {"model": m, "counts": flops,
+            "peaks": peaks or {"bf16_flops_per_s": 1e9, "hbm_bytes_per_s": 1e9},
             "trace": {"marks": marks, "calls": calls,
                       "devices": [{"plane": "/device:TPU:0", "ops": ops, "steps": steps}]}}
 
@@ -58,19 +66,99 @@ def test_kernel_roofline_is_the_least_time_over_the_kernels_time():
     ops = [Op("fusion.1", 100, 7), Op("paged_decode_attention.1", 110, 20),
            Op("paged_decode_attention.1", 140, 20)]
     ctx = _ctx({7: ("decode", [3, 5])}, [(100, 7)], ops, None)
-    f, b = flops.paged_decode_kernel(ctx["model"], [3, 5])
+    # one layer: 8 keys, q of 2 heads of 4 in and out
+    f, b = 4 * 2 * 4 * 8, 2 * 8 * 1 * 4 * 2 + 2 * 2 * 2 * 4 * 2
     need_s = 2 * max(f / 1e9, b / 1e9)
-    assert device.kernel_roofline(ctx, "decode", ("paged_decode_attention",)) == 100.0 * need_s / 40e-9
-    assert device.kernel_roofline(ctx, "prefill", ("paged_chunk_attention",)) is None
+    assert device.kernel_roofline(ctx, "decode", ("paged_decode_attention",),
+                                  "paged_decode_kernel") == 100.0 * need_s / 40e-9
+    assert device.kernel_roofline(ctx, "prefill", ("paged_chunk_attention",),
+                                  "paged_chunk_kernel") is None
 
 
 def test_step_mfu_counts_the_model_work_over_the_steps_busy_time():
     ops = [Op("a", 1000, 100), Op("b", 1050, 100), Op("c", 2000, 50)]
     ctx = _ctx({0: ("prefill", (0, 3)), 1: ("decode", [4])}, [(1000, 0), (2000, 1)], ops, None)
     work = flops.prefill_chunk_flops(ctx["model"], 0, 3)
-    assert device.step_mfu(ctx, "prefill") == 100.0 * work / (150e-9 * 1e9)
-    assert device.step_mfu(ctx, "decode") == 100.0 * flops.decode_step_flops(
+    assert device.step_mfu(ctx, "prefill", "prefill_chunk_flops") == 100.0 * work / (150e-9 * 1e9)
+    assert device.step_mfu(ctx, "decode", "decode_step_flops") == 100.0 * flops.decode_step_flops(
         ctx["model"], [4]) / (50e-9 * 1e9)
+
+
+def _served_trace(m, peaks):
+    """A reduced trace of 12 decode steps and 4 prefill chunks at the
+    model's sizes; each call ran some ops and its kernel (a different time
+    each)."""
+    calls, marks, ops = {}, [], []
+    for k in range(16):
+        t = 10_000 * k
+        if k % 4 == 3:
+            calls[k] = ("prefill", (512 * (k // 4), 300 + 97 * k))
+            kernel = "paged_chunk_attention.2"
+        else:
+            calls[k] = ("decode", [1 + 37 * k, 900 + k, 3000 - 11 * k][: 1 + k % 3])
+            kernel = "paged_decode_attention.7"
+        marks.append((t, k))
+        ops += [Op("fusion.1", t, 800 + 13 * k), Op(kernel, t + 900, 2_000 + 101 * k),
+                Op("fusion.2", t + 3_000, 500)]
+    return _ctx(calls, marks, ops, None, m=m, peaks=peaks)
+
+
+def _parent_readings(ctx):
+    """The four readings as the harness computed them before each
+    configuration named its counts: one layer's kernel work times the
+    depth, every formula written out here."""
+    m, peaks, t = ctx["model"], ctx["peaks"], ctx["trace"]
+    L, d, h, kv, hd, f, v = (m[k] for k in ("num_layers", "d_model", "num_heads", "num_kv_heads",
+                                             "d_head", "d_ff", "vocab_size"))
+    params = d * h * hd + 2 * d * kv * hd + h * hd * d + (3 if m["act"] == "silu" else 2) * d * f
+
+    def least(fl, nb):
+        return max(fl / peaks["bf16_flops_per_s"], nb / peaks["hbm_bytes_per_s"])
+
+    out = {}
+    for kind, kernel in (("decode", "paged_decode_attention"), ("prefill", "paged_chunk_attention")):
+        work = busy = need = spent = 0
+        for i, step_ops in t["devices"][0]["steps"].items():
+            what, data = t["calls"][t["marks"][i][1]]
+            if what != kind:
+                continue
+            if kind == "decode":
+                keys, rows = sum(data), len(data)
+                work += rows * 2 * (L * params + d * v) + L * 4 * h * hd * keys
+                layer = (4 * h * hd * keys, 2 * keys * kv * hd * 2 + 2 * rows * h * hd * 2)
+            else:
+                start, rows = data
+                keys = rows * start + rows * (rows + 1) // 2
+                work += 2 * L * params * rows + L * 4 * h * hd * keys + 2 * d * v
+                layer = (4 * h * hd * keys, 2 * (start + rows) * kv * hd * 2 + 2 * rows * h * hd * 2)
+            busy += tr.union_ns(step_ops, min(o.start_ns for o in step_ops),
+                                max(o.end_ns for o in step_ops))
+            need += L * least(*layer)
+            spent += sum(o.dur_ns for o in step_ops if o.name.startswith(kernel))
+        out[f"mfu.{kind}"] = 100.0 * work / (busy / 1e9 * peaks["bf16_flops_per_s"])
+        name = "paged_decode_roofline" if kind == "decode" else "paged_chunk_roofline"
+        out[name] = 100.0 * need / (spent / 1e9)
+    return out
+
+
+READERS = ("mfu.decode", "mfu.prefill", "paged_decode_roofline", "paged_chunk_roofline")
+
+
+@pytest.mark.parametrize("config", ["starcoder2-3b", "toy"])
+def test_per_layer_sums_read_what_one_layer_times_the_depth_read(config):
+    """The per-layer-call sum over the configuration's named counts gives
+    each reading the harness gave before, to 1e-9 relative: StarCoder2-3B
+    with its own counts module and the v5e's peaks, and the hand-made toy
+    model of the fixtures above."""
+    if config == "toy":
+        m, counts, peaks = TOY, flops, None
+    else:
+        cfg = run.read_json(os.path.join(run.HERE, "configs", f"{config}.json"))
+        m, counts, peaks = cfg["model"], run.model_modules(cfg)[1], run.peaks_for("TPU v5 lite")
+    ctx = dict(_served_trace(m, peaks), counts=counts)
+    want = _parent_readings(ctx)
+    for name in READERS:
+        assert run.load_reader(name).read(ctx) == pytest.approx(want[name], rel=1e-9), name
 
 
 def test_a_recorded_trace_loads_with_its_host_annotations(tmp_path):
