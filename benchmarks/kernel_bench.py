@@ -50,8 +50,8 @@ def run() -> list[dict]:
     us = _time(jax.jit(lambda *a: ssd_chunked(*a, chunk=128)[0]), x, bm, cm, dt, al, dk)
     rows.append({"name": "ssd_chunked_512", "us_per_call": round(us, 1)})
 
-    xe = jax.random.normal(ks[0], (8, 128, 128), jnp.float32)
+    xe = jax.random.normal(ks[0], (8 * 128, 128), jnp.float32)
     w = jax.random.normal(ks[1], (8, 128, 256), jnp.float32) * 0.05
-    us = _time(jax.jit(ref.gmm_ref), xe, w)
+    us = _time(jax.jit(ref.gmm_ref), xe, w, jnp.full((8,), 128, jnp.int32))
     rows.append({"name": "moe_gmm_ref_8x128", "us_per_call": round(us, 1)})
     return rows

@@ -23,4 +23,5 @@ from repro.configs import (  # noqa: F401
     seamless_m4t_medium,
     mamba2_370m,
     zamba2_7b,
+    mellum2_12b_a2_5b,
 )

@@ -28,7 +28,9 @@ class ModelConfig:
     num_experts_per_tok: int = 0
     moe_d_ff: int = 0
     capacity_factor: float = 1.25
-    moe_impl: str = "dropping"     # dropping (capacity gather/scatter) | ragged (dropless)
+    # sharded layers only (rules given): "dropping" scatters into capacity
+    # buffers, "dropping_ep" does so expert-locally; one device is dropless
+    moe_impl: str = "dropping"
     moe_min_group_tokens: int = 0  # 0 = auto (see moe.py group heuristic)
     router_aux_weight: float = 0.01
     # --- SSM (Mamba-2 / SSD) ---
@@ -47,6 +49,19 @@ class ModelConfig:
     norm: str = "rmsnorm"
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    norm_eps: float = 1e-5
+    # --- attention pattern: with a window, layer i attends to its last
+    # ``sliding_window`` keys unless i % full_attention_every ==
+    # full_attention_offset, which attends to all (0 = every layer full) ---
+    sliding_window: int = 0
+    full_attention_every: int = 1
+    full_attention_offset: int = 0
+    # --- YaRN on the full layers (factor 0 = plain RoPE everywhere) ---
+    yarn_factor: float = 0.0
+    yarn_original_max_position: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 1.0
     tie_embeddings: bool = False
     frontend: str = "none"         # none | audio | vlm (stub embeddings per spec)
     # --- platform deployment: Provuse function-chain granularity ---
@@ -83,6 +98,13 @@ class ModelConfig:
     @property
     def sub_quadratic(self) -> bool:
         return self.family in SUBQUADRATIC_FAMILIES
+
+    def full_layer(self, index):
+        """Whether layer ``index`` (an int or a traced int32) attends to
+        every key; always true without a window."""
+        if not self.sliding_window:
+            return True
+        return index % self.full_attention_every == self.full_attention_offset
 
 
 @dataclasses.dataclass(frozen=True)
@@ -155,4 +177,8 @@ def reduced_config(cfg: ModelConfig) -> ModelConfig:
         changes.update(shared_attn_every=2, num_layers=5)  # 2 groups of 2 + tail of 1
     if cfg.num_decoder_layers:
         changes.update(num_decoder_layers=2)
+    if cfg.sliding_window:  # one whole period of the layer pattern
+        changes.update(num_layers=cfg.full_attention_every, sliding_window=16)
+        if cfg.num_experts:
+            changes.update(num_experts=min(cfg.num_experts, 8))
     return dataclasses.replace(cfg, **changes)

@@ -60,6 +60,16 @@ def tree_bytes(tree) -> int:
     return total
 
 
+#: XLA options of every program the platform compiles. Without excess
+#: precision a bfloat16 value is rounded where the code rounds it, whatever
+#: the compiler fuses around it, so a fused unit computes the bits its
+#: members compute glued. With it, a fusion may keep such a value in
+#: float32: on a TPU v5e the fused Mellum2 chain's K then differed from the
+#: glued stage's in the last bit, a MoE router split near ties the other
+#: way, and the merge health check refused the chain.
+COMPILER_OPTIONS = {"xla_allow_excess_precision": False}
+
+
 def _structs_of(tree):
     return jax.tree.map(lambda a: jax.ShapeDtypeStruct(jnp.shape(a), jnp.result_type(a)), tree)
 
@@ -286,7 +296,7 @@ class FunctionInstance:
         arg_structs = _structs_of(args)
         try:
             traced = jax.jit(run).trace(params_structs, *arg_structs)
-            compiled = traced.lower().compile()
+            compiled = traced.lower().compile(COMPILER_OPTIONS)
         except BoundaryCall:
             with self._lock:
                 self._eager_entries.add(key)
@@ -373,7 +383,7 @@ class FunctionInstance:
             # would replay the last request's side effects per padded lane.
             if traced.jaxpr.effects:
                 raise BatchingUnsupported(entry)
-            compiled = traced.lower().compile()
+            compiled = traced.lower().compile(COMPILER_OPTIONS)
         except Exception:  # noqa: BLE001 — includes BoundaryCall. Batching is an
             # optimization: anything vmap/XLA rejects (boundary dispatch, host
             # callbacks, effects) falls back to per-request execution, never

@@ -1,11 +1,16 @@
-"""Per-expert grouped GEMM — Pallas TPU kernel (MegaBlocks-style dense
-capacity buffers). [arXiv:2211.15841]
+"""Grouped GEMM over rows sorted by expert — Pallas TPU kernel (the ragged,
+dropless layout of MegaBlocks; JAX's bundled megablox ``gmm`` takes group
+sizes by scalar prefetch the same way). [arXiv:2211.15841]
 
-Computes out[e] = xe[e] @ w[e] for every expert with explicit VMEM tiling:
-grid = (E, C/bc, F/bf, D/bd); the contraction axis is minor so the (bc, bf)
-fp32 accumulator lives in scratch across the d sweep. Block sizes default to
-MXU-native 128s; per-expert capacity C is already padded to a multiple of 8
-by the MoE layer.
+``x`` holds each expert's rows one after another, every group padded to
+whole blocks of ``block_rows`` rows, so each row block belongs to one
+expert. The grid walks the row blocks; the block's expert comes from the
+scalar-prefetched group ids, and its whole ``(d, f)`` weight panel is the
+weight block. Consecutive blocks of one expert map to the same weight
+block, which Pallas does not fetch again, so each expert's weights are
+read once per call, and an expert with no rows is never fetched. Blocks
+past the last group's end map to the last block in use: they start no
+DMA and compute nothing, so their output rows are left unwritten.
 """
 from __future__ import annotations
 
@@ -16,56 +21,74 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _gmm_kernel(x_ref, w_ref, o_ref, acc_ref, *, num_d_blocks: int):
-    idb = pl.program_id(3)
-
-    @pl.when(idb == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    x = x_ref[0]  # (bc, bd)
-    w = w_ref[0]  # (bd, bf)
-    acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-
-    @pl.when(idb == num_d_blocks - 1)
-    def _finalize():
-        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+#: VMEM for the double-buffered weight panel (2 x 4.1 MB at Mellum2's
+#: 2304 x 896) with the row blocks beside it
+VMEM_LIMIT_BYTES = 48 << 20
 
 
-@functools.partial(jax.jit, static_argnames=("block_c", "block_f", "block_d", "interpret"))
+def block_groups(group_sizes: jax.Array, block_rows: int, num_blocks: int):
+    """(expert of each row block, row blocks in use (1,)) for groups of
+    ``group_sizes`` rows, each a whole number of ``block_rows`` blocks.
+    A block past the groups' end gets the last group's id."""
+    ends = jnp.cumsum(group_sizes.astype(jnp.int32))
+    starts = jnp.arange(num_blocks, dtype=jnp.int32) * block_rows
+    ids = jnp.searchsorted(ends, starts, side="right").astype(jnp.int32)
+    return jnp.minimum(ids, group_sizes.shape[0] - 1), (ends[-1:] // block_rows)
+
+
+def _live(i, active):
+    """The row block grid step ``i`` reads: itself while in use, else the
+    last block in use (no new DMA)."""
+    return jnp.minimum(i, jnp.maximum(active[0] - 1, 0))
+
+
+def _gmm_kernel(ids_ref, active_ref, x_ref, w_ref, o_ref):
+    @pl.when(pl.program_id(0) < active_ref[0])
+    def _():
+        o_ref[...] = jax.lax.dot_general(
+            x_ref[...], w_ref[0], (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def moe_gmm(
-    xe: jax.Array,
+    x: jax.Array,
     w: jax.Array,
+    group_sizes: jax.Array,
     *,
-    block_c: int = 128,
-    block_f: int = 128,
-    block_d: int = 128,
+    block_rows: int,
     interpret: bool = False,
 ) -> jax.Array:
-    """xe: (E, C, d); w: (E, d, f) -> (E, C, f)."""
-    e, c, d = xe.shape
-    f = w.shape[2]
-    block_c = min(block_c, c)
-    block_f = min(block_f, f)
-    block_d = min(block_d, d)
-    if c % block_c or f % block_f or d % block_d:
-        raise ValueError(f"dims ({c},{f},{d}) must divide blocks ({block_c},{block_f},{block_d})")
-    grid = (e, c // block_c, f // block_f, d // block_d)
+    """x: (M, d) rows sorted by expert; w: (E, d, f); group_sizes: (E,)
+    int32, each a multiple of ``block_rows`` -> (M, f). Rows past the
+    groups' sum are not written."""
+    m, d = x.shape
+    e, _, f = w.shape
+    if m % block_rows:
+        raise ValueError(f"rows M={m} must be whole blocks of {block_rows}")
+    nb = m // block_rows
+    ids, active = block_groups(group_sizes, block_rows, nb)
 
-    kernel = functools.partial(_gmm_kernel, num_d_blocks=d // block_d)
-    return pl.pallas_call(
-        kernel,
-        name="moe_gmm",  # the kernel's HLO op name, whatever jit wraps it
-        grid=grid,
+    def rows(i, ids, active):
+        return (_live(i, active), 0)
+
+    def panel(i, ids, active):
+        return (ids[_live(i, active)], 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,  # expert of each row block, blocks in use
+        grid=(nb,),
         in_specs=[
-            pl.BlockSpec((1, block_c, block_d), lambda ie, ic, if_, id_: (ie, ic, id_)),
-            pl.BlockSpec((1, block_d, block_f), lambda ie, ic, if_, id_: (ie, id_, if_)),
+            pl.BlockSpec((block_rows, d), rows),
+            pl.BlockSpec((1, d, f), panel),
         ],
-        out_specs=pl.BlockSpec((1, block_c, block_f), lambda ie, ic, if_, id_: (ie, ic, if_)),
-        out_shape=jax.ShapeDtypeStruct((e, c, f), xe.dtype),
-        scratch_shapes=[pltpu.VMEM((block_c, block_f), jnp.float32)],
+        out_specs=pl.BlockSpec((block_rows, f), rows),
+    )
+    return pl.pallas_call(
+        _gmm_kernel,
+        name="moe_gmm",  # the kernel's HLO op name, whatever jit wraps it
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, f), x.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(xe, w)
+    )(ids, active, x, w)

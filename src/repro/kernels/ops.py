@@ -56,24 +56,36 @@ def _gathered(pages, block_table, dtype):
     return out.astype(dtype) if out.dtype != dtype else out
 
 
-def attention(q, k, v, *, causal: bool = True):
-    """q: (B, T, H, hd); k, v: (B, S, KV, hd) -> (B, T, H, hd)."""
+def _no_window(name: str, window) -> None:
+    """The dense-cache kernels have no window: a windowed model serves from
+    the paged kernels, and its dense route runs off the TPU only."""
+    if window is not None:
+        raise ValueError(f"{name}: the kernel has no sliding window; a windowed "
+                         f"model serves through the paged kernels")
+
+
+def attention(q, k, v, *, causal: bool = True, window=None):
+    """q: (B, T, H, hd); k, v: (B, S, KV, hd) -> (B, T, H, hd); ``window``
+    (1,) limits each causal row to its last keys."""
     mode = _dispatch("attention", q)
     if mode == "ref":
-        return ref.chunked_mha_ref(q, k, v, causal=causal)
+        return ref.chunked_mha_ref(q, k, v, causal=causal, window=window)
+    _no_window("attention", window)
     return _flash_kernel(q, k, v, causal=causal, interpret=(mode == "interpret"))
 
 
-def decode_attention(q, k, v, cur_len):
+def decode_attention(q, k, v, cur_len, window=None):
     """q: (B, H, hd); k, v: (B, S, KV, hd); cur_len: (B,) -> (B, H, hd)."""
     mode = _dispatch("decode_attention", q)
     if mode == "ref":
-        return ref.decode_attn_ref(q, k, v, cur_len)
+        return ref.decode_attn_ref(q, k, v, cur_len, window)
+    _no_window("decode_attention", window)
     return _decode_kernel(q, k, v, cur_len, interpret=(mode == "interpret"))
 
 
-def paged_decode_attention(q, k_pages, v_pages, block_table, cur_len):
-    """q: (B, H, hd); pages (P, KV, page, hd); block_table (B, n) int32.
+def paged_decode_attention(q, k_pages, v_pages, block_table, cur_len, window=None):
+    """q: (B, H, hd); pages (P, KV, page, hd); block_table (B, n) int32;
+    ``window`` (1,) or None.
 
     The block-table-indirect split-K kernel, or off the TPU one gather that
     rebuilds the contiguous (B, n*page, KV, hd) view and the dense decode
@@ -83,29 +95,33 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, cur_len):
     mode = _dispatch("paged_decode_attention", q)
     if mode == "ref":
         k, v = _gathered(k_pages, block_table, q.dtype), _gathered(v_pages, block_table, q.dtype)
-        return ref.decode_attn_ref(q, k, v, cur_len)
-    return _paged_kernel(q, k_pages, v_pages, block_table, cur_len,
+        return ref.decode_attn_ref(q, k, v, cur_len, window)
+    return _paged_kernel(q, k_pages, v_pages, block_table, cur_len, window,
                          interpret=(mode == "interpret"))
 
 
-def paged_chunk_attention(q, k_pages, v_pages, block_table, start):
+def paged_chunk_attention(q, k_pages, v_pages, block_table, start, window=None):
     """q: (1, C, H, hd) chunk rows from absolute position ``start`` (1,);
-    chunked-prefill attention over the page arena.
+    chunked-prefill attention over the page arena; ``window`` (1,) or None.
 
     The chunk kernel, or off the TPU one gather and the q-chunked reference
     with ``q_offset=start`` — bit-exact against a dense causal prefill."""
     mode = _dispatch("paged_chunk_attention", q)
     if mode == "ref":
         k, v = _gathered(k_pages, block_table, q.dtype), _gathered(v_pages, block_table, q.dtype)
-        return ref.chunked_mha_ref(q, k, v, causal=True, q_offset=start[0])
-    return _paged_chunk_kernel(q, k_pages, v_pages, block_table, start,
+        return ref.chunked_mha_ref(q, k, v, causal=True, q_offset=start[0], window=window)
+    return _paged_chunk_kernel(q, k_pages, v_pages, block_table, start, window,
                                interpret=(mode == "interpret"))
 
 
-def gmm(xe, w):
-    """Per-expert GEMM of every routing group. xe: (G, E, C, d); w: (E, d, f)
-    -> (G, E, C, f). The kernel runs once per group."""
-    mode = _dispatch("gmm", xe)
+def gmm(x, w, group_sizes, *, block_rows: int):
+    """Grouped GEMM of expert rows. x: (M, d) rows sorted by expert, each
+    expert's ``group_sizes`` (E,) rows a whole number of ``block_rows``
+    blocks; w: (E, d, f) -> (M, f). Rows past the groups are not read
+    back by the caller (the kernel leaves them unwritten, the reference
+    zero)."""
+    mode = _dispatch("gmm", x)
     if mode == "ref":
-        return jnp.einsum("gecd,edf->gecf", xe, w)
-    return jnp.stack([_gmm_kernel(x, w, interpret=(mode == "interpret")) for x in xe])
+        return ref.gmm_ref(x, w, group_sizes)
+    return _gmm_kernel(x, w, group_sizes, block_rows=block_rows,
+                       interpret=(mode == "interpret"))

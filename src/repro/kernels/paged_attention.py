@@ -25,6 +25,14 @@ per block whose double-buffered K+V scratch fits ``SCRATCH_BUDGET_BYTES``.
 page index maps read the block table, and pages wholly past the chunk's
 last row are skipped.
 
+Both kernels take an optional window, scalar-prefetched so that one scan
+over layers of two kinds can pass each its own: a query at position p then
+sees the keys above ``p - window``. The decode kernel's page loop starts at
+the block of the window's first page and copies no page before it; the
+chunk kernel's page map sends the pages wholly before a query block's
+window to the first page it reads, so they cost no DMA. Without a window
+each kernel compiles exactly as it does without the argument.
+
 ``gather_pages`` is the non-TPU fallback: it reconstructs the contiguous
 (B, S, KV, hd) view with one advanced-indexing gather, which XLA fuses into
 the surrounding program (see ``kernels/ops.py`` for the dispatch).
@@ -69,16 +77,27 @@ def _pages_per_block(n: int, kv: int, page: int, hd: int, itemsize: int) -> int:
     return max(fits, default=1)
 
 
+def decode_span(cur, window, page: int, ppb: int):
+    """(lowest key, first page, first block) a slot of length ``cur``
+    attends from under ``window``: keys ``[max(0, cur - window), cur)``;
+    the page loop starts at the block holding the lowest key's page."""
+    lo = jnp.maximum(cur - window, 0)
+    return lo, lo // page, lo // (ppb * page)
+
+
 def _paged_kernel(
-    bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, m_ref, l_ref, acc_ref,
-    *, scale: float, page: int, ppb: int,
+    bt_ref, len_ref, *refs, scale: float, page: int, ppb: int, windowed: bool,
 ):
+    if windowed:
+        win_ref, *refs = refs
+    q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, m_ref, l_ref, acc_ref = refs
     ib = pl.program_id(0)
     cur = len_ref[ib]
     kv = q_ref.shape[0]
     bk = ppb * page
     live_pages = (cur + page - 1) // page
     num_blocks = (cur + bk - 1) // bk
+    lo, first_page, first_blk = decode_span(cur, win_ref[0], page, ppb) if windowed else (0, 0, 0)
 
     m_ref[...] = jnp.full_like(m_ref, osm.NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -89,8 +108,11 @@ def _paged_kernel(
         into buffer ``slot``; a page past the length is never copied."""
         for i in range(ppb):
             idx = blk * ppb + i
+            live = idx < live_pages
+            if windowed:  # a page wholly before the window is never copied
+                live = jnp.logical_and(live, idx >= first_page)
 
-            @pl.when(idx < live_pages)
+            @pl.when(live)
             def _():
                 pid = bt_ref[ib, idx]
                 for hbm, buf, sem in ((k_hbm, k_buf, sems.at[0, slot]),
@@ -107,17 +129,23 @@ def _paged_kernel(
             v = v_buf[slot, :, j].reshape(bk, -1)
             s = osm.scores(q_ref[j], k, scale)  # (G, bk)
             if masked:  # the last block: columns at or past cur_len, and their
-                # never-copied rows of V (stale VMEM), contribute exact zeros
+                # never-copied rows of V (stale VMEM), contribute exact zeros;
+                # with a window, so do the first block's columns below it
                 rest = cur - blk * bk
-                s = jnp.where(jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) < rest,
-                              s, osm.NEG_INF)
-                v = jnp.where(jax.lax.broadcasted_iota(jnp.int32, v.shape, 0) < rest,
-                              v, jnp.zeros_like(v))
+                cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                rows = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+                keep_s, keep_v = cols < rest, rows < rest
+                if windowed:
+                    start = lo - blk * bk
+                    keep_s = jnp.logical_and(keep_s, cols >= start)
+                    keep_v = jnp.logical_and(keep_v, rows >= start)
+                s = jnp.where(keep_s, s, osm.NEG_INF)
+                v = jnp.where(keep_v, v, jnp.zeros_like(v))
             m_ref[j], l_ref[j], acc_ref[j] = osm.step(s, v, m_ref[j], l_ref[j], acc_ref[j])
 
-    @pl.when(num_blocks > 0)
+    @pl.when(num_blocks > first_blk)
     def _prime():
-        copy_block(0, 0, start=True)
+        copy_block(first_blk, first_blk % 2, start=True)
 
     def body(blk, carry):
         slot = blk % 2
@@ -127,12 +155,14 @@ def _paged_kernel(
             copy_block(blk + 1, 1 - slot, start=True)
 
         copy_block(blk, slot, start=False)
-        last = blk == num_blocks - 1
-        pl.when(last)(lambda: fold(blk, slot, True))
-        pl.when(jnp.logical_not(last))(lambda: fold(blk, slot, False))
+        edge = blk == num_blocks - 1
+        if windowed:
+            edge = jnp.logical_or(edge, blk == first_blk)
+        pl.when(edge)(lambda: fold(blk, slot, True))
+        pl.when(jnp.logical_not(edge))(lambda: fold(blk, slot, False))
         return carry
 
-    jax.lax.fori_loop(0, num_blocks, body, 0)
+    jax.lax.fori_loop(first_blk, num_blocks, body, 0)
     for j in range(kv):  # an empty slot keeps l == 0: exact zeros
         o_ref[j] = osm.finalize(l_ref[j], acc_ref[j], o_ref.dtype)
 
@@ -144,12 +174,19 @@ def paged_decode_attention(
     v_pages: jax.Array,
     block_table: jax.Array,
     cur_len: jax.Array,
+    window: jax.Array | None = None,
     *,
     interpret: bool = False,
     pages_per_block: int | None = None,
 ) -> jax.Array:
     """q: (B, H, hd); k_pages/v_pages: (P, KV, page, hd);
     block_table: (B, n_pages) int32; cur_len: (B,) -> (B, H, hd).
+
+    ``window`` (1,) int32, scalar-prefetched so a scan over layers of two
+    kinds can pass it, keeps slot b to the keys
+    ``[max(0, cur_len[b] - window), cur_len[b])``: its page loop starts at
+    the block of the window's first page, and earlier pages are never
+    copied. None compiles the unwindowed kernel.
 
     ``pages_per_block`` overrides the block size derived from the shapes
     (tests force multi-block sweeps at tiny shapes with it); it must divide
@@ -171,16 +208,20 @@ def paged_decode_attention(
     if n % ppb:
         raise ValueError(f"pages_per_block={ppb} must divide the table width {n}")
 
-    kernel = functools.partial(_paged_kernel, scale=scale, page=page, ppb=ppb)
+    windowed = window is not None
+    kernel = functools.partial(_paged_kernel, scale=scale, page=page, ppb=ppb, windowed=windowed)
+    prefetch = [block_table.astype(jnp.int32), cur_len.astype(jnp.int32)]
+    if windowed:
+        prefetch.append(window.astype(jnp.int32).reshape(1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block_table, cur_len
+        num_scalar_prefetch=len(prefetch),  # block_table, cur_len[, window]
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((None, kv, g, lanes), lambda ib, bt, ln: (ib, 0, 0, 0)),
+            pl.BlockSpec((None, kv, g, lanes), lambda ib, *_: (ib, 0, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((None, kv, g, lanes), lambda ib, bt, ln: (ib, 0, 0, 0)),
+        out_specs=pl.BlockSpec((None, kv, g, lanes), lambda ib, *_: (ib, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, ppb, kv, page, lanes), k_pages.dtype),
             pltpu.VMEM((2, ppb, kv, page, lanes), v_pages.dtype),
@@ -196,23 +237,41 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, g, lanes), q.dtype),
         interpret=interpret,
-    )(block_table.astype(jnp.int32), cur_len.astype(jnp.int32), qh, k_pages, v_pages)
+    )(*prefetch, qh, k_pages, v_pages)
     return out[..., :hd].reshape(b, h, hd)
 
 
+def chunk_pages(row0, block_q: int, page: int, window=None):
+    """(first, last) page a block of ``block_q`` query rows from absolute
+    position ``row0`` reads: the last holds its last row; the first, with
+    ``window``, the lowest key its first row sees (row0 - window + 1)."""
+    last = (row0 + block_q - 1) // page
+    if window is None:
+        return 0, last
+    return jnp.maximum(row0 - window + 1, 0) // page, last
+
+
 def _paged_chunk_kernel(
-    bt_ref, start_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-    *, scale: float, page: int, num_page_blocks: int, block_q: int, groups: int,
+    bt_ref, start_ref, *refs, scale: float, page: int, num_page_blocks: int, block_q: int,
+    groups: int, windowed: bool,
 ):
     """Chunked-prefill attention over the page arena: a block of ``block_q``
     query rows of one prefill chunk (the chunk starts at absolute position
     ``start``), for each of the G heads sharing a KV head, sweeps the
     sequence's pages with the decode kernel's online softmax, carrying
     per-row (m, l, acc) in VMEM scratch. Row i masks columns past its own
-    absolute position (causal); pages wholly past the block's last row are
-    skipped."""
+    absolute position (causal) and, with a window, columns at or below its
+    position minus the window; pages wholly past the block's last row, or
+    wholly before its first row's window, are skipped."""
+    if windowed:
+        win_ref, *refs = refs
+    q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
     ib, iq, ik = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     row0 = start_ref[ib] + iq * block_q  # absolute position of the block's row 0
+    live = ik * page <= row0 + block_q - 1
+    if windowed:
+        first, _ = chunk_pages(row0, block_q, page, win_ref[0])
+        live = jnp.logical_and(live, ik >= first)
 
     @pl.when(ik == 0)
     def _init():
@@ -220,13 +279,15 @@ def _paged_chunk_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(ik * page <= row0 + block_q - 1)
+    @pl.when(live)
     def _body():
         k = k_ref[...]  # (page, hd)
         v = v_ref[...]
         rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, page), 0)
         cols = ik * page + jax.lax.broadcasted_iota(jnp.int32, (block_q, page), 1)
         causal = cols <= rows
+        if windowed:
+            causal = jnp.logical_and(causal, cols > rows - win_ref[0])
         for gi in range(groups):  # static: G (bq, hd) x (hd, page) MXU matmuls
             s = jnp.where(causal, osm.scores(q_ref[gi], k, scale), osm.NEG_INF)
             m_ref[gi], l_ref[gi], acc_ref[gi] = osm.step(
@@ -246,13 +307,17 @@ def paged_chunk_attention(
     v_pages: jax.Array,
     block_table: jax.Array,
     start: jax.Array,
+    window: jax.Array | None = None,
     *,
     block_q: int = DEFAULT_BLOCK_Q,
     interpret: bool = False,
 ) -> jax.Array:
     """q: (B, C, H, hd); k_pages/v_pages: (P, KV, page, hd);
     block_table: (B, n) int32; start: (B,) absolute position of q[:, 0]
-    -> (B, C, H, hd)."""
+    -> (B, C, H, hd). ``window`` (1,) int32, scalar-prefetched: row p
+    attends to keys above ``p - window`` only, and a q block issues no DMA
+    for the pages wholly before its first row's window. None compiles the
+    unwindowed kernel."""
     b, c, h, hd = q.shape
     _, kv, page, _ = k_pages.shape
     n = block_table.shape[1]
@@ -262,18 +327,27 @@ def paged_chunk_attention(
         raise ValueError(f"chunk C={c} must divide block_q={block_q}")
     scale = 1.0 / (hd**0.5)
 
-    def page_map(ib, j, iq, ik, bt, st):  # pages past the block's last row: no DMA
-        return (bt[ib, jnp.minimum(ik, (st[ib] + (iq + 1) * block_q - 1) // page)], j, 0, 0)
+    windowed = window is not None
+    prefetch = [block_table.astype(jnp.int32), start.astype(jnp.int32)]
+    if windowed:
+        prefetch.append(window.astype(jnp.int32).reshape(1))
 
-    def q_map(ib, j, iq, ik, bt, st):
+    def page_map(ib, j, iq, ik, bt, st, *win):  # pages past the block's last row
+        # (and before its window): the page already held, so no DMA
+        first, last = chunk_pages(st[ib] + iq * block_q, block_q, page,
+                                  win[0][0] if windowed else None)
+        idx = jnp.minimum(ik, last) if not windowed else jnp.clip(ik, first, last)
+        return (bt[ib, idx], j, 0, 0)
+
+    def q_map(ib, j, iq, ik, *_):
         return (ib, j, 0, iq, 0)
 
     kernel = functools.partial(
         _paged_chunk_kernel, scale=scale, page=page, num_page_blocks=n,
-        block_q=block_q, groups=g,
+        block_q=block_q, groups=g, windowed=windowed,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block_table, start
+        num_scalar_prefetch=len(prefetch),  # block_table, start[, window]
         grid=(b, kv, c // block_q, n),
         in_specs=[
             pl.BlockSpec((None, None, g, block_q, hd), q_map),
@@ -296,5 +370,5 @@ def paged_chunk_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
         interpret=interpret,
-    )(block_table.astype(jnp.int32), start.astype(jnp.int32), qh, k_pages, v_pages)
+    )(*prefetch, qh, k_pages, v_pages)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, c, h, hd)

@@ -21,6 +21,7 @@ def chunked_mha_ref(
     causal: bool,
     q_chunk: int = DEFAULT_Q_CHUNK,
     q_offset: jax.Array | int = 0,
+    window: jax.Array | None = None,
 ) -> jax.Array:
     """Query-chunked attention. q: (B,T,H,hd); k,v: (B,S,KV,hd) -> (B,T,H,hd).
 
@@ -28,7 +29,8 @@ def chunked_mha_ref(
     materializes a (T, S) score tensor — the jnp analogue of the
     flash-attention memory profile, which keeps dry-run lowering at full
     size honest. ``q_offset``: absolute position of q[0] (a chunked-prefill
-    block may start past 0)."""
+    block may start past 0). ``window`` (1,): a causal row at position p
+    sees the keys above ``p - window`` only."""
     b, t, h, hd = q.shape
     num_kv = k.shape[2]
     qg = q.reshape(b, t, num_kv, h // num_kv, hd)
@@ -47,6 +49,8 @@ def chunked_mha_ref(
         if causal:
             q_idx = blk_i * chunk + jnp.arange(chunk) + q_offset
             mask = k_idx[None, :] <= q_idx[:, None]
+            if window is not None:
+                mask = mask & (k_idx[None, :] > q_idx[:, None] - window[0])
             scores = jnp.where(mask[None, None, None], scores, -jnp.inf)
         probs = jax.nn.softmax(scores, axis=-1)
         out = jnp.einsum("bkgts,bskh->btkgh", probs.astype(v.dtype), v)
@@ -75,8 +79,10 @@ def mha_ref(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True, q_
     return out.reshape(b, t, h, hd)
 
 
-def decode_attn_ref(q: jax.Array, k: jax.Array, v: jax.Array, cur_len: jax.Array) -> jax.Array:
-    """q: (B, H, hd); k, v: (B, S, KV, hd); cur_len: (B,) -> (B, H, hd)."""
+def decode_attn_ref(q: jax.Array, k: jax.Array, v: jax.Array, cur_len: jax.Array,
+                    window: jax.Array | None = None) -> jax.Array:
+    """q: (B, H, hd); k, v: (B, S, KV, hd); cur_len: (B,) -> (B, H, hd).
+    ``window`` (1,): slot b sees keys ``[cur_len[b] - window, cur_len[b])``."""
     b, h, hd = q.shape
     s, kv = k.shape[1], k.shape[2]
     g = h // kv
@@ -84,6 +90,8 @@ def decode_attn_ref(q: jax.Array, k: jax.Array, v: jax.Array, cur_len: jax.Array
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
     scores = jnp.einsum("bkgh,bskh->bkgs", qg, k, preferred_element_type=jnp.float32) * scale
     mask = jnp.arange(s)[None, :] < cur_len[:, None]  # (B, S)
+    if window is not None:
+        mask = mask & (jnp.arange(s)[None, :] >= cur_len[:, None] - window[0])
     scores = jnp.where(mask[:, None, None, :], scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgs,bskh->bkgh", probs.astype(v.dtype), v)
@@ -118,6 +126,8 @@ def ssd_ref(x, bm, cm, dt, a_log, d_skip):
     return y, state
 
 
-def gmm_ref(xe: jax.Array, w: jax.Array) -> jax.Array:
-    """Per-expert GEMM. xe: (E, C, d); w: (E, d, f) -> (E, C, f)."""
-    return jnp.einsum("ecd,edf->ecf", xe, w, preferred_element_type=jnp.float32).astype(xe.dtype)
+def gmm_ref(x: jax.Array, w: jax.Array, group_sizes: jax.Array) -> jax.Array:
+    """Grouped GEMM of rows sorted by group. x: (M, d); w: (E, d, f);
+    group_sizes: (E,) -> (M, f); rows past the groups' sum come out zero."""
+    out = jax.lax.ragged_dot(x, w, group_sizes, preferred_element_type=jnp.float32)
+    return out.astype(x.dtype)

@@ -14,8 +14,23 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import apply_rope, rms_norm_1d
+from repro.models.layers import apply_rope, layer_rope, rms_norm_1d
 from repro.models.params import ParamDef
+
+#: the window a full layer of a windowed configuration passes: wider than
+#: any sequence, so its lower key bound is 0
+NO_WINDOW = 1 << 30
+
+
+def layer_window(cfg: ModelConfig, layer) -> jax.Array | None:
+    """How many keys back from each query layer ``layer`` (an int or a
+    traced int32) attends to, as an int32 (1,) for the kernels' scalar
+    prefetch; None for a configuration without a window, whose attention
+    takes the unwindowed path."""
+    if not cfg.sliding_window:
+        return None
+    w = jnp.where(cfg.full_layer(layer), NO_WINDOW, cfg.sliding_window)
+    return jnp.asarray(w, jnp.int32).reshape(1)
 
 
 def attn_defs(cfg: ModelConfig):
@@ -35,8 +50,10 @@ def attn_defs(cfg: ModelConfig):
     return defs
 
 
-def qkv_project(params, x: jax.Array, cfg: ModelConfig, positions: jax.Array | None):
-    """x: (B, T, d) -> q (B,T,H,hd), k/v (B,T,KV,hd); applies QK-norm + RoPE."""
+def qkv_project(params, x: jax.Array, cfg: ModelConfig, positions: jax.Array | None,
+                layer=None):
+    """x: (B, T, d) -> q (B,T,H,hd), k/v (B,T,KV,hd); applies QK-norm + RoPE
+    (layer ``layer``'s kind of RoPE, see ``layers.layer_rope``)."""
     q = jnp.einsum("btd,dhk->bthk", x, params["wq"])
     k = jnp.einsum("btd,dhk->bthk", x, params["wk"])
     v = jnp.einsum("btd,dhk->bthk", x, params["wv"])
@@ -44,17 +61,20 @@ def qkv_project(params, x: jax.Array, cfg: ModelConfig, positions: jax.Array | N
         q = rms_norm_1d(params["q_norm"], q)
         k = rms_norm_1d(params["k_norm"], k)
     if positions is not None:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        freqs, scale = layer_rope(cfg, q.shape[-1], layer)
+        q = apply_rope(q, positions, freqs, scale)
+        k = apply_rope(k, positions, freqs, scale)
     return q, k, v
 
 
-def full_attention(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool) -> jax.Array:
+def full_attention(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
+                   window: jax.Array | None = None) -> jax.Array:
     """q: (B,T,H,hd); k,v: (B,S,KV,hd) -> (B,T,H,hd). The flash kernel on a
-    TPU, the query-chunked jnp reference elsewhere (``kernels/ops.py``)."""
+    TPU, the query-chunked jnp reference elsewhere (``kernels/ops.py``).
+    ``window`` (see :func:`layer_window`) limits each row to its last keys."""
     from repro.kernels import ops as kops
 
-    return kops.attention(q, k, v, causal=causal)
+    return kops.attention(q, k, v, causal=causal, window=window)
 
 
 def decode_attention(
@@ -62,6 +82,7 @@ def decode_attention(
     k_cache: jax.Array,
     v_cache: jax.Array,
     cur_len: jax.Array,
+    window: jax.Array | None = None,
 ) -> jax.Array:
     """One-token attention over a (possibly sequence-sharded) KV cache.
 
@@ -76,7 +97,7 @@ def decode_attention(
         # dtype; the upconvert fuses into the attention kernel on TPU
         k_cache = k_cache.astype(q.dtype)
         v_cache = v_cache.astype(q.dtype)
-    return kops.decode_attention(q[:, 0], k_cache, v_cache, cur_len)[:, None]
+    return kops.decode_attention(q[:, 0], k_cache, v_cache, cur_len, window)[:, None]
 
 
 def paged_decode_attention(
@@ -85,18 +106,22 @@ def paged_decode_attention(
     v_pages: jax.Array,
     block_table: jax.Array,
     cur_len: jax.Array,
+    window: jax.Array | None = None,
 ) -> jax.Array:
     """One-token attention over a paged KV arena.
 
     q: (B,1,H,hd); pages: (P, KV, page, hd); block_table: (B, n) int32 rows
     of physical page ids (padded entries point at the arena's scratch page);
-    cur_len: (B,) valid lengths. On TPU the block-table-indirect split-K
-    kernel reads pages directly; elsewhere the pages are gathered contiguous
-    and the dense decode reference runs on them, bit-exact against a dense
-    cache of the same width (``kernels/ops.py``)."""
+    cur_len: (B,) valid lengths; ``window`` (1,) keeps slot b to its keys
+    ``[max(0, cur_len[b] - window), cur_len[b])``. On TPU the
+    block-table-indirect split-K kernel reads pages directly; elsewhere the
+    pages are gathered contiguous and the dense decode reference runs on
+    them, bit-exact against a dense cache of the same width
+    (``kernels/ops.py``)."""
     from repro.kernels import ops as kops
 
-    return kops.paged_decode_attention(q[:, 0], k_pages, v_pages, block_table, cur_len)[:, None]
+    return kops.paged_decode_attention(q[:, 0], k_pages, v_pages, block_table, cur_len,
+                                       window)[:, None]
 
 
 def paged_chunk_attention(
@@ -105,19 +130,21 @@ def paged_chunk_attention(
     v_pages: jax.Array,
     block_table: jax.Array,
     start: jax.Array,
+    window: jax.Array | None = None,
 ) -> jax.Array:
     """Causal attention for one chunked-prefill block over a paged arena.
 
     q: (1, C, H, hd) — C chunk rows whose absolute positions begin at
     ``start`` (shape (1,) int32); pages: (P, KV, page, hd); block_table:
     (1, n). Each chunk row attends to every position <= its own absolute
-    position, exactly like the matching rows of a dense causal prefill. On
-    TPU the block-table-indirect chunk kernel reads pages directly;
-    elsewhere one gather rebuilds the contiguous view and the q-chunked
-    reference runs with ``q_offset=start``, bit-exact against dense."""
+    position (and, with ``window`` (1,), above it minus the window), exactly
+    like the matching rows of a dense causal prefill. On TPU the
+    block-table-indirect chunk kernel reads pages directly; elsewhere one
+    gather rebuilds the contiguous view and the q-chunked reference runs
+    with ``q_offset=start``, bit-exact against dense."""
     from repro.kernels import ops as kops
 
-    return kops.paged_chunk_attention(q, k_pages, v_pages, block_table, start)
+    return kops.paged_chunk_attention(q, k_pages, v_pages, block_table, start, window)
 
 
 def attn_output(params, attn: jax.Array) -> jax.Array:

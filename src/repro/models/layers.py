@@ -6,6 +6,8 @@ softmax and the loss.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -25,7 +27,8 @@ def norm_defs(cfg: ModelConfig, d: int | None = None):
     }
 
 
-def apply_norm(params, x: jax.Array, cfg: ModelConfig, eps: float = 1e-5) -> jax.Array:
+def apply_norm(params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    eps = cfg.norm_eps
     xf = x.astype(jnp.float32)
     if "bias" in params:  # LayerNorm
         mu = jnp.mean(xf, axis=-1, keepdims=True)
@@ -53,13 +56,47 @@ def rope_freqs(head_dim: int, theta: float) -> jax.Array:
     return 1.0 / (theta**exponent)  # (head_dim/2,)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., seq, heads, head_dim); positions: broadcastable to (..., seq)."""
-    head_dim = x.shape[-1]
-    freqs = rope_freqs(head_dim, theta)  # (hd/2,)
+def yarn_freqs(cfg: ModelConfig, head_dim: int) -> jax.Array:
+    """YaRN's inverse frequencies (HF ``_compute_yarn_parameters``): each
+    dimension blends the plain frequency (extrapolation) and the plain
+    frequency over ``yarn_factor`` (interpolation) by a linear ramp between
+    the dimensions that turn ``yarn_beta_fast`` and ``yarn_beta_slow``
+    times over the original context."""
+    theta, orig = cfg.rope_theta, cfg.yarn_original_max_position
+
+    def dim_of(rotations: float) -> float:
+        return head_dim * math.log(orig / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(dim_of(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(dim_of(cfg.yarn_beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / (high - low), 0.0, 1.0)
+    plain = rope_freqs(head_dim, theta)
+    return plain / cfg.yarn_factor * ramp + plain * (1.0 - ramp)
+
+
+def layer_rope(cfg: ModelConfig, head_dim: int, layer=None) -> tuple[jax.Array, jax.Array | float]:
+    """(inverse frequencies, cos/sin scale) of layer ``layer`` (an int or a
+    traced int32): YaRN's on a full layer of a configuration with YaRN,
+    plain RoPE at ``rope_theta`` otherwise."""
+    plain = rope_freqs(head_dim, cfg.rope_theta)
+    if not cfg.yarn_factor:
+        return plain, 1.0
+    if layer is None:
+        raise ValueError(f"{cfg.name}: YaRN applies by layer, and no layer index was given")
+    full = cfg.full_layer(layer)
+    return (jnp.where(full, yarn_freqs(cfg, head_dim), plain),
+            jnp.where(full, cfg.yarn_attention_factor, 1.0))
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, freqs: jax.Array, scale=1.0) -> jax.Array:
+    """x: (..., seq, heads, head_dim); positions: broadcastable to (..., seq);
+    ``freqs`` (head_dim/2,) and ``scale`` from :func:`layer_rope`: cos and
+    sin are multiplied by ``scale``."""
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., seq, hd/2)
-    cos = jnp.cos(angles)[..., :, None, :]  # (..., seq, 1, hd/2)
-    sin = jnp.sin(angles)[..., :, None, :]
+    cos = (jnp.cos(angles) * scale)[..., :, None, :]  # (..., seq, 1, hd/2)
+    sin = (jnp.sin(angles) * scale)[..., :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -122,11 +122,12 @@ def build_model(cfg: ModelConfig, rules: LogicalRules | None = None, *, layout: 
                 h = x
                 cache = {} if collect_cache else None
                 metrics = None
-                for key in sorted(params["blocks"]):
+                for i, key in enumerate(sorted(params["blocks"])):
                     h, entry, m = tfm.apply_block_full(
                         params["blocks"][key], h, cfg, blk_kind, rules, positions,
-                        causal=True, collect_cache=collect_cache,
+                        causal=True, collect_cache=collect_cache, layer=i,
                     )
+                    m.pop(tfm.EXPERT_ROWS, None)
                     metrics = m if metrics is None else jax.tree.map(jnp.add, metrics, m)
                     if collect_cache:
                         cache[key] = {"k": entry[0], "v": entry[1]} if isinstance(entry, tuple) else entry
@@ -192,9 +193,9 @@ def build_model(cfg: ModelConfig, rules: LogicalRules | None = None, *, layout: 
             if perlayer:
                 h = x
                 new_cache = {}
-                for key in sorted(params["blocks"]):
+                for i, key in enumerate(sorted(params["blocks"])):
                     h, nc, _ = tfm.apply_block_decode(
-                        params["blocks"][key], h, cache[key], cfg, blk_kind, rules, cur_len
+                        params["blocks"][key], h, cache[key], cfg, blk_kind, rules, cur_len, i
                     )
                     new_cache[key] = nc
             else:

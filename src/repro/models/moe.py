@@ -1,4 +1,15 @@
-"""Top-k token-choice Mixture-of-Experts with expert parallelism.
+"""Top-k token-choice Mixture-of-Experts: dropless on one device, with
+expert parallelism when sharded.
+
+On one device (``rules is None``: the serving path) the layer is dropless.
+Routes sort by expert into a row buffer of static size in which each
+expert's rows are padded to whole blocks of the grouped matmul's rows; the
+three expert matmuls run as one grouped matmul each (``kernels/ops.gmm``),
+which reads the weights of the experts that got rows and no others; the
+outputs gather back to their routes and combine with the gate weights.
+Rows marked invalid (empty batch slots, chunk padding) route nowhere.
+
+Sharded (``rules`` given), the capacity formulation below runs.
 
 Why not the GShard dispatch einsum: its (S, E, C) one-hot contraction costs
 ``N*S*k*cf*d`` FLOPs — for qwen3-moe (E=128, top-8) that is ~5x the *useful*
@@ -49,21 +60,93 @@ def capacity(n_tokens: int, cfg: ModelConfig) -> int:
     return max(8, _round_up(c, 8))
 
 
-def num_groups(n_tokens: int, batch: int, cfg: ModelConfig, rules: LogicalRules | None) -> int:
+def num_groups(n_tokens: int, batch: int, cfg: ModelConfig, rules: LogicalRules) -> int:
     """Groups = data-parallel shard count when per-group token counts stay
     healthy (>= ~4 slots/expert); halved otherwise (tiny decode batches)."""
     target = cfg.moe_min_group_tokens or 4 * cfg.num_experts
-    if rules is None:
-        g = 1
-    else:
-        g = rules.mesh_axis_sizes.get("pod", 1) * rules.mesh_axis_sizes.get("data", 1)
+    g = rules.mesh_axis_sizes.get("pod", 1) * rules.mesh_axis_sizes.get("data", 1)
     while g > 1 and ((n_tokens // g) < target or n_tokens % g or (g > batch and g % batch)):
         g //= 2
     return max(1, g)
 
 
-def apply_moe(params, x: jax.Array, cfg: ModelConfig, rules: LogicalRules | None = None):
-    """x: (B, T, d) -> (y (B, T, d), metrics dict)."""
+def _route(params, xf: jax.Array, k: int):
+    """Router probabilities (n, E), the top-k gate weights renormalised and
+    their experts (n, k), in float32.
+
+    The experts are the top k of the logits, and their gates the softmax of
+    those k logits: the same as the top k of the probabilities renormalised,
+    but chosen on the router matmul's output alone, so that a near tie does
+    not hang on the softmax's sum, whose rounding moves with how a program
+    fuses it (the aux loss's use of ``probs`` decides that)."""
+    logits = jnp.einsum("nd,de->ne", xf.astype(jnp.float32), params["router"])
+    top, idx = jax.lax.top_k(logits, k)
+    return jax.nn.softmax(logits, axis=-1), jax.nn.softmax(top, axis=-1), jax.lax.stop_gradient(idx)
+
+
+def block_rows(routes: int, num_experts: int) -> int:
+    """Rows of one grouped-matmul block: the power of two nearest above an
+    expert's mean rows, within [16, 128] (a bfloat16 tile's rows, the MXU's
+    width)."""
+    mean = -(-routes // num_experts)
+    return min(128, max(16, 1 << (mean - 1).bit_length()))
+
+
+def apply_moe_dropless(params, x: jax.Array, cfg: ModelConfig, valid: jax.Array | None = None):
+    """x: (B, T, d); ``valid`` (B, T) bool marks the rows that route (None:
+    all) -> (y (B, T, d), metrics). Every route of a valid row is computed:
+    ``metrics["expert_rows"]`` (E,) int32 counts each expert's rows."""
+    from repro.kernels import ops as kops
+
+    b, t, d = x.shape
+    n = b * t
+    k, e = cfg.num_experts_per_tok, cfg.num_experts
+    xf = x.reshape(n, d)
+    probs, w, idx = _route(params, xf, k)
+    ok = jnp.ones((n,), bool) if valid is None else valid.reshape(n)
+    ex = jnp.where(ok[:, None], idx, e).reshape(n * k)  # expert e: routes nowhere
+    rows = jnp.zeros((e + 1,), jnp.int32).at[ex].add(1)[:e]
+
+    # sorted row buffer: expert j's rows start at starts[j], its group
+    # padded to whole blocks of bm rows; m bounds the padded groups' sum
+    bm = block_rows(n * k, e)
+    m = bm * (-(-(n * k) // bm) + e)
+    padded = -(-rows // bm) * bm
+    starts = jnp.cumsum(padded) - padded
+    order = jnp.argsort(ex, stable=True)
+    sorted_e = ex[order]
+    first = jnp.cumsum(rows) - rows  # each expert's first place in sorted order
+    je = jnp.minimum(sorted_e, e - 1)
+    dest_sorted = jnp.where(sorted_e < e, starts[je] + jnp.arange(n * k) - first[je], m)
+    dest = jnp.zeros((n * k,), jnp.int32).at[order].set(dest_sorted.astype(jnp.int32))
+    # each buffer row's token (n: a padding row, read as zeros): only int32
+    # indices scatter, and the rows move by one gather
+    src = jnp.full((m,), n, jnp.int32).at[dest_sorted].set(order // k, mode="drop")
+    xs = jnp.take(xf, src, axis=0, mode="fill", fill_value=0)
+
+    gate = kops.gmm(xs, params["wi_gate"], padded, block_rows=bm)
+    up = kops.gmm(xs, params["wi_up"], padded, block_rows=bm)
+    h = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype) * up
+    ys = kops.gmm(h, params["wo"], padded, block_rows=bm)  # (m, d)
+
+    yk = ys.at[dest].get(mode="fill", fill_value=0).reshape(n, k, d)
+    wk = jnp.where(ok[:, None], w, 0.0)
+    y = jnp.einsum("nkd,nk->nd", yk.astype(jnp.float32), wk).astype(x.dtype).reshape(b, t, d)
+
+    okf = ok.astype(jnp.float32)
+    n_ok = jnp.maximum(jnp.sum(okf), 1.0)
+    f_e = rows.astype(jnp.float32) / n_ok
+    p_e = jnp.sum(probs * okf[:, None], axis=0) / n_ok
+    aux = e * jnp.sum(f_e / k * p_e)
+    return y, {"moe_aux": aux, "moe_dropped": jnp.zeros((), jnp.float32), "expert_rows": rows}
+
+
+def apply_moe(params, x: jax.Array, cfg: ModelConfig, rules: LogicalRules | None = None,
+              valid: jax.Array | None = None):
+    """x: (B, T, d) -> (y (B, T, d), metrics dict). One device: dropless
+    (``valid`` marks the rows that route); sharded: capacity buffers."""
+    if rules is None:
+        return apply_moe_dropless(params, x, cfg, valid)
     b, t, d = x.shape
     n = b * t
     k, e = cfg.num_experts_per_tok, cfg.num_experts
@@ -238,12 +321,10 @@ def apply_moe(params, x: jax.Array, cfg: ModelConfig, rules: LogicalRules | None
     xe = shard_as(xe, ("batch", "experts", None, None), rules)
 
     # --- expert compute (dense GEMMs; E is the EP axis) ---
-    from repro.kernels import ops as kops
-
-    gate = kops.gmm(xe, params["wi_gate"])
-    up = kops.gmm(xe, params["wi_up"])
+    gate = jnp.einsum("gecd,edf->gecf", xe, params["wi_gate"])
+    up = jnp.einsum("gecd,edf->gecf", xe, params["wi_up"])
     h = jax.nn.silu(gate.astype(jnp.float32)).astype(xe.dtype) * up
-    ye = kops.gmm(h, params["wo"])  # wo: (E, f, d)
+    ye = jnp.einsum("gecf,efd->gecd", h, params["wo"])
     ye = shard_as(ye, ("batch", "experts", None, None), rules)
 
     # --- combine: one explicit all-gather of ye over 'model' (E-dim), then a

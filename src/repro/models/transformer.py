@@ -23,13 +23,30 @@ from repro.models.params import ParamDef, stack_defs
 from repro.sharding.specs import LogicalRules, shard_as, shard_as_bf16_grad
 
 ZERO_METRICS = {"moe_aux": 0.0, "moe_dropped": 0.0}
+#: the dropless MoE layer's per-expert row counts, int32 (E,) a layer
+EXPERT_ROWS = "expert_rows"
 
 
 def _metrics_like(m: dict | None) -> dict:
     out = dict(ZERO_METRICS)
     if m:
         out.update(m)
-    return {k: jnp.asarray(v, jnp.float32) for k, v in out.items()}
+    return {k: v if k == EXPERT_ROWS else jnp.asarray(v, jnp.float32) for k, v in out.items()}
+
+
+def _stack_metrics(metrics: dict, keep_rows: bool = False) -> dict:
+    """A stack's metrics from its layers' (leading layer axis): the scalars
+    summed; the expert row counts kept per layer, (L, E), or dropped."""
+    out = {k: jnp.sum(v) for k, v in metrics.items() if k != EXPERT_ROWS}
+    if keep_rows and EXPERT_ROWS in metrics:
+        out[EXPERT_ROWS] = metrics[EXPERT_ROWS]
+    return out
+
+
+def _ffn(params, h: jax.Array, cfg: ModelConfig, kind: str, rules, valid=None):
+    if kind == "moe":
+        return moe_mod.apply_moe(params["moe"], h, cfg, rules, valid)
+    return apply_mlp(params["mlp"], h, cfg), None
 
 
 # ------------------------------------------------------------------ blocks
@@ -68,10 +85,13 @@ def apply_block_full(
     positions: jax.Array,
     causal: bool = True,
     collect_cache: bool = False,
+    layer=None,
 ):
     """Full-sequence block. Returns (x, cache_entry | None, metrics).
 
-    cache_entry: (k, v) for attention kinds, ssm state dict for 'ssm'."""
+    cache_entry: (k, v) for attention kinds, ssm state dict for 'ssm'.
+    ``layer`` (the model index, an int or a traced int32) chooses the
+    layer's window and RoPE."""
     metrics = None
     if kind == "ssm":
         h = apply_norm(params["ln1"], x, cfg)
@@ -84,7 +104,7 @@ def apply_block_full(
         return x, cache, _metrics_like(metrics)
 
     h = apply_norm(params["ln1"], x, cfg)
-    q, k, v = attn_mod.qkv_project(params["attn"], h, cfg, positions)
+    q, k, v = attn_mod.qkv_project(params["attn"], h, cfg, positions, layer)
     q = shard_as(q, ("batch", "seq_full", "act_heads", None), rules)
     k_attn, v_attn = k, v
     if rules is not None:
@@ -101,7 +121,8 @@ def apply_block_full(
             v_attn = jnp.repeat(v, rep, axis=2)
             k_attn = shard_as(k_attn, ("batch", "seq_full", "act_heads", None), rules)
             v_attn = shard_as(v_attn, ("batch", "seq_full", "act_heads", None), rules)
-    out = attn_mod.full_attention(q, k_attn, v_attn, causal=causal)
+    out = attn_mod.full_attention(q, k_attn, v_attn, causal=causal,
+                                  window=attn_mod.layer_window(cfg, layer))
     x = x + attn_mod.attn_output(params["attn"], out)
     x = shard_as_bf16_grad(x, ("batch", "seq", None), rules)
     if collect_cache:
@@ -113,10 +134,7 @@ def apply_block_full(
         v = shard_as(v.astype(cache_dt), ("batch", "cache_seq", "cache_kv_heads", None), rules)
 
     h = apply_norm(params["ln2"], x, cfg)
-    if kind == "moe":
-        y, metrics = moe_mod.apply_moe(params["moe"], h, cfg, rules)
-    else:
-        y = apply_mlp(params["mlp"], h, cfg)
+    y, metrics = _ffn(params, h, cfg, kind, rules)
     x = x + y
     x = shard_as_bf16_grad(x, ("batch", "seq", None), rules)
     return x, (k, v), _metrics_like(metrics)
@@ -130,6 +148,7 @@ def apply_block_decode(
     kind: str,
     rules: LogicalRules | None,
     cur_len: jax.Array,
+    layer=None,
 ):
     """Single-token block step. cache: {'k','v'} or SSM state dict."""
     metrics = None
@@ -140,16 +159,14 @@ def apply_block_decode(
 
     positions = cur_len[:, None]  # (B, 1)
     h = apply_norm(params["ln1"], x, cfg)
-    q, k_new, v_new = attn_mod.qkv_project(params["attn"], h, cfg, positions)
+    q, k_new, v_new = attn_mod.qkv_project(params["attn"], h, cfg, positions, layer)
     k_cache, v_cache = attn_mod.update_kv_cache(cache["k"], cache["v"], k_new, v_new, positions)
-    out = attn_mod.decode_attention(q, k_cache, v_cache, cur_len + 1)
+    out = attn_mod.decode_attention(q, k_cache, v_cache, cur_len + 1,
+                                    attn_mod.layer_window(cfg, layer))
     x = x + attn_mod.attn_output(params["attn"], out)
 
     h = apply_norm(params["ln2"], x, cfg)
-    if kind == "moe":
-        y, metrics = moe_mod.apply_moe(params["moe"], h, cfg, rules)
-    else:
-        y = apply_mlp(params["mlp"], h, cfg)
+    y, metrics = _ffn(params, h, cfg, kind, rules)
     x = x + y
     new_cache = {"k": k_cache, "v": v_cache}
     return x, new_cache, _metrics_like(metrics)
@@ -166,6 +183,7 @@ def apply_block_decode_paged(
     rules: LogicalRules | None,
     cur_len: jax.Array,
     write_kv: bool = True,
+    layer=None,
 ):
     """Single-token block step against one layer's page arena slice.
 
@@ -179,25 +197,26 @@ def apply_block_decode_paged(
     ``write_kv=False`` runs a FROZEN step: the new token's K/V is assumed
     already resident at position ``cur_len`` (a shared-prefix-cache hit)
     and nothing is written — the engine uses this to recover first-token
-    logits for a whole-prompt hit without touching shared pages."""
+    logits for a whole-prompt hit without touching shared pages.
+
+    ``layer`` (the model index) chooses the window and the RoPE. An empty
+    slot (its block-table row all the arena's scratch page 0) routes to no
+    expert."""
     if kind == "ssm":
         raise ValueError("paged decode applies to attention caches only")
-    metrics = None
     positions = cur_len[:, None]  # (B, 1)
     h = apply_norm(params["ln1"], x, cfg)
-    q, k_new, v_new = attn_mod.qkv_project(params["attn"], h, cfg, positions)
+    q, k_new, v_new = attn_mod.qkv_project(params["attn"], h, cfg, positions, layer)
     if write_kv:
         k_pages, v_pages = attn_mod.update_paged_kv(
             k_pages, v_pages, k_new, v_new, block_table, cur_len
         )
-    out = attn_mod.paged_decode_attention(q, k_pages, v_pages, block_table, cur_len + 1)
+    out = attn_mod.paged_decode_attention(q, k_pages, v_pages, block_table, cur_len + 1,
+                                          attn_mod.layer_window(cfg, layer))
     x = x + attn_mod.attn_output(params["attn"], out)
 
     h = apply_norm(params["ln2"], x, cfg)
-    if kind == "moe":
-        y, metrics = moe_mod.apply_moe(params["moe"], h, cfg, rules)
-    else:
-        y = apply_mlp(params["mlp"], h, cfg)
+    y, metrics = _ffn(params, h, cfg, kind, rules, valid=(block_table[:, :1] != 0))
     x = x + y
     return x, k_pages, v_pages, _metrics_like(metrics)
 
@@ -213,6 +232,7 @@ def apply_block_prefill_chunk_paged(
     rules: LogicalRules | None,
     start: jax.Array,
     valid: jax.Array,
+    layer=None,
 ):
     """One prefill CHUNK's block step against a layer's page arena slice.
 
@@ -222,25 +242,22 @@ def apply_block_prefill_chunk_paged(
     scratch page). The chunk's K/V is scattered BEFORE attention so chunk
     tokens attend to themselves and each other, exactly like the matching
     rows of a dense causal prefill — chunked prefill is bit-exact vs dense
-    on the gather path."""
+    on the gather path. Padding rows route to no expert."""
     if kind == "ssm":
         raise ValueError("paged prefill applies to attention caches only")
-    metrics = None
     c = x.shape[1]
     positions = start[:, None] + jnp.arange(c)[None, :]  # (1, C)
     h = apply_norm(params["ln1"], x, cfg)
-    q, k_new, v_new = attn_mod.qkv_project(params["attn"], h, cfg, positions)
+    q, k_new, v_new = attn_mod.qkv_project(params["attn"], h, cfg, positions, layer)
     k_pages, v_pages = attn_mod.update_paged_kv_chunk(
         k_pages, v_pages, k_new, v_new, block_table, start, valid
     )
-    out = attn_mod.paged_chunk_attention(q, k_pages, v_pages, block_table, start)
+    out = attn_mod.paged_chunk_attention(q, k_pages, v_pages, block_table, start,
+                                         attn_mod.layer_window(cfg, layer))
     x = x + attn_mod.attn_output(params["attn"], out)
 
     h = apply_norm(params["ln2"], x, cfg)
-    if kind == "moe":
-        y, metrics = moe_mod.apply_moe(params["moe"], h, cfg, rules)
-    else:
-        y = apply_mlp(params["mlp"], h, cfg)
+    y, metrics = _ffn(params, h, cfg, kind, rules, valid=(jnp.arange(c) < valid[0])[None, :])
     x = x + y
     return x, k_pages, v_pages, _metrics_like(metrics)
 
@@ -262,6 +279,7 @@ def apply_stack_full(
     causal: bool = True,
     collect_cache: bool = False,
     unroll: bool | None = None,
+    first_layer: int = 0,
 ):
     """Full-sequence pass through the stack.
 
@@ -270,16 +288,19 @@ def apply_stack_full(
     ssm state dict.
 
     ``unroll`` exists for experimentation; the scan path is the default for
-    all passes (unrolled loops lose cross-layer buffer reuse)."""
+    all passes (unrolled loops lose cross-layer buffer reuse).
+    ``first_layer`` is the model index of the stack's first layer."""
     if unroll is None:
         unroll = False
+    n = jax.tree.leaves(stacked_params)[0].shape[0]
     if unroll:
-        n = jax.tree.leaves(stacked_params)[0].shape[0]
         entries = []
         metrics = None
         for i in range(n):
             lp = jax.tree.map(lambda a: a[i], stacked_params)
-            x, entry, m = apply_block_full(lp, x, cfg, kind, rules, positions, causal, collect_cache)
+            x, entry, m = apply_block_full(lp, x, cfg, kind, rules, positions, causal,
+                                           collect_cache, first_layer + i)
+            m.pop(EXPERT_ROWS, None)
             metrics = m if metrics is None else jax.tree.map(jnp.add, metrics, m)
             if collect_cache:
                 entries.append(entry)
@@ -289,19 +310,20 @@ def apply_stack_full(
             cache = {"k": stacked[0], "v": stacked[1]} if isinstance(stacked, tuple) else stacked
         return x, cache, metrics
 
-    def body(carry, layer_params):
+    def body(carry, inp):
+        layer, layer_params = inp
         h, entry, metrics = apply_block_full(
-            layer_params, carry, cfg, kind, rules, positions, causal, collect_cache
+            layer_params, carry, cfg, kind, rules, positions, causal, collect_cache, layer
         )
         ys = (entry if collect_cache else None, metrics)
         return h, ys
 
     body_fn = jax.checkpoint(body) if cfg.remat else body
-    x, (entries, metrics) = jax.lax.scan(body_fn, x, stacked_params)
+    x, (entries, metrics) = jax.lax.scan(body_fn, x, (first_layer + jnp.arange(n), stacked_params))
     cache = None
     if collect_cache and entries is not None:
         cache = {"k": entries[0], "v": entries[1]} if isinstance(entries, tuple) else entries
-    return x, cache, jax.tree.map(jnp.sum, metrics)
+    return x, cache, _stack_metrics(metrics)
 
 
 def apply_stack_decode(
@@ -313,6 +335,7 @@ def apply_stack_decode(
     rules: LogicalRules | None,
     cur_len: jax.Array,
     mode: str = "carry",
+    first_layer: int = 0,
 ):
     """One decode step through the stack; caches have a leading 'layers' dim.
 
@@ -323,12 +346,14 @@ def apply_stack_decode(
     a python-unrolled loop is worse still (no cross-layer buffer reuse).
     mode='xs' keeps the plain xs/ys formulation for comparison."""
     n = jax.tree.leaves(stacked_params)[0].shape[0]
+    layers = first_layer + jnp.arange(n)
     if mode == "carry":
         def body(carry, inp):
             i, layer_params = inp
             h, caches_c = carry
             cache_i = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), caches_c)
-            h, new_cache, metrics = apply_block_decode(layer_params, h, cache_i, cfg, kind, rules, cur_len)
+            h, new_cache, metrics = apply_block_decode(layer_params, h, cache_i, cfg, kind, rules,
+                                                       cur_len, first_layer + i)
             caches_c = jax.tree.map(
                 lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u.astype(a.dtype), i, 0),
                 caches_c, new_cache,
@@ -338,15 +363,16 @@ def apply_stack_decode(
         (x, new_caches), metrics = jax.lax.scan(
             body, (x, caches), (jnp.arange(n), stacked_params)
         )
-        return x, new_caches, jax.tree.map(jnp.sum, metrics)
+        return x, new_caches, _stack_metrics(metrics)
 
     def body(carry, inp):
-        layer_params, cache = inp
-        h, new_cache, metrics = apply_block_decode(layer_params, carry, cache, cfg, kind, rules, cur_len)
+        layer, layer_params, cache = inp
+        h, new_cache, metrics = apply_block_decode(layer_params, carry, cache, cfg, kind, rules,
+                                                   cur_len, layer)
         return h, (new_cache, metrics)
 
-    x, (new_caches, metrics) = jax.lax.scan(body, x, (stacked_params, caches))
-    return x, new_caches, jax.tree.map(jnp.sum, metrics)
+    x, (new_caches, metrics) = jax.lax.scan(body, x, (layers, stacked_params, caches))
+    return x, new_caches, _stack_metrics(metrics)
 
 
 def apply_stack_decode_paged(
@@ -359,6 +385,7 @@ def apply_stack_decode_paged(
     rules: LogicalRules | None,
     cur_len: jax.Array,
     write_kv: bool = True,
+    first_layer: int = 0,
 ):
     """One decode step through the stack against a paged arena.
 
@@ -368,7 +395,9 @@ def apply_stack_decode_paged(
     dynamic updates, so the whole pool stays ONE buffer through the stack
     instead of double-buffering per layer. ``write_kv=False`` is the frozen
     step (see :func:`apply_block_decode_paged`): nothing is scattered and
-    the arena comes back unchanged."""
+    the arena comes back unchanged. Layer ``i`` of the stack is the model's
+    layer ``first_layer + i``, which sets its window and RoPE; the metrics
+    keep a MoE stack's expert row counts per layer, (L, E)."""
     n = jax.tree.leaves(stacked_params)[0].shape[0]
 
     def body(carry, inp):
@@ -378,7 +407,7 @@ def apply_stack_decode_paged(
         v_pages = jax.lax.dynamic_index_in_dim(arena_c["v"], i, 0, keepdims=False)
         h, k_pages, v_pages, metrics = apply_block_decode_paged(
             layer_params, h, k_pages, v_pages, block_table, cfg, kind, rules,
-            cur_len, write_kv,
+            cur_len, write_kv, first_layer + i,
         )
         if write_kv:
             arena_c = {
@@ -394,7 +423,7 @@ def apply_stack_decode_paged(
     (x, new_arena), metrics = jax.lax.scan(
         body, (x, arena), (jnp.arange(n), stacked_params)
     )
-    return x, new_arena, jax.tree.map(jnp.sum, metrics)
+    return x, new_arena, _stack_metrics(metrics, keep_rows=True)
 
 
 def apply_stack_prefill_chunk_paged(
@@ -407,6 +436,7 @@ def apply_stack_prefill_chunk_paged(
     rules: LogicalRules | None,
     start: jax.Array,
     valid: jax.Array,
+    first_layer: int = 0,
 ):
     """One prefill chunk through the stack against a paged arena — same
     single-buffer carry pattern as :func:`apply_stack_decode_paged`, with
@@ -421,7 +451,7 @@ def apply_stack_prefill_chunk_paged(
         v_pages = jax.lax.dynamic_index_in_dim(arena_c["v"], i, 0, keepdims=False)
         h, k_pages, v_pages, metrics = apply_block_prefill_chunk_paged(
             layer_params, h, k_pages, v_pages, block_table, cfg, kind, rules,
-            start, valid,
+            start, valid, first_layer + i,
         )
         arena_c = {
             "k": jax.lax.dynamic_update_index_in_dim(
@@ -436,4 +466,4 @@ def apply_stack_prefill_chunk_paged(
     (x, new_arena), metrics = jax.lax.scan(
         body, (x, arena), (jnp.arange(n), stacked_params)
     )
-    return x, new_arena, jax.tree.map(jnp.sum, metrics)
+    return x, new_arena, _stack_metrics(metrics, keep_rows=True)

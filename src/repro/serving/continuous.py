@@ -41,7 +41,10 @@ Host phases: the loop marks ``serve.admit``, ``serve.prefill_chunk`` and
 ``serve.decode_step`` (with ``serve.step_inputs``, ``serve.token_fetch`` and
 ``serve.emit`` inside) through the platform tracer's ``phase``: profiler
 annotations on the loop thread, inert unless a profiler trace runs, and
-about once a second an ``obs.clock`` anchor (see ``obs.trace``).
+about once a second an ``obs.clock`` anchor (see ``obs.trace``). For a MoE
+model each decode step's expert row counts come back in the same copy as
+its tokens and land in the flight recorder as one ``moe.expert_rows`` event
+a step.
 """
 from __future__ import annotations
 
@@ -60,6 +63,11 @@ from repro.scheduler.scheduler import OverloadShedError
 from repro.scheduler.slo import BEST_EFFORT, ClassLanes, SLOClass
 from repro.serving.engine import ServingEngine, _greedy_token
 from repro.serving.kvpool import ArenaFull, KVArena
+
+
+#: the flight-recorder event a decode step of a MoE model leaves: its
+#: ``rows`` argument holds each layer's rows per expert, (layers, E)
+EXPERT_ROWS_EVENT = "moe.expert_rows"
 
 
 class ShedError(OverloadShedError):
@@ -481,10 +489,15 @@ class ContinuousBatcher:
                 active = self._step_inputs()
                 tokens = jnp.asarray(self._tok)
             logits = self.engine.paged_decode_step(tokens, self._cur, self._bt)
-            with phase("serve.token_fetch"):
-                nxt = np.asarray(_greedy_token(jnp.asarray(logits)))
+            with phase("serve.token_fetch"):  # one copy: tokens and expert rows
+                nxt, rows = jax.device_get((_greedy_token(jnp.asarray(logits)),
+                                            getattr(self.engine, "expert_rows", None)))
             with phase("serve.emit"):
                 self._emit(active, nxt)
+                if rows is not None and self._tracer is not None:
+                    # one record per step: each layer's rows per expert
+                    self._tracer.control_event(EXPERT_ROWS_EVENT, args={
+                        "rows": np.concatenate(rows).tolist()})
 
     def _step_inputs(self) -> list[int]:
         """Give every resident its write page and fill the step's token,

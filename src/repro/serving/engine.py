@@ -44,7 +44,7 @@ import numpy as np
 
 from repro.analysis.dispatch import TRACER
 from repro.configs.base import ModelConfig
-from repro.core.function import FunctionSpec
+from repro.core.function import COMPILER_OPTIONS, FunctionSpec
 from repro.core.platform import ProvusePlatform
 from repro.models import encdec as ed
 from repro.models import hybrid as hy
@@ -84,6 +84,18 @@ class PagedPrefillJob:
         return self.t_in - self.pos
 
 
+def _stage(fn, params, x, *args):
+    """``fn(params, x, *args)``: one chain stage's layers. Traced into a
+    fused unit, they are inlined, and the platform compiles the unit with
+    its options (``core.function.COMPILER_OPTIONS``); run glued, they are
+    compiled as one program with the same options, so the layers compute
+    the same bits either way. Glued, ``fn`` is a new closure on each call
+    and compiles on each call, as the eager glue's layer scan always did."""
+    if isinstance(x, jax.core.Tracer):
+        return fn(params, x, *args)
+    return jax.jit(fn, compiler_options=COMPILER_OPTIONS)(params, x, *args)
+
+
 def _slice_tree(tree, lo: int, hi: int):
     return jax.tree.map(lambda x: x[lo:hi], tree)
 
@@ -114,6 +126,9 @@ class ServingEngine:
         self.trust = trust_domain or self.cfg.name
         self.entry = f"{self.prefix}/embed"
         self.arena: KVArena | None = None
+        #: the last paged step's expert row counts, one (layers, E) int32
+        #: device array per stage, or None for a model without experts
+        self.expert_rows: tuple | None = None
         key = jax.random.PRNGKey(seed)
         fam = self.cfg.family
         if fam in ("dense", "moe", "vlm", "ssm"):
@@ -158,28 +173,39 @@ class ServingEngine:
         def make_group_fn(i: int):
             key = f"g{i}"
             nxt = names[i + 1] if i + 1 < g else head_name
+            first = i * per  # the model index of the stage's first layer
 
             def group_fn(ctx, params, x, cur_len, caches):
                 if "block_table" in caches:  # paged: caches hold the arena
                     if "chunk_valid" in caches:  # chunked-prefill rows
-                        h, new_arena, _ = tfm.apply_stack_prefill_chunk_paged(
-                            params, x, caches[key], caches["block_table"], cfg,
-                            kind, None, cur_len, caches["chunk_valid"],
-                        )
+                        h, new_arena, metrics = _stage(
+                            lambda p, x, a, t, s, v: tfm.apply_stack_prefill_chunk_paged(
+                                p, x, a, t, cfg, kind, None, s, v, first),
+                            params, x, caches[key], caches["block_table"], cur_len,
+                            caches["chunk_valid"])
                     else:  # single-token decode ("__frozen__" = no KV write)
-                        h, new_arena, _ = tfm.apply_stack_decode_paged(
-                            params, x, caches[key], caches["block_table"], cfg,
-                            kind, None, cur_len, "__frozen__" not in caches,
-                        )
+                        write_kv = "__frozen__" not in caches
+                        h, new_arena, metrics = _stage(
+                            lambda p, x, a, t, c: tfm.apply_stack_decode_paged(
+                                p, x, a, t, cfg, kind, None, c, write_kv, first),
+                            params, x, caches[key], caches["block_table"], cur_len)
                     caches = dict(caches)
                     caches[key] = new_arena
+                    if tfm.EXPERT_ROWS in metrics:  # (per, E) rows per expert
+                        caches[f"{key}.{tfm.EXPERT_ROWS}"] = metrics[tfm.EXPERT_ROWS]
                     return ctx.call(nxt, h, cur_len, caches)
                 old = caches[key]
                 if x.shape[1] == 1:  # decode
-                    h, new_cache, _ = tfm.apply_stack_decode(params, x, old, cfg, kind, None, cur_len)
+                    h, new_cache, _ = _stage(
+                        lambda p, x, c, n: tfm.apply_stack_decode(p, x, c, cfg, kind, None, n,
+                                                                  first_layer=first),
+                        params, x, old, cur_len)
                 else:  # prefill: build the cache and scatter into max_len slots
-                    positions = jnp.arange(x.shape[1])[None, :]
-                    h, built, _ = tfm.apply_stack_full(params, x, cfg, kind, None, positions, collect_cache=True)
+                    h, built, _ = _stage(
+                        lambda p, x: tfm.apply_stack_full(
+                            p, x, cfg, kind, None, jnp.arange(x.shape[1])[None, :],
+                            collect_cache=True, first_layer=first),
+                        params, x)
                     if kind == "ssm":
                         new_cache = built
                     else:
@@ -492,10 +518,14 @@ class ServingEngine:
         return logits
 
     def _store_back(self, caches: dict) -> None:
-        """Keep the step's updated page pools as the arena's state."""
+        """Keep the step's updated page pools as the arena's state, and its
+        expert row counts as :attr:`expert_rows`."""
         with self.platform.tracer.phase("engine.store_back"):
             for name in self.arena.data:
                 self.arena.swap_data(name, caches[name])
+            rows = tuple(caches[k] for k in (f"{n}.{tfm.EXPERT_ROWS}" for n in self.arena.data)
+                         if k in caches)
+            self.expert_rows = rows or None
 
     def _block_table_for(self, seq_ids) -> np.ndarray:
         rows = [self.arena.block_row(s, self.block_width) for s in seq_ids]

@@ -104,6 +104,144 @@ def test_paged_decode_attention_scratch_rows_masked():
     np.testing.assert_allclose(np.asarray(out)[live], np.asarray(expect), rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("lengths,window,ppb", [
+    pytest.param((117, 45, 3, 0), 20, 2, id="window-inside-one-block"),
+    pytest.param((117, 45, 3, 0), 40, 2, id="window-across-blocks"),
+    pytest.param((120, 64, 17, 0), 5, 1, id="window-inside-one-page"),
+    pytest.param((128, 100, 33, 0), 16, 2, id="window-on-page-boundary"),
+    pytest.param((90, 31, 7, 0), 1000, None, id="window-longer-than-every-slot"),
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_decode_attention_window(lengths, window, ppb, dtype):
+    """Slot b attends to keys [max(0, len - W), len) only: the kernel equals
+    the windowed oracle on the gathered cache, and an empty slot is zero."""
+    from repro.kernels.paged_attention import gather_pages, paged_decode_attention
+
+    b, pages, page, h, kv, hd, width = len(lengths), 17, 16, 4, 2, 64, 8
+    ks = jax.random.split(RNG, 4)
+    q = jax.random.normal(ks[0], (b, h, hd), jnp.float32).astype(dtype)
+    kp = jax.random.normal(ks[1], (pages, kv, page, hd), jnp.float32).astype(dtype)
+    vp = jax.random.normal(ks[2], (pages, kv, page, hd), jnp.float32).astype(dtype)
+    bt = jax.random.randint(ks[3], (b, width), 1, pages, jnp.int32).at[-1].set(0)
+    cur, win = jnp.array(lengths, jnp.int32), jnp.array([window], jnp.int32)
+    out = paged_decode_attention(q, kp, vp, bt, cur, win, interpret=True, pages_per_block=ppb)
+    expect = ref.decode_attn_ref(q, gather_pages(kp, bt), gather_pages(vp, bt), cur, win)
+    np.testing.assert_allclose(np.asarray(out[:-1], np.float32),
+                               np.asarray(expect[:-1], np.float32), **tol(dtype))
+    np.testing.assert_array_equal(np.asarray(out)[-1], 0.0)  # the empty slot
+    # keys before the window carry no weight: changing them changes nothing
+    lo = max(0, lengths[0] - window)
+    if lo:
+        keys = gather_pages(kp, bt[:1])
+        poked = keys.at[:, :lo].set(99.0)
+        again = ref.decode_attn_ref(q[:1], poked, gather_pages(vp, bt[:1]), cur[:1], win)
+        np.testing.assert_array_equal(np.asarray(again), np.asarray(expect[:1]))
+
+
+@pytest.mark.parametrize("cur,window,ppb", [(100, 20, 1), (100, 40, 2), (64, 32, 1)])
+def test_paged_decode_window_never_reads_the_pages_before_it(cur, window, ppb):
+    """The pages wholly before the window hold NaN: a kernel that folded
+    them in, masked or not, would return NaN. The output is the same bits
+    as with those pages intact."""
+    from repro.kernels.paged_attention import paged_decode_attention
+
+    pages, page, h, kv, hd = 9, 16, 4, 2, 64
+    ks = jax.random.split(RNG, 4)
+    q = jax.random.normal(ks[0], (1, h, hd), jnp.float32)
+    kp = jax.random.normal(ks[1], (pages, kv, page, hd), jnp.float32)
+    vp = jax.random.normal(ks[2], (pages, kv, page, hd), jnp.float32)
+    bt = jax.random.permutation(ks[3], jnp.arange(1, pages, dtype=jnp.int32))[None]
+    before = bt[0, : (cur - window) // page]  # physical pages wholly below the window
+    assert before.size
+    args = (bt, jnp.array([cur], jnp.int32), jnp.array([window], jnp.int32))
+    clean = paged_decode_attention(q, kp, vp, *args, interpret=True, pages_per_block=ppb)
+    poisoned = paged_decode_attention(q, kp.at[before].set(jnp.nan), vp.at[before].set(jnp.nan),
+                                      *args, interpret=True, pages_per_block=ppb)
+    assert np.isfinite(np.asarray(poisoned)).all()
+    np.testing.assert_array_equal(np.asarray(poisoned), np.asarray(clean))
+
+
+@pytest.mark.parametrize("kernel", ["decode", "chunk"])
+def test_paged_kernels_without_a_window_equal_an_unbounded_one_bit_for_bit(kernel):
+    """No window compiles the unwindowed kernel; a window wider than every
+    sequence (a full layer of a windowed model) computes the same bits."""
+    from repro.kernels.paged_attention import paged_chunk_attention, paged_decode_attention
+    from repro.models.attention import NO_WINDOW
+
+    ks = jax.random.split(RNG, 4)
+    pages, page, h, kv, hd = 17, 16, 4, 2, 64
+    kp = jax.random.normal(ks[1], (pages, kv, page, hd), jnp.float32).astype(jnp.bfloat16)
+    vp = jax.random.normal(ks[2], (pages, kv, page, hd), jnp.float32).astype(jnp.bfloat16)
+    wide = jnp.array([NO_WINDOW], jnp.int32)
+    if kernel == "decode":
+        q = jax.random.normal(ks[0], (3, h, hd), jnp.float32).astype(jnp.bfloat16)
+        bt = jax.random.randint(ks[3], (3, 8), 1, pages, jnp.int32)
+        args = (q, kp, vp, bt, jnp.array([117, 45, 0], jnp.int32))
+        run = lambda *w: paged_decode_attention(*args, *w, interpret=True, pages_per_block=2)
+    else:
+        q = jax.random.normal(ks[0], (1, 32, h, hd), jnp.float32).astype(jnp.bfloat16)
+        bt = jax.random.permutation(ks[3], jnp.arange(1, pages, dtype=jnp.int32))[None, :8]
+        args = (q, kp, vp, bt, jnp.array([37], jnp.int32))
+        run = lambda *w: paged_chunk_attention(*args, *w, block_q=16, interpret=True)
+    np.testing.assert_array_equal(np.asarray(run()), np.asarray(run(wide)))
+
+
+@pytest.mark.parametrize("c,start,window,block_q", [
+    (32, 37, 16, 16),    # two q blocks; the window starts mid-page
+    (16, 100, 40, 16),   # the window reaches back across pages
+    (32, 0, 8, 16),      # a prompt's first chunk
+    (16, 50, 1, 16),     # each row sees only itself
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_chunk_attention_window(c, start, window, block_q, dtype):
+    """Row p attends to keys (p - W, p]: the chunk kernel equals the
+    windowed causal oracle with the chunk's offset."""
+    from repro.kernels.paged_attention import gather_pages, paged_chunk_attention
+
+    ks = jax.random.split(RNG, 4)
+    pages, page, h, kv, hd, width = 17, 16, 4, 2, 64, 12
+    q = jax.random.normal(ks[0], (1, c, h, hd), jnp.float32).astype(dtype)
+    kp = jax.random.normal(ks[1], (pages, kv, page, hd), jnp.float32).astype(dtype)
+    vp = jax.random.normal(ks[2], (pages, kv, page, hd), jnp.float32).astype(dtype)
+    bt = jax.random.permutation(ks[3], jnp.arange(1, pages, dtype=jnp.int32))[None, :width]
+    st, win = jnp.array([start], jnp.int32), jnp.array([window], jnp.int32)
+    out = paged_chunk_attention(q, kp, vp, bt, st, win, block_q=block_q, interpret=True)
+    expect = ref.chunked_mha_ref(q, gather_pages(kp, bt), gather_pages(vp, bt), causal=True,
+                                 q_offset=start, window=win)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(expect, np.float32), **tol(dtype))
+
+
+@pytest.mark.parametrize("cur,window", [(1, 1024), (1024, 1024), (1025, 1024), (3000, 1024),
+                                        (9000, 1024), (300, 5)])
+def test_decode_page_loop_starts_at_the_windows_first_page(cur, window):
+    """The decode kernel copies pages [first page, live pages) only: the
+    first holds the window's lowest key, every page before it lies wholly
+    below the window, and its loop starts at that page's block."""
+    from repro.kernels.paged_attention import decode_span
+
+    page, ppb = 128, 8
+    lo, first_page, first_blk = (int(v) for v in decode_span(jnp.int32(cur), window, page, ppb))
+    assert lo == max(0, cur - window)
+    assert first_page * page <= lo < (first_page + 1) * page
+    assert first_blk == first_page // ppb
+
+
+@pytest.mark.parametrize("row0,window", [(0, 16), (37, 16), (100, 40), (64, 1), (5, 1 << 30)])
+def test_chunk_page_map_issues_no_dma_before_the_window(row0, window):
+    """For a q block, the pages its grid steps map to run from the page of
+    its first row's lowest key to the page of its last row: a page wholly
+    before the window maps to the first page already fetched."""
+    from repro.kernels.paged_attention import chunk_pages
+
+    page, block_q, n = 16, 16, 12
+    first, last = (int(v) for v in chunk_pages(jnp.int32(row0), block_q, page, jnp.int32(window)))
+    low = max(0, row0 - window + 1)
+    assert first == low // page and last == (row0 + block_q - 1) // page
+    mapped = [min(max(ik, first), last) for ik in range(n)]
+    assert set(mapped) == set(range(first, last + 1))
+    assert all((p + 1) * page > low for p in mapped)
+
+
 @pytest.mark.parametrize("c,start,page,h,kv,hd,block_q", [
     (16, 0, 128, 4, 2, 64, 128),     # first chunk of a prompt
     (32, 200, 128, 4, 2, 64, 16),    # later chunk, two q blocks, crosses a page
@@ -228,9 +366,46 @@ def test_models_ssd_chunked_matches_oracle():
 @pytest.mark.parametrize("e,c,d,f", [(4, 64, 128, 256), (2, 128, 64, 128), (1, 32, 32, 32)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_moe_gmm_vs_ref(e, c, d, f, dtype):
+    """Every expert with the same c rows (a capacity buffer's layout)."""
     ks = jax.random.split(RNG, 2)
-    xe = jax.random.normal(ks[0], (e, c, d), jnp.float32).astype(dtype)
+    x = jax.random.normal(ks[0], (e * c, d), jnp.float32).astype(dtype)
     w = (jax.random.normal(ks[1], (e, d, f), jnp.float32) * 0.05).astype(dtype)
-    out = moe_gmm(xe, w, block_c=32, block_f=32, block_d=32, interpret=True)
-    expect = ref.gmm_ref(xe, w)
+    sizes = jnp.full((e,), c, jnp.int32)
+    out = moe_gmm(x, w, sizes, block_rows=32, interpret=True)
+    expect = ref.gmm_ref(x, w, sizes)
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(expect, np.float32), **tol(dtype))
+
+
+@pytest.mark.parametrize("sizes", [(16, 0, 48, 16, 0), (0, 0, 0, 32, 0), (64, 16, 16, 16, 16)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_moe_gmm_ragged_groups_vs_a_per_expert_loop(sizes, dtype):
+    """Uneven groups, empty experts and unused trailing blocks: each group's
+    rows times its own expert's weights; the rows past the groups are not
+    read back."""
+    e, d, f, bm = len(sizes), 64, 128, 16
+    m = sum(sizes) + 2 * bm  # two trailing blocks in no group
+    ks = jax.random.split(RNG, 2)
+    x = jax.random.normal(ks[0], (m, d), jnp.float32).astype(dtype)
+    w = (jax.random.normal(ks[1], (e, d, f), jnp.float32) * 0.05).astype(dtype)
+    out = np.asarray(moe_gmm(x, w, jnp.array(sizes, jnp.int32), block_rows=bm, interpret=True),
+                     np.float32)
+    start = 0
+    for j, n in enumerate(sizes):
+        want = np.asarray(x[start:start + n].astype(jnp.float32) @ w[j].astype(jnp.float32))
+        np.testing.assert_allclose(out[start:start + n], want, **tol(dtype))
+        start += n
+
+
+def test_moe_gmm_fetches_no_weights_of_an_expert_without_rows():
+    """The weight panel each grid step maps to: only experts that have rows,
+    each as one run of consecutive steps (fetched once), and the steps past
+    the groups repeat the last panel (no DMA)."""
+    from repro.kernels.moe_gmm import _live, block_groups
+
+    sizes = jnp.array([32, 0, 0, 16, 0, 48, 0], jnp.int32)
+    bm, nb = 16, 10
+    ids, active = block_groups(sizes, bm, nb)
+    panels = [int(ids[_live(i, active)]) for i in range(nb)]
+    assert int(active[0]) == 6
+    assert panels == [0, 0, 3, 5, 5, 5, 5, 5, 5, 5]
+    assert set(panels) == {j for j, n in enumerate(np.asarray(sizes)) if n}

@@ -102,6 +102,9 @@ _LOGITS = np.array([[4.0, -0.01, 0.5]], np.float32)
      "['logits'] float32[1, 3]: max |d| 1.0 > 0.09"),
     ({"logits": _LOGITS + np.float32(1.0), "len": np.int32(3)},
      "['logits'] float32[1, 3]: max |d| 1.0"),
+    # a NaN is never within tolerance
+    ({"logits": _LOGITS * np.float32([[1.0, np.nan, 1.0]]), "len": np.int32(3)},
+     "['logits'] float32[1, 3]: max |d| nan"),
     ({"logits": _LOGITS, "len": np.int32(4)}, "['len']: int32 values differ"),
     ({"logits": _LOGITS[:, :2], "len": np.int32(3)}, "['logits']: shape (1, 2) vs (1, 3)"),
     ({"logits": _LOGITS}, "1 leaves vs 2"),

@@ -140,6 +140,65 @@ def test_moe_is_dropless_with_ample_capacity():
     )
 
 
+@pytest.mark.parametrize("path", ["ref", "interpret"])
+def test_moe_dropless_keeps_every_route_under_skew_and_skips_empty_rows(monkeypatch, path):
+    """Every token routes to the same two experts (a capacity layer would
+    drop most of them), and two batch slots are empty: each valid row
+    equals the explicit top-k mix, an empty row gets nothing, and the row
+    counts hold exactly the valid rows' routes."""
+    from repro.kernels import ops
+    from repro.models import moe as moe_mod
+
+    monkeypatch.setattr(ops, "_mode", lambda: path)
+    cfg = reduced_config(get_arch("mellum2-12b-a2.5b"))
+    params = init_params(moe_mod.moe_defs(cfg), jax.random.PRNGKey(0))
+    # rows near the all-ones direction, which experts 2 and 5 favour
+    skew = jnp.zeros((cfg.num_experts,)).at[jnp.array([2, 5])].set(jnp.array([0.5, 0.4]))
+    params["router"] = params["router"] * 0.1 + skew
+    b, t = 4, 8
+    x = (1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(1), (b, t, cfg.d_model))).astype(jnp.bfloat16)
+    valid = jnp.ones((b, t), bool).at[jnp.array([1, 3])].set(False)
+    y, metrics = moe_mod.apply_moe(params, x, cfg, valid=valid)
+
+    rows = np.asarray(metrics["expert_rows"])
+    assert rows.sum() == 2 * cfg.num_experts_per_tok * t
+    assert rows[2] == rows[5] == 2 * t  # every valid token, none dropped
+    np.testing.assert_array_equal(np.asarray(y)[1], 0.0)
+    np.testing.assert_array_equal(np.asarray(y)[3], 0.0)
+    xf = x.reshape(-1, cfg.d_model)
+    probs = jax.nn.softmax(xf.astype(jnp.float32) @ params["router"], -1)
+    w, idx = jax.lax.top_k(probs, cfg.num_experts_per_tok)
+    w = w / w.sum(-1, keepdims=True)
+    for i in np.flatnonzero(np.asarray(valid).reshape(-1)):
+        acc = 0
+        for j in range(cfg.num_experts_per_tok):
+            e = int(idx[i, j])
+            gate = jax.nn.silu((xf[i] @ params["wi_gate"][e]).astype(jnp.float32))
+            up = (xf[i] @ params["wi_up"][e]).astype(jnp.float32)
+            acc = acc + float(w[i, j]) * ((gate * up).astype(jnp.bfloat16) @ params["wo"][e]).astype(jnp.float32)
+        np.testing.assert_allclose(np.asarray(y.reshape(-1, cfg.d_model)[i], np.float32),
+                                   np.asarray(acc), rtol=5e-2, atol=5e-2)
+
+
+def test_moe_route_picks_on_the_logits_with_the_renormalised_gates():
+    """The experts are the top k of the router logits and the gates the
+    softmax of those k: the published rule (top k of the softmax,
+    renormalised), taken on the matmul's output so that a near tie does
+    not hang on how a program fuses the softmax."""
+    from repro.models import moe as moe_mod
+
+    cfg = reduced_config(get_arch("mellum2-12b-a2.5b"))
+    params = init_params(moe_mod.moe_defs(cfg), jax.random.PRNGKey(0))
+    x = jax.random.normal(jax.random.PRNGKey(1), (64, cfg.d_model), jnp.float32)
+    probs, w, idx = moe_mod._route(params, x, cfg.num_experts_per_tok)
+    ref_p = jax.nn.softmax(x @ params["router"], -1)
+    ref_w, ref_idx = jax.lax.top_k(ref_p, cfg.num_experts_per_tok)
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(ref_idx))
+    np.testing.assert_allclose(np.asarray(w), np.asarray(ref_w / ref_w.sum(-1, keepdims=True)),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(probs), np.asarray(ref_p), rtol=1e-5, atol=1e-7)
+
+
 def test_moe_router_weights_normalized():
     cfg = reduced_config(get_arch("phi3.5-moe-42b-a6.6b"))
     from repro.models import moe as moe_mod

@@ -144,3 +144,41 @@ def test_hybrid_monolithic_chain():
         assert toks.shape == (1, 5)
     finally:
         platform.shutdown()
+
+
+@pytest.mark.parametrize("step", ["prefill_chunk", "decode"])
+def test_moe_stage_computes_the_same_bits_glued_or_fused(step):
+    """The merge health check compares a stage run as interpreter glue with
+    the same stage inside a fused unit. For a MoE stage (Mellum2's pattern:
+    windowed and YaRN layers, dropless experts) on a prompt past its window,
+    both give the same arena and expert row counts bit for bit, and the same
+    logits: the routing does not move with the compile context."""
+    from repro.core.function import FunctionInstance
+
+    cfg = reduced_config(get_arch("mellum2-12b-a2.5b"))
+    platform = TinyJaxBackend()
+    platform.merger.submit = lambda *a, **k: None  # keep every stage glued
+    try:
+        eng = ServingEngine(build_model(cfg), platform, max_len=128, seed=5,
+                            kv_pages=24, kv_page_size=8)
+        prompt = np.random.default_rng(0).integers(0, cfg.vocab_size, (1, 40)).astype(np.int32)
+        job = eng.begin_prefill_paged("a", {"tokens": prompt})
+        while eng.prefill_chunk_paged(job, 32) is None:
+            pass
+        if step == "decode":  # slot 1 live, slots 0, 2, 3 empty
+            eng.arena.extend("a", 41)
+            table = np.zeros((4, eng.block_width), np.int32)
+            table[1] = eng.arena.block_row("a", eng.block_width)
+            tokens = np.zeros((4, 1), np.int32)
+            tokens[1, 0] = 7
+            eng.paged_decode_step(jnp.asarray(tokens), np.asarray([0, 40, 0, 0], np.int32), table)
+        g1, head = f"{cfg.name}/g1", f"{cfg.name}/head"
+        canary = platform.handler.canary(g1)
+        glued = platform._invoke_with_retry(g1, canary)
+        fused = FunctionInstance({n: platform.spec_of(n) for n in (g1, head)}, platform)
+        assert fused.get_compiled(g1, canary) is not None  # one compiled program
+        got = fused.execute(g1, canary)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(glued)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    finally:
+        platform.shutdown()
