@@ -11,6 +11,11 @@ block, which Pallas does not fetch again, so each expert's weights are
 read once per call, and an expert with no rows is never fetched. Blocks
 past the last group's end map to the last block in use: they start no
 DMA and compute nothing, so their output rows are left unwritten.
+
+``w`` may also be a stack of layers' experts, (L, E, d, f), with the layer
+a third scalar-prefetched operand: the kernel then reads that layer's
+panels in place, and the caller never slices a (E, d, f) bank out of the
+stack (a decode step's scan would copy every layer's whole bank for it).
 """
 from __future__ import annotations
 
@@ -42,7 +47,9 @@ def _live(i, active):
     return jnp.minimum(i, jnp.maximum(active[0] - 1, 0))
 
 
-def _gmm_kernel(ids_ref, active_ref, x_ref, w_ref, o_ref):
+def _gmm_kernel(ids_ref, active_ref, *refs):
+    x_ref, w_ref, o_ref = refs[-3:]  # after the layer's ref, when stacked
+
     @pl.when(pl.program_id(0) < active_ref[0])
     def _():
         o_ref[...] = jax.lax.dot_general(
@@ -55,33 +62,37 @@ def moe_gmm(
     x: jax.Array,
     w: jax.Array,
     group_sizes: jax.Array,
+    layer: jax.Array | None = None,
     *,
     block_rows: int,
     interpret: bool = False,
 ) -> jax.Array:
-    """x: (M, d) rows sorted by expert; w: (E, d, f); group_sizes: (E,)
-    int32, each a multiple of ``block_rows`` -> (M, f). Rows past the
-    groups' sum are not written."""
+    """x: (M, d) rows sorted by expert; w: (E, d, f), or (L, E, d, f) with
+    ``layer`` (1,) int32 naming the layer; group_sizes: (E,) int32, each a
+    multiple of ``block_rows`` -> (M, f). Rows past the groups' sum are not
+    written."""
     m, d = x.shape
-    e, _, f = w.shape
+    f = w.shape[-1]
     if m % block_rows:
         raise ValueError(f"rows M={m} must be whole blocks of {block_rows}")
     nb = m // block_rows
     ids, active = block_groups(group_sizes, block_rows, nb)
+    prefetch = (ids, active) if layer is None else (ids, active, layer)
 
-    def rows(i, ids, active):
+    def rows(i, ids, active, *_):
         return (_live(i, active), 0)
 
-    def panel(i, ids, active):
-        return (ids[_live(i, active)], 0, 0)
+    if layer is None:
+        panel = pl.BlockSpec((1, d, f), lambda i, ids, active: (ids[_live(i, active)], 0, 0))
+    else:
+        panel = pl.BlockSpec((None, 1, d, f),
+                             lambda i, ids, active, layer: (layer[0], ids[_live(i, active)], 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # expert of each row block, blocks in use
+        # expert of each row block, blocks in use, and the layer if stacked
+        num_scalar_prefetch=len(prefetch),
         grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((block_rows, d), rows),
-            pl.BlockSpec((1, d, f), panel),
-        ],
+        in_specs=[pl.BlockSpec((block_rows, d), rows), panel],
         out_specs=pl.BlockSpec((block_rows, f), rows),
     )
     return pl.pallas_call(
@@ -91,4 +102,4 @@ def moe_gmm(
         out_shape=jax.ShapeDtypeStruct((m, f), x.dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(ids, active, x, w)
+    )(*prefetch, x, w)

@@ -114,14 +114,15 @@ def paged_chunk_attention(q, k_pages, v_pages, block_table, start, window=None):
                                interpret=(mode == "interpret"))
 
 
-def gmm(x, w, group_sizes, *, block_rows: int):
+def gmm(x, w, group_sizes, layer=None, *, block_rows: int):
     """Grouped GEMM of expert rows. x: (M, d) rows sorted by expert, each
     expert's ``group_sizes`` (E,) rows a whole number of ``block_rows``
-    blocks; w: (E, d, f) -> (M, f). Rows past the groups are not read
-    back by the caller (the kernel leaves them unwritten, the reference
-    zero)."""
-    mode = _dispatch("gmm", x)
+    blocks; w: (E, d, f), or a stack (L, E, d, f) read in place at
+    ``layer`` (1,) int32 (reported as ``gmm_stacked``) -> (M, f). Rows past
+    the groups are not read back by the caller (the kernel leaves them
+    unwritten, the reference zero)."""
+    mode = _dispatch("gmm" if layer is None else "gmm_stacked", x)
     if mode == "ref":
-        return ref.gmm_ref(x, w, group_sizes)
-    return _gmm_kernel(x, w, group_sizes, block_rows=block_rows,
+        return ref.gmm_ref(x, w, group_sizes, layer)
+    return _gmm_kernel(x, w, group_sizes, layer, block_rows=block_rows,
                        interpret=(mode == "interpret"))

@@ -126,8 +126,12 @@ def ssd_ref(x, bm, cm, dt, a_log, d_skip):
     return y, state
 
 
-def gmm_ref(x: jax.Array, w: jax.Array, group_sizes: jax.Array) -> jax.Array:
-    """Grouped GEMM of rows sorted by group. x: (M, d); w: (E, d, f);
-    group_sizes: (E,) -> (M, f); rows past the groups' sum come out zero."""
+def gmm_ref(x: jax.Array, w: jax.Array, group_sizes: jax.Array,
+            layer: jax.Array | None = None) -> jax.Array:
+    """Grouped GEMM of rows sorted by group. x: (M, d); w: (E, d, f), or
+    (L, E, d, f) with ``layer`` (1,) naming the layer; group_sizes: (E,)
+    -> (M, f); rows past the groups' sum come out zero."""
+    if layer is not None:
+        w = w[layer[0]]
     out = jax.lax.ragged_dot(x, w, group_sizes, preferred_element_type=jnp.float32)
     return out.astype(x.dtype)

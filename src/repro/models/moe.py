@@ -92,10 +92,14 @@ def block_rows(routes: int, num_experts: int) -> int:
     return min(128, max(16, 1 << (mean - 1).bit_length()))
 
 
-def apply_moe_dropless(params, x: jax.Array, cfg: ModelConfig, valid: jax.Array | None = None):
+def apply_moe_dropless(params, x: jax.Array, cfg: ModelConfig, valid: jax.Array | None = None,
+                       layer=None):
     """x: (B, T, d); ``valid`` (B, T) bool marks the rows that route (None:
     all) -> (y (B, T, d), metrics). Every route of a valid row is computed:
-    ``metrics["expert_rows"]`` (E,) int32 counts each expert's rows."""
+    ``metrics["expert_rows"]`` (E,) int32 counts each expert's rows.
+
+    ``layer`` (an int32 scalar) says the expert matrices are a stack's,
+    (L, E, d, f), and which layer to read in place; None: one layer's."""
     from repro.kernels import ops as kops
 
     b, t, d = x.shape
@@ -124,10 +128,11 @@ def apply_moe_dropless(params, x: jax.Array, cfg: ModelConfig, valid: jax.Array 
     src = jnp.full((m,), n, jnp.int32).at[dest_sorted].set(order // k, mode="drop")
     xs = jnp.take(xf, src, axis=0, mode="fill", fill_value=0)
 
-    gate = kops.gmm(xs, params["wi_gate"], padded, block_rows=bm)
-    up = kops.gmm(xs, params["wi_up"], padded, block_rows=bm)
+    at = None if layer is None else jnp.reshape(layer, (1,)).astype(jnp.int32)
+    gate = kops.gmm(xs, params["wi_gate"], padded, at, block_rows=bm)
+    up = kops.gmm(xs, params["wi_up"], padded, at, block_rows=bm)
     h = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype) * up
-    ys = kops.gmm(h, params["wo"], padded, block_rows=bm)  # (m, d)
+    ys = kops.gmm(h, params["wo"], padded, at, block_rows=bm)  # (m, d)
 
     yk = ys.at[dest].get(mode="fill", fill_value=0).reshape(n, k, d)
     wk = jnp.where(ok[:, None], w, 0.0)
@@ -142,11 +147,12 @@ def apply_moe_dropless(params, x: jax.Array, cfg: ModelConfig, valid: jax.Array 
 
 
 def apply_moe(params, x: jax.Array, cfg: ModelConfig, rules: LogicalRules | None = None,
-              valid: jax.Array | None = None):
+              valid: jax.Array | None = None, layer=None):
     """x: (B, T, d) -> (y (B, T, d), metrics dict). One device: dropless
-    (``valid`` marks the rows that route); sharded: capacity buffers."""
+    (``valid`` marks the rows that route, ``layer`` picks stacked experts);
+    sharded: capacity buffers, on one layer's experts."""
     if rules is None:
-        return apply_moe_dropless(params, x, cfg, valid)
+        return apply_moe_dropless(params, x, cfg, valid, layer)
     b, t, d = x.shape
     n = b * t
     k, e = cfg.num_experts_per_tok, cfg.num_experts

@@ -25,6 +25,9 @@ from repro.sharding.specs import LogicalRules, shard_as, shard_as_bf16_grad
 ZERO_METRICS = {"moe_aux": 0.0, "moe_dropped": 0.0}
 #: the dropless MoE layer's per-expert row counts, int32 (E,) a layer
 EXPERT_ROWS = "expert_rows"
+#: a MoE layer's expert matrices, (E, d, f) each: the leaves a paged decode
+#: step reads in place from the stage's stack
+EXPERT_MATRICES = ("wi_gate", "wi_up", "wo")
 
 
 def _metrics_like(m: dict | None) -> dict:
@@ -43,9 +46,9 @@ def _stack_metrics(metrics: dict, keep_rows: bool = False) -> dict:
     return out
 
 
-def _ffn(params, h: jax.Array, cfg: ModelConfig, kind: str, rules, valid=None):
+def _ffn(params, h: jax.Array, cfg: ModelConfig, kind: str, rules, valid=None, expert_layer=None):
     if kind == "moe":
-        return moe_mod.apply_moe(params["moe"], h, cfg, rules, valid)
+        return moe_mod.apply_moe(params["moe"], h, cfg, rules, valid, expert_layer)
     return apply_mlp(params["mlp"], h, cfg), None
 
 
@@ -184,6 +187,7 @@ def apply_block_decode_paged(
     cur_len: jax.Array,
     write_kv: bool = True,
     layer=None,
+    expert_layer=None,
 ):
     """Single-token block step against one layer's page arena slice.
 
@@ -201,7 +205,8 @@ def apply_block_decode_paged(
 
     ``layer`` (the model index) chooses the window and the RoPE. An empty
     slot (its block-table row all the arena's scratch page 0) routes to no
-    expert."""
+    expert. ``expert_layer`` (the index in the stage's stack) says the MoE
+    params carry the stack's expert matrices, (L, E, d, f), read in place."""
     if kind == "ssm":
         raise ValueError("paged decode applies to attention caches only")
     positions = cur_len[:, None]  # (B, 1)
@@ -216,7 +221,8 @@ def apply_block_decode_paged(
     x = x + attn_mod.attn_output(params["attn"], out)
 
     h = apply_norm(params["ln2"], x, cfg)
-    y, metrics = _ffn(params, h, cfg, kind, rules, valid=(block_table[:, :1] != 0))
+    y, metrics = _ffn(params, h, cfg, kind, rules, valid=(block_table[:, :1] != 0),
+                      expert_layer=expert_layer)
     x = x + y
     return x, k_pages, v_pages, _metrics_like(metrics)
 
@@ -397,17 +403,30 @@ def apply_stack_decode_paged(
     step (see :func:`apply_block_decode_paged`): nothing is scattered and
     the arena comes back unchanged. Layer ``i`` of the stack is the model's
     layer ``first_layer + i``, which sets its window and RoPE; the metrics
-    keep a MoE stack's expert row counts per layer, (L, E)."""
+    keep a MoE stack's expert row counts per layer, (L, E).
+
+    On one device (``rules is None``) a MoE stack's expert matrices stay
+    whole, out of the scan's per-layer slices, and each layer's grouped
+    matmuls read its experts in place at ``i``: a slice of a (E, d, f) bank
+    that feeds a kernel is a copy of all E experts, where the step touches
+    a few."""
     n = jax.tree.leaves(stacked_params)[0].shape[0]
+    per_layer, experts = stacked_params, None
+    if rules is None and "moe" in stacked_params:
+        moe = stacked_params["moe"]
+        experts = {k: moe[k] for k in EXPERT_MATRICES}
+        per_layer = dict(stacked_params, moe={k: v for k, v in moe.items() if k not in experts})
 
     def body(carry, inp):
         i, layer_params = inp
         h, arena_c = carry
+        if experts is not None:
+            layer_params = dict(layer_params, moe=dict(layer_params["moe"], **experts))
         k_pages = jax.lax.dynamic_index_in_dim(arena_c["k"], i, 0, keepdims=False)
         v_pages = jax.lax.dynamic_index_in_dim(arena_c["v"], i, 0, keepdims=False)
         h, k_pages, v_pages, metrics = apply_block_decode_paged(
             layer_params, h, k_pages, v_pages, block_table, cfg, kind, rules,
-            cur_len, write_kv, first_layer + i,
+            cur_len, write_kv, first_layer + i, None if experts is None else i,
         )
         if write_kv:
             arena_c = {
@@ -421,7 +440,7 @@ def apply_stack_decode_paged(
         return (h, arena_c), metrics
 
     (x, new_arena), metrics = jax.lax.scan(
-        body, (x, arena), (jnp.arange(n), stacked_params)
+        body, (x, arena), (jnp.arange(n), per_layer)
     )
     return x, new_arena, _stack_metrics(metrics, keep_rows=True)
 

@@ -396,6 +396,36 @@ def test_moe_gmm_ragged_groups_vs_a_per_expert_loop(sizes, dtype):
         start += n
 
 
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("path", ["kernel", "ref"])
+def test_gmm_reads_a_stacked_layer_in_place_bit_for_bit(monkeypatch, path, layer):
+    """A stack of L=3 layers' experts (L, E, d, f) read at ``layer`` gives
+    the bits of the layer's own (E, d, f) matrix: the kernel against itself
+    on ``w[layer]``, ``ops.gmm``'s reference against ``ragged_dot`` on it.
+    The groups hold an expert with no rows, and the buffer ends in blocks
+    past the last group."""
+    from repro.kernels import ops
+
+    sizes = jnp.array([16, 0, 48, 16, 0], jnp.int32)
+    e, d, f, bm = sizes.shape[0], 64, 128, 16
+    m = int(sizes.sum()) + 2 * bm
+    ks = jax.random.split(RNG, 2)
+    x = jax.random.normal(ks[0], (m, d), jnp.float32).astype(jnp.bfloat16)
+    w = (jax.random.normal(ks[1], (3, e, d, f), jnp.float32) * 0.05).astype(jnp.bfloat16)
+    at = jnp.array([layer], jnp.int32)
+    if path == "kernel":
+        got = moe_gmm(x, w, sizes, at, block_rows=bm, interpret=True)
+        want = moe_gmm(x, w[layer], sizes, block_rows=bm, interpret=True)
+        n = int(sizes.sum())  # rows past the groups are left unwritten
+        np.testing.assert_array_equal(np.asarray(got)[:n], np.asarray(want)[:n])
+    else:
+        monkeypatch.setattr(ops, "_mode", lambda: "ref")
+        got = jax.jit(lambda x, w, s, at: ops.gmm(x, w, s, at, block_rows=bm))(x, w, sizes, at)
+        want = jax.lax.ragged_dot(x, w[layer], sizes,
+                                  preferred_element_type=jnp.float32).astype(x.dtype)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_moe_gmm_fetches_no_weights_of_an_expert_without_rows():
     """The weight panel each grid step maps to: only experts that have rows,
     each as one run of consecutive steps (fetched once), and the steps past

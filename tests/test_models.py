@@ -208,3 +208,63 @@ def test_moe_router_weights_normalized():
     y, metrics = moe_mod.apply_moe(params, x, cfg)
     assert y.shape == x.shape
     assert float(metrics["moe_aux"]) > 0.0  # aux loss is live
+
+
+@pytest.mark.parametrize("path", ["ref", "interpret"])
+def test_moe_paged_decode_reads_the_stacked_experts_in_place(monkeypatch, path):
+    """The paged decode scan keeps a MoE stack's expert matrices whole and
+    reads each layer's in place: the same logits, arena and expert row
+    counts, bit for bit, as a per-layer loop that slices every layer's
+    weights out of the stack, both compiled as the platform compiles a
+    stage. Two slots are live (one past its window), two are empty."""
+    import functools
+
+    from repro.core.function import COMPILER_OPTIONS
+    from repro.kernels import ops
+    from repro.models import transformer as tfm
+    from repro.models.layers import apply_norm, unembed
+
+    monkeypatch.setattr(ops, "_mode", lambda: path)
+    cfg = reduced_config(get_arch("mellum2-12b-a2.5b"))
+    n, pages, page = cfg.num_layers, 24, 8
+    model = build_model(cfg)
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    params = init_params(tfm.stack_block_defs(cfg, "moe", n), ks[0])
+    head = init_params({k: model.param_defs[k] for k in ("embed", "ln_f")}, ks[1])
+    shape = (n, pages, cfg.num_kv_heads, page, cfg.d_head)
+    arena = {kv: jax.random.normal(k, shape, jnp.float32).astype(jnp.bfloat16)
+             for kv, k in zip("kv", ks[2:4])}
+    table = np.zeros((4, 6), np.int32)
+    table[0] = [1, 2, 3, 4, 5, 6]  # 37 tokens, past the 16-token window
+    table[2, :2] = [7, 8]
+    table = jnp.asarray(table)
+    cur_len = jnp.array([37, 0, 10, 0], jnp.int32)
+    x = jax.random.normal(ks[4], (4, 1, cfg.d_model), jnp.float32).astype(jnp.bfloat16)
+
+    def logits(h):
+        return unembed(head["embed"], apply_norm(head["ln_f"], h, cfg))[:, 0]
+
+    stage = functools.partial(jax.jit, compiler_options=COMPILER_OPTIONS)
+
+    @stage
+    def in_place(params, x, arena):
+        h, arena, metrics = tfm.apply_stack_decode_paged(params, x, arena, table, cfg, "moe",
+                                                         None, cur_len)
+        return logits(h), arena, metrics[tfm.EXPERT_ROWS]
+
+    @stage
+    def per_layer(params, x, arena):
+        ks_, vs_, rows = [], [], []
+        for i in range(n):
+            lp = jax.tree.map(lambda a: a[i], params)
+            x, k, v, m = tfm.apply_block_decode_paged(lp, x, arena["k"][i], arena["v"][i], table,
+                                                      cfg, "moe", None, cur_len, True, i)
+            ks_.append(k)
+            vs_.append(v)
+            rows.append(m[tfm.EXPERT_ROWS])
+        return logits(x), {"k": jnp.stack(ks_), "v": jnp.stack(vs_)}, jnp.stack(rows)
+
+    got, want = in_place(params, x, arena), per_layer(params, x, arena)
+    assert int(want[2].sum()) == 2 * cfg.num_experts_per_tok * n  # two live slots route
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
